@@ -46,6 +46,9 @@ def main() -> None:
                     help="write a flight-recorder JSONL of the run to PATH")
     args = ap.parse_args()
 
+    from repro.compile_cache import enable_compile_cache
+    enable_compile_cache()
+
     from benchmarks import kernel_perf, serving_bench
 
     if args.smoke:

@@ -6,10 +6,11 @@ many thousands of nodes").
 
     PYTHONPATH=src python examples/scale_torus.py [--k 22] [--no-watermarks]
 
-The run ends with the observability capstone: a torus3d(100) =
-10^6-node sparse-engine run with in-kernel excursion watermarks ON and
-the full (R, B, N) record OFF — the per-node peak |β| / ν-spread health
-report exists even where materializing the record is impossible
+The run ends with the observability capstone: a torus3d(34) =
+39,304-node sparse-engine run (the largest torus whose sparse working
+set fits one TPU's VMEM budget with watermarks on) with in-kernel
+excursion watermarks ON and the full (R, B, N) record OFF — the
+per-node peak |β| / ν-spread health report needs no record at all
 (``--no-watermarks`` skips it).
 """
 import argparse
@@ -34,8 +35,8 @@ def sync_torus(k: int, kp: float = 2e-8, duration_s: float = 30.0):
     return topo, res, wall
 
 
-def watermark_health(k: int = 100, depth: int = 32):
-    """10^6-node watermark run: sparse engine, NO (R, B, N) record."""
+def watermark_health(k: int = 34, depth: int = 32):
+    """Sparse-engine watermark run at torus3d(k): NO (R, B, N) record."""
     from repro.kernels import simulate_fused
 
     topo = torus3d(k)
@@ -49,8 +50,8 @@ def watermark_health(k: int = 100, depth: int = 32):
                          record_watermarks=True)
     wall = time.time() - t0
     assert res.beta is None  # the whole point: no record materialized
-    # The guard margin needs the dense Laplacian spectrum — 7 TiB at
-    # 10^6 nodes.  Every 3-D torus is 6-regular with k-independent
+    # The guard margin needs the dense Laplacian spectrum of every node —
+    # minutes at 10^4 nodes.  Every 3-D torus is 6-regular with k-independent
     # λ_max, and the slack terms the margin charges (in-flight ν·ω·l
     # coupling, second-order controller products, float32 rounding) are
     # per-node quantities, so a small same-family torus is a faithful
@@ -69,7 +70,7 @@ def main():
     ap = argparse.ArgumentParser()
     ap.add_argument("--k", type=int, default=22)
     ap.add_argument("--no-watermarks", action="store_true",
-                    help="skip the 10^6-node watermark health report")
+                    help="skip the sparse-engine watermark health report")
     args = ap.parse_args()
 
     for k in (6, 10, 14, args.k):

@@ -60,8 +60,8 @@ if [ "$FAST" -eq 1 ]; then
 
     # Sparse-lane smoke: the random-graph property matrix + ELL table
     # unit tests must run even when the caller filtered the main pytest
-    # invocation down to a subset (the 1M-node scale gate itself runs in
-    # the bench smoke below via kernel_sparse_scale's pass_scale field).
+    # invocation down to a subset (the torus3d(34) scale gate itself runs
+    # in the bench smoke below via kernel_sparse_scale's pass_scale field).
     if [ $# -gt 0 ]; then
         python -m pytest -q tests/test_sparse_engine.py
     fi

@@ -1,8 +1,8 @@
 """One-release deprecation machinery for the typed options/telemetry API.
 
 PR 10 replaced the sprawl of boolean engine kwargs (``record_beta``,
-``record_watermarks``, ``trace``, ``auto_reframe``, ``interpret``) with
-the frozen :class:`repro.kernels.EngineOptions` /
+``record_watermarks``, ``trace``, ``auto_reframe``) with the frozen
+:class:`repro.kernels.EngineOptions` /
 :class:`repro.telemetry.Telemetry` objects.  The old kwargs keep working
 for one release; each emits exactly ONE :class:`DeprecationWarning` per
 process (keyed on the kwarg name) and is mapped onto the new object.

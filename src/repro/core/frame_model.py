@@ -296,18 +296,9 @@ def _run_core(src, dst, lat_frames, lam_eff, nu_u, dt_frames, inner,
 _RUN_STATIC = ("ctrl", "num_nodes", "outer", "quantize_beta", "record_beta")
 
 
-def _donate_nu_u():
-    # jax buffer donation is a no-op (warning spam) on CPU; only donate the
-    # state-sized ν_u buffer where the runtime can actually reuse it.
-    # Queried lazily so importing this module never initializes the backend
-    # (which would pin the platform before callers can configure it).
-    return (4,) if jax.default_backend() in ("tpu", "gpu") else ()
-
-
 @functools.lru_cache(maxsize=None)
 def _jitted_run():
-    return partial(jax.jit, static_argnames=_RUN_STATIC,
-                   donate_argnums=_donate_nu_u())(_run_core)
+    return partial(jax.jit, static_argnames=_RUN_STATIC)(_run_core)
 
 
 def _run_ensemble_core(src, dst, lat_frames, lam_eff, nu_u, dt_frames, inner,
@@ -343,8 +334,7 @@ def _run_ensemble_core(src, dst, lat_frames, lam_eff, nu_u, dt_frames, inner,
 
 @functools.lru_cache(maxsize=None)
 def _jitted_run_ensemble():
-    return partial(jax.jit, static_argnames=_RUN_STATIC,
-                   donate_argnums=_donate_nu_u())(_run_ensemble_core)
+    return partial(jax.jit, static_argnames=_RUN_STATIC)(_run_ensemble_core)
 
 
 def _resolve_init(init, nu_default, num_nodes: int, ctrl: ControllerConfig):
@@ -359,10 +349,7 @@ def _resolve_init(init, nu_default, num_nodes: int, ctrl: ControllerConfig):
     """
     if init is None:
         shape = np.shape(nu_default)
-        # nu0 must be a distinct buffer: nu_u is donated on TPU/GPU, and
-        # donating an argument that aliases another is undefined.
-        return (jnp.zeros(shape, jnp.float32),
-                jnp.array(nu_default, copy=True),
+        return (jnp.zeros(shape, jnp.float32), jnp.asarray(nu_default),
                 controller_init(ctrl, num_nodes) if len(shape) == 1 else
                 jax.tree_util.tree_map(
                     lambda z: jnp.broadcast_to(z, shape),

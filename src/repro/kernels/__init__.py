@@ -11,9 +11,10 @@ bittide_step    pl.pallas_call kernels: per-step baseline + fused multi-period
                 one compiled kernel.
 bittide_sparse  edge-major ELL engine: per-node state resident, (K, N) slot
                 tables (neighbor / per-edge latency / weight) streamed in
-                i-panels — O(N·deg) per period for bounded-degree graphs up
-                to ~10⁶ nodes, with per-draw edge weights and fully
-                heterogeneous per-draw latencies as traced inputs.
+                i-panels — O(N·deg) per period for bounded-degree graphs
+                (~5·10⁴ nodes at B = 8 on a TPU), with per-draw edge
+                weights and fully heterogeneous per-draw latencies as
+                traced inputs.
 ops             jit wrappers + topology densification (fixed-class, weighted)
                 + fused/ensemble runners (init-state chaining, per-draw link
                 parameters; DenseResult path metadata + exact .nu)

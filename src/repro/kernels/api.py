@@ -6,16 +6,14 @@ PR 10's API consolidation: engine selection knobs live in the frozen
 and ``BittideNetwork.run_scenario``), and the raw engine lanes return a
 named :class:`EngineOutputs` instead of the positional 5-tuple that had
 to be reshuffled every time a telemetry axis was added.  The old kwargs
-(``engine=``, ``interpret=``, ``chunk_records=``) keep working —
-``interpret=`` with a one-release deprecation warning, the non-boolean
-two silently mapped (see :mod:`repro._compat`).
+(``engine=``, ``chunk_records=``) keep working, mapped silently.
+Whether the kernels run in the Pallas interpreter is no option: the
+backend decides it (``repro.kernels.ops._auto_interpret``).
 """
 from __future__ import annotations
 
 import dataclasses
 from typing import Any, NamedTuple, Optional
-
-from repro._compat import deprecated_kwarg
 
 __all__ = ["EngineOptions", "EngineOutputs", "resolve_options"]
 
@@ -28,8 +26,6 @@ class EngineOptions:
       engine: lane name — "auto" dispatches by shape/degree; explicit
         values are "fused" / "tiled" / "sparse" / "per-step" (and
         "segment-sum" where the scenario runner accepts it).
-      interpret: force the Pallas interpreter (None = auto: interpret
-        off TPU).
       chunk_records: records per kernel launch in the scenario runner
         (None = the runner's default).  With the in-kernel guard this
         is a latency/launch-overhead trade only — a guard trip freezes
@@ -38,7 +34,6 @@ class EngineOptions:
     """
 
     engine: str = "auto"
-    interpret: Optional[bool] = None
     chunk_records: Optional[int] = None
 
 
@@ -61,15 +56,14 @@ class EngineOutputs(NamedTuple):
 
 
 def resolve_options(options: Optional[EngineOptions], caller: str, *,
-                    engine=None, interpret=None, chunk_records=None,
+                    engine=None, chunk_records=None,
                     default_engine: str = "auto") -> EngineOptions:
     """Merge legacy kwargs into an :class:`EngineOptions`.
 
     Legacy values are ``None`` when not passed; a passed value wins over
-    the ``options`` field.  ``interpret=`` (a boolean knob) emits the
-    one-per-process deprecation warning; ``engine=`` / ``chunk_records=``
-    are mapped silently for now (they are not booleans — the warn set is
-    the boolean sprawl the redesign retires).
+    the ``options`` field.  ``engine=`` / ``chunk_records=`` are mapped
+    silently (they are not booleans — the warn set is the boolean sprawl
+    the redesign retires).
     """
     base = options if options is not None else EngineOptions(
         engine=default_engine)
@@ -80,9 +74,6 @@ def resolve_options(options: Optional[EngineOptions], caller: str, *,
     updates = {}
     if engine is not None:
         updates["engine"] = engine
-    if interpret is not None:
-        deprecated_kwarg("interpret=", "options=EngineOptions(interpret=...)")
-        updates["interpret"] = interpret
     if chunk_records is not None:
         updates["chunk_records"] = chunk_records
     return dataclasses.replace(base, **updates) if updates else base

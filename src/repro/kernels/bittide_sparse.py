@@ -15,29 +15,38 @@ control period as K slot gathers over a **slot-major ELL table**:
             − (ψ_i + β_off)·deg_i + lamsum_i,      deg_i = Σ_k w[k,i]
 
 followed by the same cancellation-free controller update as the dense
-kernels.  Per-period cost is O(N·K) — for torus3d(100) (1M nodes, K=6)
-that is ~10⁵× less arithmetic than the dense formulation, lifting the
-node ceiling to 10⁵–10⁶.
+kernels.  Per-period cost is O(N·K) — for torus3d(34) (39,304 nodes,
+K=6) that is ~6,500× less arithmetic than the dense formulation.  On a
+TPU the VMEM-resident state then bounds the node count instead (about
+5·10⁴ nodes at B = 8, see ``sparse_vmem_bytes``).
 
 Layout: slot-major (K, N) rather than node-major (N, K), so every slot
-row is an N-vector aligned with the state's lane axis — the gather is K
-full-row ``jnp.take`` ops and the fold is K fused multiply-adds on
-(B, N) tiles, never a reduction across misaligned K lanes.  Padding
-slots self-index (``nbr[k, i] = i``) with weight 0, so they gather a
-valid address and contribute exactly nothing; padding *nodes* have all
-slots padded (degree 0) and stay inert like the dense lanes' padding.
+row is an N-vector aligned with the state's lane axis and the fold is K
+fused multiply-adds on (B, tile_i) tiles, never a reduction across
+misaligned K lanes.  Padding slots self-index (``nbr[k, i] = i``) with
+weight 0, so they gather a valid address and contribute exactly
+nothing; padding *nodes* have all slots padded (degree 0) and stay inert
+like the dense lanes' padding.
 
 The kernel advances ``num_records × record_every`` periods in ONE
 ``pallas_call`` with grid ``(num_records, record_every, i_panels)``:
 per-node state (ψ, ν) lives whole in VMEM scratch (the gather needs
-every source node), while the neighbor tables stream as (·, K, tile_i)
-node panels whose index map advances with the innermost grid axis —
-double-buffered from HBM like the tiled dense engine's column panels.
-Each panel computes the update for its own node rows into a *staging*
-scratch (gathers must read the pre-period state, so in-place writes
-would corrupt later panels); the last panel of each period commits
-staging → canonical.  With a single panel (tile_i = N) the staging hop
-is skipped and the update writes the canonical scratch directly.
+every source node), while the tables stream as node panels whose index
+map advances with the innermost grid axis — double-buffered from HBM
+like the tiled dense engine's column panels, the slot table into SMEM
+(its entries are gather addresses) and the latency/weight tables into
+VMEM.
+
+The gather.  Mosaic lowers no lane-axis gather over a whole (B, N) row
+(its only gather is a within-vreg ``take_along_axis``), so the first
+panel of every pass snapshots the state into a node-major *mirror*
+(N, W): row j holds node j's ψ in lanes [0, B) and its ν in lanes
+[B, 2B) (W = 2B rounded up to TILE), written one (TILE, TILE) transpose
+at a time.  A slot's gather is then one dynamic sublane read per
+destination node — ``mirror[nbr[k, r]]`` with the index from SMEM —
+into a (tile_i, W) row buffer, transposed back to (2B, tile_i).  The
+mirror is also the pass's snapshot of the pre-period state, so every
+panel updates its own columns of the canonical carries in place.
 
 Everything the dense lanes trace is traced here too — state, per-draw
 gains, per-draw controller masks, per-draw λeff folds — plus the
@@ -55,9 +64,9 @@ sums O(ψ spread)).  The edge-major layout also makes a per-EDGE β
 record a natural follow-on — β_e is the k-th gather term per slot
 before the Σ_k fold — the record shape (K, N) is the table shape.
 
-On CPU the kernel runs the Pallas interpreter; the lane gathers lower
-through Mosaic's dynamic-gather support on TPU (TPU validation is a
-ROADMAP item, as for the dense lanes).
+On CPU the kernel runs the Pallas interpreter; on TPU the same body
+compiles with Mosaic (``tests/test_tpu_compile.py`` compiles it for a
+v5e at Fig-18 scale, ``chip_smoke.py`` runs it on one).
 """
 from __future__ import annotations
 
@@ -72,9 +81,10 @@ from jax.experimental.pallas import tpu as pltpu
 
 from repro.core.topology import Topology
 
-from .bittide_step import (SUBLANE, TILE, VMEM_BUDGET_BYTES, _check_shapes,
-                           _gain_col, _guard_cols, _lamsum_rows, _mask_row,
-                           _split_outputs, sparse_vmem_bytes)
+from .bittide_step import (COMPILER_PARAMS, SUBLANE, TILE, VMEM_BUDGET_BYTES,
+                           _check_shapes, _gain_col, _guard_cols,
+                           _lamsum_rows, _mask_row, _split_outputs,
+                           sparse_vmem_bytes)
 
 __all__ = ["bittide_sparse_pallas", "ellify", "max_in_degree"]
 
@@ -158,15 +168,19 @@ def ellify(topo: Topology, lat_frames, edge_w=None, tile: int = TILE,
     return jnp.asarray(nbr), jnp.asarray(latf), jnp.asarray(wt)
 
 
+def _mirror_width(b: int) -> int:
+    """Lane width W of the node-major mirror: ψ and ν of B draws."""
+    return -(-2 * b // TILE) * TILE
+
+
 def _sparse_kernel(nbr_ref, latf_ref, w_ref, psi0_ref, nu0_ref, nu_u_ref,
                    kp_ref, boff_ref, mask_ref, lamsum_ref, *rest,
-                   dt_frames: float, max_deg: int, multi_panel: bool,
+                   dt_frames: float, max_deg: int,
                    record_beta: bool, record_watermarks: bool,
                    record_guard: bool):
     t = pl.program_id(0)
     p = pl.program_id(1)
     i = pl.program_id(2)
-    i_panels = pl.num_programs(2)
     # With β recording (watermarks, or the in-kernel guard) the period
     # axis carries one extra trailing pass per record: p < periods
     # advances the state, p == periods re-streams the table panels to
@@ -185,9 +199,9 @@ def _sparse_kernel(nbr_ref, latf_ref, w_ref, psi0_ref, nu0_ref, nu_u_ref,
         wm_beta_ref, wm_idx_ref, wm_lo_ref, wm_hi_ref = refs[:4]
         refs = refs[4:]
     trip_ref = refs.pop(0) if record_guard else None
-    psi_s, nu_s = refs.pop(0), refs.pop(0)
-    if multi_panel:
-        psi_ns, nu_ns = refs.pop(0), refs.pop(0)
+    psi_s, nu_s, mir_s, row_s = refs
+    b, n = psi_s.shape
+    tile_i = row_s.shape[0]
 
     first = jnp.logical_and(t == 0, jnp.logical_and(p == 0, i == 0))
 
@@ -200,31 +214,59 @@ def _sparse_kernel(nbr_ref, latf_ref, w_ref, psi0_ref, nu0_ref, nu_u_ref,
             trip_ref[...] = jnp.full(trip_ref.shape, pl.num_programs(0),
                                      jnp.int32)
 
+    def _snapshot():
+        """Node-major mirror of the pass's input state (see module doc)."""
+        pad = mir_s.shape[1] - 2 * b
+
+        def chunk(c, carry):
+            at = pl.multiple_of(c * TILE, TILE)
+            parts = [psi_s[:, pl.ds(at, TILE)], nu_s[:, pl.ds(at, TILE)]]
+            if pad:
+                parts.append(jnp.zeros((pad, TILE), jnp.float32))
+            mir_s[pl.ds(at, TILE), :] = jnp.concatenate(parts, axis=0).T
+            return carry
+
+        jax.lax.fori_loop(0, n // TILE, chunk, 0)
+
+    def _gather(k: int):
+        """(W, tile_i): slot k's source ψ in rows [0, B), ν in [B, 2B)."""
+        def rows(r8, carry):
+            # Mosaic unrolls only fully, so unroll one sublane by hand.
+            for u in range(SUBLANE):
+                r = r8 * SUBLANE + u
+                row_s[pl.ds(r, 1), :] = mir_s[pl.ds(nbr_ref[k, r], 1), :]
+            return carry
+
+        jax.lax.fori_loop(0, tile_i // SUBLANE, rows, 0)
+        return row_s[...].T
+
     def _step():
-        tile_i = nbr_ref.shape[-1]
         cols = pl.ds(pl.multiple_of(i * tile_i, TILE), tile_i)
-        psi_full = psi_s[...]                              # (B, N)
-        nu_full = nu_s[...]
+
+        @pl.when(i == 0)
+        def _mirror():
+            _snapshot()
+
         if measure:
             # β pass: center ψ by its full-row mean (β is exactly
             # shift-invariant; centering keeps float32 partial sums O(ψ
             # spread)).  The mean is over the whole scratch row, so every
             # panel of the pass — and every engine — subtracts the same
             # constant.
-            m = jnp.mean(psi_full, axis=1, keepdims=True)  # (B, 1)
-            psi_full = jnp.where(p == periods, psi_full - m, psi_full)
+            m = jnp.mean(psi_s[...], axis=1, keepdims=True)  # (B, 1)
 
         # K slot gathers over the streamed (·, K, tile_i) table panel:
-        # each slot row pulls its source nodes' state from the whole-row
-        # scratch and folds one weighted FMA into the panel's
-        # accumulation.
+        # each slot row pulls its source nodes' state from the mirror
+        # and folds one weighted FMA into the panel's accumulation.
         lat = latf_ref[...]                                # (·, K, TI)
         w = w_ref[...]
         deg = jnp.sum(w, axis=1)                           # (·, TI)
-        acc = jnp.zeros((psi_full.shape[0], tile_i), jnp.float32)
+        acc = jnp.zeros((b, tile_i), jnp.float32)
         for k in range(max_deg):
-            g_psi = jnp.take(psi_full, nbr_ref[k], axis=1)  # (B, TI)
-            g_nu = jnp.take(nu_full, nbr_ref[k], axis=1)
+            g = _gather(k)
+            g_psi, g_nu = g[:b], g[b:2 * b]                # (B, TI)
+            if measure:
+                g_psi = jnp.where(p == periods, g_psi - m, g_psi)
             acc = acc + w[:, k, :] * (g_psi - g_nu * lat[:, k, :])
 
         psi_i = psi_s[:, cols]                             # (B, TI)
@@ -244,27 +286,16 @@ def _sparse_kernel(nbr_ref, latf_ref, w_ref, psi0_ref, nu0_ref, nu_u_ref,
             # Holdover: masked-out nodes freeze ν at its previous value.
             nu_next = jnp.where(mask_ref[...] > 0.5, nu_next, nu_i)
             psi_next = psi_i + nu_next * dt_frames
-            if multi_panel:
-                # Gathers must read the pre-period state, so panel
-                # updates stage until every panel of this period has
-                # aggregated.
-                psi_ns[:, cols] = psi_next
-                nu_ns[:, cols] = nu_next
-            else:
-                psi_s[:, cols] = psi_next
-                nu_s[:, cols] = nu_next
+            # In place: later panels gather from the mirror's snapshot of
+            # the pre-period state, never from these columns.
+            psi_s[:, cols] = psi_next
+            nu_s[:, cols] = nu_next
             # Telemetry flushes to HBM when the record index advances, so
             # overwriting every period within a record is decimation for
             # free.
-            rec_ref[...] = nu_next[None]
-            psi_out_ref[...] = psi_next
-            nu_out_ref[...] = nu_next
-
-        if multi_panel:
-            @pl.when(jnp.logical_and(p < periods, i == i_panels - 1))
-            def _commit():
-                psi_s[...] = psi_ns[...]
-                nu_s[...] = nu_ns[...]
+            rec_ref[0, :, cols] = nu_next
+            psi_out_ref[:, cols] = psi_next
+            nu_out_ref[:, cols] = nu_next
 
         if measure:
             @pl.when(p == periods)
@@ -272,7 +303,7 @@ def _sparse_kernel(nbr_ref, latf_ref, w_ref, psi0_ref, nu0_ref, nu_u_ref,
                 # acc aggregated the centered post-update state this pass.
                 bnode = acc - psi_i * deg + lamsum_ref[...]
                 if record_beta:
-                    brec_ref[...] = bnode[None]
+                    brec_ref[0, :, cols] = bnode
                 if record_watermarks:
                     # Watermark accumulators are whole (B, N) output
                     # blocks with CONSTANT index maps (VMEM-resident for
@@ -355,8 +386,8 @@ def bittide_sparse_pallas(psi, nu, nu_u, nbr, latf, w, lamsum, kp, beta_off,
         switch; the ν-only grid is unchanged when off).
       record_watermarks: carry O(B·N) excursion watermarks in-kernel —
         per-node max |β|, its record index, and the ν min/max — updated
-        at every record from the same β aggregation pass, so a 1M-node
-        run reports its peak excursion with NO (R, B, N) record.  Shares
+        at every record from the same β aggregation pass, so a run
+        reports its peak excursion with NO (R, B, N) record.  Shares
         the extra table pass with ``record_beta`` when both are on.
       record_guard: in-kernel reframing guard with chunk early-exit —
         shares the measure pass, adds a (B, 1) int32 first-trip-record
@@ -393,7 +424,9 @@ def bittide_sparse_pallas(psi, nu, nu_u, nbr, latf, w, lamsum, kp, beta_off,
             f"tile_i={tile_i} must be a multiple of {TILE} dividing N={n}")
     i_panels = n // tile_i
     rows = max(latf.shape[0], w.shape[0])
-    vmem = sparse_vmem_bytes(b, n, k, tile_i, rows)
+    vmem = sparse_vmem_bytes(b, n, k, tile_i, rows, record_beta=record_beta,
+                             record_watermarks=record_watermarks,
+                             record_guard=record_guard)
     if vmem > VMEM_BUDGET_BYTES and not interpret:
         raise ValueError(
             f"sparse working set {vmem/2**20:.1f} MiB exceeds the "
@@ -401,20 +434,25 @@ def bittide_sparse_pallas(psi, nu, nu_u, nbr, latf, w, lamsum, kp, beta_off,
             f"K={k}, tile_i={tile_i}); the O(B·N) state must stay resident "
             "— shard the node axis or use the segment-sum simulator")
 
-    multi_panel = i_panels > 1
     kern = functools.partial(
         _sparse_kernel, dt_frames=float(dt_frames), max_deg=int(k),
-        multi_panel=multi_panel, record_beta=bool(record_beta),
+        record_beta=bool(record_beta),
         record_watermarks=bool(record_watermarks),
         record_guard=bool(record_guard))
 
     mask = _mask_row(ctrl_mask, n, b)
     full3 = lambda t, p, i: (0, 0)
     panel2 = lambda t, p, i: (0, i)
+    record = lambda t, p, i: (t, 0, 0)
+    # Every output block is whole-row: the pipeline writes an output
+    # block back each time its index changes and never reads it in, so a
+    # panel-wide block revisited on the next pass (or skipped by a guard
+    # freeze) would flush a stale buffer over the panel's results.  Each
+    # panel writes its own columns of the resident block instead.
     out_specs = [
-        pl.BlockSpec((b, tile_i), panel2),                    # psi final
-        pl.BlockSpec((b, tile_i), panel2),                    # nu final
-        pl.BlockSpec((1, b, tile_i), lambda t, p, i: (t, 0, i)),  # ν rec
+        pl.BlockSpec((b, n), full3),                          # psi final
+        pl.BlockSpec((b, n), full3),                          # nu final
+        pl.BlockSpec((1, b, n), record),                      # ν record
     ]
     out_shape = [
         jax.ShapeDtypeStruct((b, n), jnp.float32),
@@ -422,14 +460,13 @@ def bittide_sparse_pallas(psi, nu, nu_u, nbr, latf, w, lamsum, kp, beta_off,
         jax.ShapeDtypeStruct((num_records, b, n), jnp.float32),
     ]
     if record_beta:
-        out_specs.append(
-            pl.BlockSpec((1, b, tile_i), lambda t, p, i: (t, 0, i)))
+        out_specs.append(pl.BlockSpec((1, b, n), record))
         out_shape.append(
             jax.ShapeDtypeStruct((num_records, b, n), jnp.float32))
     if record_watermarks:
         # Whole-row (B, N) accumulators with constant index maps: they
-        # stay VMEM-resident across the grid (like the ψ/ν carries) and
-        # each panel read-modify-writes its own columns.
+        # stay VMEM-resident across the grid and each panel
+        # read-modify-writes its own columns.
         for dt_ in (jnp.float32, jnp.int32, jnp.float32, jnp.float32):
             out_specs.append(pl.BlockSpec((b, n), full3))
             out_shape.append(jax.ShapeDtypeStruct((b, n), dt_))
@@ -441,17 +478,16 @@ def bittide_sparse_pallas(psi, nu, nu_u, nbr, latf, w, lamsum, kp, beta_off,
     scratch = [
         pltpu.VMEM((b, n), jnp.float32),                      # ψ carry
         pltpu.VMEM((b, n), jnp.float32),                      # ν carry
+        pltpu.VMEM((n, _mirror_width(b)), jnp.float32),       # mirror
+        pltpu.VMEM((tile_i, _mirror_width(b)), jnp.float32),  # gathered
     ]
-    if multi_panel:
-        scratch += [
-            pltpu.VMEM((b, n), jnp.float32),                  # ψ staging
-            pltpu.VMEM((b, n), jnp.float32),                  # ν staging
-        ]
     in_specs = [
         # Table panels: the index map advances with i, so the Pallas
         # pipeline double-buffers the HBM fetch of panel i+1 behind
-        # the gathers on panel i.
-        pl.BlockSpec((k, tile_i), panel2),                # nbr
+        # the gathers on panel i.  Slot entries are gather addresses,
+        # read as scalars, so their panel lands in SMEM.
+        pl.BlockSpec((k, tile_i), panel2,
+                     memory_space=pltpu.SMEM),            # nbr
         pl.BlockSpec((latf.shape[0], k, tile_i),
                      lambda t, p, i: (0, 0, i)),          # latf
         pl.BlockSpec((w.shape[0], k, tile_i),
@@ -483,6 +519,7 @@ def bittide_sparse_pallas(psi, nu, nu_u, nbr, latf, w, lamsum, kp, beta_off,
         out_specs=out_specs,
         out_shape=out_shape,
         scratch_shapes=scratch,
+        compiler_params=COMPILER_PARAMS,
         interpret=interpret,
     )(*args)
     return _split_outputs(out, record_beta, record_watermarks, record_guard)

@@ -111,14 +111,26 @@ from .api import EngineOutputs
 __all__ = ["bittide_step_pallas", "bittide_fused_pallas",
            "bittide_tiled_fused_pallas", "select_engine", "fused_vmem_bytes",
            "tiled_vmem_bytes", "sparse_vmem_bytes", "TILE", "SUBLANE",
-           "VMEM_BUDGET_BYTES", "RESIDENT_N_MAX", "TILE_J_MAX"]
+           "sparse_panel", "VMEM_LIMIT_BYTES", "VMEM_BUDGET_BYTES",
+           "RESIDENT_N_MAX", "TILE_J_MAX", "COMPILER_PARAMS"]
 
 TILE = 128     # MXU/VPU-aligned tile edge (lane axis)
 SUBLANE = 8    # float32 sublane quantum (batch axis of the fused kernel)
 
-# Conservative per-core VMEM budget for the fused kernel's resident set
-# (real TPU cores have ~16 MB; leave headroom for Mosaic's own buffers).
-VMEM_BUDGET_BYTES = 14 * 1024 * 1024
+# Scoped-VMEM limit every pallas_call compiles against.  Mosaic's default
+# scoped limit (16 MiB on v5e) is a compiler setting, not the size of the
+# core's VMEM (128 MiB on v5e/v6e): the telemetry variants of the tiled
+# lane at Fig-18 scale need more than the default, so every kernel passes
+# this limit explicitly.
+VMEM_LIMIT_BYTES = 64 * 1024 * 1024
+# What the estimators below may fill: the remaining quarter is headroom
+# for Mosaic's internal scratch (spilled (B, N) temporaries).
+VMEM_BUDGET_BYTES = VMEM_LIMIT_BYTES * 3 // 4
+COMPILER_PARAMS = pltpu.CompilerParams(vmem_limit_bytes=VMEM_LIMIT_BYTES)
+# Full-f32 MXU passes for every aggregation: at Mosaic's default f32
+# contraction precision the fused lane ran 4e-3 ppm (a bf16-scale error)
+# from segment-sum on a v5e — ψ is not mean-centred in the period update.
+_F32_MXU = jax.lax.Precision.HIGHEST
 
 # --- tile-size heuristic for engine dispatch (see `select_engine`) -------
 # Keep the whole (C, N, N) adjacency VMEM-resident only up to this padded
@@ -126,8 +138,8 @@ VMEM_BUDGET_BYTES = 14 * 1024 * 1024
 # residency stops paying once the stack dominates VMEM, while streaming
 # bounds the footprint and leaves headroom for batch/gain axes.  The
 # trade-off is that streamed panels are re-fetched every control period —
-# the cutoffs are CPU-validated defaults; tuning them against measured
-# HBM bandwidth on real TPU hardware is a ROADMAP item.
+# the cutoffs have not been measured on a chip yet; tuning them against
+# measured HBM bandwidth is a ROADMAP item.
 RESIDENT_N_MAX = 2 * TILE
 # Widest streamed panel (2 MXU tiles): wide enough to amortize the DMA,
 # narrow enough that the double-buffered pair stays a small VMEM fraction.
@@ -147,6 +159,7 @@ def _kernel(lat_ref, a_ref, psi_j_ref, nu_j_ref, psi_i_ref, nu_i_ref,
         partial = jax.lax.dot_general(
             a_ref[c], x[0],
             dimension_numbers=(((1,), (0,)), ((), ())),
+            precision=_F32_MXU,
             preferred_element_type=jnp.float32)                    # (TI,)
         acc = acc + partial[None, :]
 
@@ -258,6 +271,7 @@ def bittide_step_pallas(psi, nu, nu_u, a, lam_eff, lat_frames,
         ],
         out_specs=out_specs,
         out_shape=out_shape,
+        compiler_params=COMPILER_PARAMS,
         interpret=interpret,
     )(lat_frames.reshape(c, 1).astype(jnp.float32),
       a.astype(jnp.float32), row(psi), row(nu), row(psi), row(nu),
@@ -321,6 +335,7 @@ def _fused_kernel(lat_ref, a_ref, psi0_ref, nu0_ref, nu_u_ref, kp_ref,
             acc = acc + jax.lax.dot_general(
                 x, a_ref[c],
                 dimension_numbers=(((1,), (1,)), ((), ())),
+                precision=_F32_MXU,
                 preferred_element_type=jnp.float32)
         err = acc - (psi + beta_off) * deg + lamsum
         c_rel = kp * err
@@ -357,6 +372,7 @@ def _fused_kernel(lat_ref, a_ref, psi0_ref, nu0_ref, nu_u_ref, kp_ref,
                 bacc = bacc + jax.lax.dot_general(
                     x, a_ref[c],
                     dimension_numbers=(((1,), (1,)), ((), ())),
+                    precision=_F32_MXU,
                     preferred_element_type=jnp.float32)
             bnode = bacc - psi_c * deg + lamsum
             if record_beta:
@@ -413,53 +429,87 @@ def _fused_kernel(lat_ref, a_ref, psi0_ref, nu0_ref, nu_u_ref, kp_ref,
         _advance()
 
 
-def fused_vmem_bytes(b: int, n: int, c: int) -> int:
-    """Resident-set estimate for the fused kernel (adjacency + state)."""
-    return 4 * (c * n * n          # A stack
-                + 5 * b * n        # psi0/nu0/nu_u inputs + 2 scratch
-                + 3 * b * n        # psi/nu outputs + one record block
-                + b * n            # per-draw lamsum rows
-                + 2 * b            # kp, beta_off gain columns
-                + b * c            # per-draw class latencies
-                + 2 * n)           # deg, ctrl mask
+def _block_bytes(*shape: int) -> int:
+    """VMEM bytes of one 32-bit block: its last two dims pad to the
+    (SUBLANE, TILE) tile, so a (1, N) row costs as much as (8, N)."""
+    *lead, rows, cols = shape
+    size = 4 * (-(-rows // SUBLANE) * SUBLANE) * (-(-cols // TILE) * TILE)
+    for d in lead:
+        size *= d
+    return size
 
 
-def tiled_vmem_bytes(b: int, n: int, c: int, tile_j: int) -> int:
-    """Working-set estimate for the tiled engine (panels + state).
+def _working_set(operands, scratch) -> int:
+    """Pipelined operands (every blocked input and output) get two
+    buffers each — even constant-index blocks; scratch gets one."""
+    return (2 * sum(_block_bytes(*s) for s in operands)
+            + sum(_block_bytes(*s) for s in scratch))
 
-    The adjacency contributes one (C, N, tile_j) column panel ×2 for the
-    pipeline's double buffering instead of the full (C, N, N) stack.
-    """
-    return 4 * (2 * c * n * tile_j  # double-buffered A panels
-                + 5 * b * n         # psi0/nu0/nu_u inputs + psi/nu scratch
-                + b * n             # accumulator scratch
-                + 3 * b * n         # psi/nu outputs + one record block
-                + b * n             # per-draw lamsum rows
-                + 2 * b             # kp, beta_off gain columns
-                + b * c             # per-draw class latencies
-                + 2 * n)            # deg, ctrl mask
+
+def _state_operands(b: int, n: int, panel: int, record_beta: bool,
+                    record_watermarks: bool, record_guard: bool):
+    """The blocks every batched lane pipelines besides its adjacency:
+    ψ0/ν0 whole rows, per-node ``panel``-wide ν_u / mask / λeff fold,
+    gain columns, whole-row ψ/ν/ν-record outputs, and the telemetry
+    variant's β record block, four (B, N) watermark accumulators, and
+    guard band / stop inputs plus the trip output."""
+    ops = [(b, n), (b, n), (b, panel), (b, panel), (b, panel),
+           (b, 1), (b, 1), (b, n), (b, n), (1, b, n)]
+    if record_beta:
+        ops.append((1, b, n))
+    if record_watermarks:
+        ops += [(b, n)] * 4
+    if record_guard:
+        ops += [(b, 1)] * 4
+    return ops
+
+
+def fused_vmem_bytes(b: int, n: int, c: int, *, record_beta: bool = False,
+                     record_watermarks: bool = False,
+                     record_guard: bool = False) -> int:
+    """Working set of the fused kernel variant: the resident (C, N, N)
+    stack, state, telemetry outputs and the ψ/ν scratch carries."""
+    ops = [(b, c), (c, n, n), (1, n)] + _state_operands(
+        b, n, n, record_beta, record_watermarks, record_guard)
+    return _working_set(ops, [(b, n)] * 2)
+
+
+def tiled_vmem_bytes(b: int, n: int, c: int, tile_j: int, *,
+                     record_beta: bool = False,
+                     record_watermarks: bool = False,
+                     record_guard: bool = False) -> int:
+    """Working set of the tiled kernel variant: one double-buffered
+    (C, N, tile_j) column panel instead of the whole stack, plus state,
+    telemetry outputs and the ψ/ν/accumulator scratch."""
+    ops = [(b, c), (c, n, tile_j), (1, n)] + _state_operands(
+        b, n, n, record_beta, record_watermarks, record_guard)
+    return _working_set(ops, [(b, n)] * 3)
 
 
 def sparse_vmem_bytes(b: int, n: int, k: int, tile_i: int,
-                      table_rows: int = 1) -> int:
-    """Working-set estimate for the sparse ELL engine.
+                      table_rows: int = 1, *, record_beta: bool = False,
+                      record_watermarks: bool = False,
+                      record_guard: bool = False) -> int:
+    """Working set of the sparse ELL kernel variant.
 
-    Per-node state (ψ/ν carries, staging, inputs, outputs) is fully
-    VMEM-resident — the gather needs every source node — while the
-    slot-major neighbor tables stream as (·, K, tile_i) row panels, ×2
-    for the pipeline's double buffering.  ``table_rows`` is the tables'
-    leading axis: 1 shared, B with per-draw latencies/weights.
+    The ψ/ν carries stay whole-row resident and the gather reads a
+    node-major (N, W) mirror of them (W = 2B lanes rounded up to TILE)
+    through a (tile_i, W) row buffer; the latency/weight tables stream
+    as (·, K, tile_i) panels (``table_rows`` is their leading axis: 1
+    shared, B per-draw).  The slot table itself streams into SMEM and
+    costs no VMEM.
     """
-    return 4 * (6 * b * n               # ψ/ν carry + staging + psi0/nu0
-                + 2 * b * n             # psi/nu final outputs
-                + 2 * (1 + 2 * table_rows) * k * tile_i  # nbr+latf+w panels
-                + 4 * b * tile_i        # nu_u/lamsum/rec panels + mask
-                + 2 * b)                # kp, beta_off gain columns
+    w = -(-2 * b // TILE) * TILE
+    ops = [(table_rows, k, tile_i)] * 2 + _state_operands(
+        b, n, tile_i, record_beta, record_watermarks, record_guard)
+    return _working_set(ops, [(b, n), (b, n), (n, w), (tile_i, w)])
 
 
 def select_engine(b: int, n: int, c: int,
                   vmem_budget: int = VMEM_BUDGET_BYTES,
-                  max_deg=None):
+                  max_deg=None, *, record_beta: bool = False,
+                  record_watermarks: bool = False,
+                  record_guard: bool = False):
     """Tile-size dispatch heuristic: (engine, tile_j) for padded (B, N, C).
 
     Replaces the old VMEM cliff (fused-or-per-step-fallback) with four
@@ -475,29 +525,49 @@ def select_engine(b: int, n: int, c: int,
       cost drops from O(N²) to O(N·K) with the slot-major neighbor
       tables streamed in (·, K, ti) node panels.  Chosen when every dense
       working set is over budget but the O(B·N) resident state still
-      fits — the 10⁵–10⁶-node bounded-degree regime.
+      fits — bounded-degree graphs past the dense lanes' reach.
     - ``("per-step", 0)`` — nothing fits (huge C·N, no degree bound);
       the per-period tiled 2-D kernel is the only option left.
 
     Callers without neighbor-table information omit ``max_deg`` and get
-    the historical three-regime behavior unchanged.
+    the historical three-regime behavior unchanged.  The telemetry flags
+    name the kernel variant that will run: its β record, watermark and
+    guard buffers count against the budget, so the lane and tile chosen
+    are the ones that compile for that variant.
     """
-    if n <= RESIDENT_N_MAX and fused_vmem_bytes(b, n, c) <= vmem_budget:
+    tel = dict(record_beta=record_beta, record_watermarks=record_watermarks,
+               record_guard=record_guard)
+    if n <= RESIDENT_N_MAX and fused_vmem_bytes(b, n, c, **tel) <= vmem_budget:
         return "fused", n
     tj = min(n, TILE_J_MAX)
     while tj >= TILE:
-        if n % tj == 0 and tiled_vmem_bytes(b, n, c, tj) <= vmem_budget:
+        if n % tj == 0 and tiled_vmem_bytes(b, n, c, tj,
+                                            **tel) <= vmem_budget:
             return "tiled", tj
         tj -= TILE
     if max_deg is not None:
-        ti = min(n, TILE_J_MAX)
-        while ti >= TILE:
-            if (n % ti == 0
-                    and sparse_vmem_bytes(b, n, int(max_deg), ti)
-                    <= vmem_budget):
-                return "sparse", ti
-            ti -= TILE
+        ti = sparse_panel(b, n, int(max_deg), vmem_budget=vmem_budget, **tel)
+        if ti:
+            return "sparse", ti
     return "per-step", 0
+
+
+def sparse_panel(b: int, n: int, k: int, table_rows: int = 1,
+                 vmem_budget: int = VMEM_BUDGET_BYTES, **telemetry):
+    """Node-panel width of the sparse ELL kernel variant: the widest
+    multiple of TILE dividing padded N, at most TILE_J_MAX, whose working
+    set fits ``vmem_budget`` — or None when none does.  Wider panels only
+    grow the gather's (tile_i, W) row buffer and its transposes.  The
+    choice is the same under the interpreter as on the chip, so CPU runs
+    take the panel layout the chip will.  ``telemetry`` holds the
+    kernel's ``record_*`` flags."""
+    ti = min(n, TILE_J_MAX)
+    while ti >= TILE:
+        if n % ti == 0 and sparse_vmem_bytes(
+                b, n, k, ti, table_rows, **telemetry) <= vmem_budget:
+            return ti
+        ti -= TILE
+    return None
 
 
 def _gain_col(v, b: int, name: str):
@@ -626,7 +696,9 @@ def bittide_fused_pallas(psi, nu, nu_u, a, deg, lamsum, lat_frames,
     b, n = psi.shape
     c = a.shape[0]
     _check_shapes(b, n, num_records, record_every)
-    vmem = fused_vmem_bytes(b, n, c)
+    vmem = fused_vmem_bytes(b, n, c, record_beta=record_beta,
+                            record_watermarks=record_watermarks,
+                            record_guard=record_guard)
     if vmem > VMEM_BUDGET_BYTES and not interpret:
         raise ValueError(
             f"fused kernel resident set {vmem/2**20:.1f} MiB exceeds the "
@@ -701,6 +773,7 @@ def bittide_fused_pallas(psi, nu, nu_u, a, deg, lamsum, lat_frames,
             pltpu.VMEM((b, n), jnp.float32),             # ψ carry
             pltpu.VMEM((b, n), jnp.float32),             # ν carry
         ],
+        compiler_params=COMPILER_PARAMS,
         interpret=interpret,
     )(*args)
     return _split_outputs(out, record_beta, record_watermarks, record_guard)
@@ -804,6 +877,7 @@ def _tiled_kernel(lat_ref, a_ref, psi0_ref, nu0_ref, nu_u_ref, kp_ref,
             partial = partial + jax.lax.dot_general(
                 x, a_ref[c],
                 dimension_numbers=(((1,), (1,)), ((), ())),
+                precision=_F32_MXU,
                 preferred_element_type=jnp.float32)
 
         @pl.when(j == 0)
@@ -930,7 +1004,9 @@ def bittide_tiled_fused_pallas(psi, nu, nu_u, a, deg, lamsum, lat_frames,
         raise ValueError(
             f"tile_j={tile_j} must be a multiple of {TILE} dividing N={n}")
     j_tiles = n // tile_j
-    vmem = tiled_vmem_bytes(b, n, c, tile_j)
+    vmem = tiled_vmem_bytes(b, n, c, tile_j, record_beta=record_beta,
+                            record_watermarks=record_watermarks,
+                            record_guard=record_guard)
     if vmem > VMEM_BUDGET_BYTES and not interpret:
         raise ValueError(
             f"tiled working set {vmem/2**20:.1f} MiB exceeds the "
@@ -1006,6 +1082,7 @@ def bittide_tiled_fused_pallas(psi, nu, nu_u, a, deg, lamsum, lat_frames,
             pltpu.VMEM((b, n), jnp.float32),               # ν carry
             pltpu.VMEM((b, n), jnp.float32),               # err accumulator
         ],
+        compiler_params=COMPILER_PARAMS,
         interpret=interpret,
     )(*args)
     return _split_outputs(out, record_beta, record_watermarks, record_guard)

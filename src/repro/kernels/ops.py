@@ -49,8 +49,10 @@ the dense lane requires a shared class structure (one latency per class
 per draw); fully heterogeneous per-draw links run on the segment-sum
 lane in ``repro.core.frame_model``.
 
-On CPU (this container) the kernels run in interpret mode; on TPU the same
-code path compiles to Mosaic.  `interpret=None` auto-detects.
+Interpret mode is a property of the backend, decided in one place
+(:func:`_auto_interpret`): off a TPU the kernels run in the Pallas
+interpreter, on a TPU the same code compiles to Mosaic and never runs in
+the interpreter.
 """
 from __future__ import annotations
 
@@ -69,10 +71,9 @@ from repro.telemetry.watermarks import Watermarks
 
 from .api import EngineOutputs, resolve_options
 from .bittide_sparse import bittide_sparse_pallas, ellify, max_in_degree
-from .bittide_step import (SUBLANE, TILE, TILE_J_MAX, VMEM_BUDGET_BYTES,
-                           bittide_fused_pallas, bittide_step_pallas,
-                           bittide_tiled_fused_pallas, select_engine,
-                           sparse_vmem_bytes)
+from .bittide_step import (SUBLANE, TILE, bittide_fused_pallas,
+                           bittide_step_pallas, bittide_tiled_fused_pallas,
+                           select_engine, sparse_panel)
 from .ref import (bittide_dense_multistep_ref, bittide_dense_step_ref,
                   node_occupancy_ref)
 
@@ -86,10 +87,12 @@ __all__ = ["densify", "latency_classes", "bittide_step", "simulate_dense",
 MAX_EXACT_CLASSES = 8
 
 
-def _auto_interpret(interpret: Optional[bool]) -> bool:
-    if interpret is None:
-        return jax.default_backend() != "tpu"
-    return interpret
+def _auto_interpret() -> bool:
+    """Whether the Pallas kernels run in the interpreter: exactly when
+    the default backend is not a TPU.  No caller chooses — the
+    interpreter never stands in for Mosaic on a TPU, and Mosaic cannot
+    run anywhere else."""
+    return jax.default_backend() != "tpu"
 
 
 class DenseResult(tuple):
@@ -244,10 +247,9 @@ def densify(topo: Topology, links: LinkParams, omega_nom: float = OMEGA_NOM,
 
 
 @functools.partial(jax.jit, static_argnames=("kp", "beta_off", "dt_frames",
-                                             "interpret", "use_ref"))
+                                             "use_ref"))
 def bittide_step(psi, nu, nu_u, a, lam_eff, lat, kp, beta_off, dt_frames,
-                 interpret: bool = True, use_ref: bool = False,
-                 ctrl_mask=None):
+                 use_ref: bool = False, ctrl_mask=None):
     """One control period (per-step baseline path).
 
     Args:
@@ -270,7 +272,7 @@ def bittide_step(psi, nu, nu_u, a, lam_eff, lat, kp, beta_off, dt_frames,
         return psi2, nu2
     return bittide_step_pallas(psi, nu, nu_u, a, lam_eff, lat,
                                kp, beta_off, dt_frames, ctrl_mask=ctrl_mask,
-                               interpret=interpret)
+                               interpret=_auto_interpret())
 
 
 @functools.partial(jax.jit, static_argnames=("dt_frames", "num_records",
@@ -660,27 +662,6 @@ def _lamsum_host(topo: Topology, beta0: np.ndarray, edge_w, b_rows: int,
     return out.astype(np.float32)
 
 
-def _sparse_tile(b_pad: int, n_pad: int, k: int, rows: int,
-                 interp: bool) -> int:
-    """Default node-panel width for the sparse engine.
-
-    Single panel (tables resident alongside the state) whenever the
-    working set fits — or always under interpret, where VMEM is not
-    enforced; otherwise the widest multiple of TILE dividing N that
-    fits the budget (falling back to TILE and letting the kernel's own
-    VMEM check raise if even that cannot fit)."""
-    if interp or sparse_vmem_bytes(b_pad, n_pad, k, n_pad,
-                                   rows) <= VMEM_BUDGET_BYTES:
-        return n_pad
-    ti = min(n_pad, TILE_J_MAX)
-    while ti > TILE:
-        if n_pad % ti == 0 and sparse_vmem_bytes(
-                b_pad, n_pad, k, ti, rows) <= VMEM_BUDGET_BYTES:
-            return ti
-        ti -= TILE
-    return TILE
-
-
 def _host_watermarks(wm_dev, num_records: int, b: Optional[int],
                      n: int) -> Watermarks:
     """Device watermark tuple -> host :class:`Watermarks`.
@@ -739,7 +720,8 @@ def _run_sparse(topo: Topology, lat_be, beta0_be, beta0_batched: bool,
     k = nbr.shape[0]
     rows_t = max(latf.shape[0], w.shape[0])
     ti = (int(tile_j) if tile_j is not None
-          else _sparse_tile(b_pad, n_pad, k, rows_t, interp))
+          else sparse_panel(b_pad, n_pad, k, rows_t, record_beta=record_beta,
+                            record_watermarks=record_watermarks) or TILE)
 
     out = _sparse_engine(
         psi0, nu0, nu_u, _pad_gain(kp, b_pad), _pad_gain(beta_off, b_pad),
@@ -763,7 +745,6 @@ def simulate_ensemble_dense(topo: Topology, links: LinkParams, ppm_u,
                             steps: int, kp, dt: float = 1e-3,
                             beta_off=0.0, record_every: int = 1,
                             omega_nom: float = OMEGA_NOM,
-                            interpret: Optional[bool] = None,
                             use_ref: bool = False,
                             engine: Optional[str] = None,
                             tile_j: Optional[int] = None,
@@ -822,13 +803,11 @@ def simulate_ensemble_dense(topo: Topology, links: LinkParams, ppm_u,
       record_watermarks: carry O(B·N) excursion watermarks in-kernel —
         per-node max |β| with its record index plus ν min/max — so the
         run's peak excursion and frequency spread are available WITHOUT
-        materializing any (R, B, N) record (the only way a 1M-node
-        sparse run can report them).  Also a compile-time kernel
+        materializing any (R, B, N) record (how a large sparse run
+        reports them).  Also a compile-time kernel
         variant, independent of (and composable with) ``record_beta``.
       options: :class:`repro.kernels.EngineOptions` — the typed home of
-        ``engine`` / ``interpret``.  Explicit legacy kwargs win over the
-        corresponding fields; ``interpret=`` emits a one-release
-        :class:`DeprecationWarning` (``engine=`` maps silently).
+        ``engine``.  An explicit ``engine=`` wins over the field.
       telemetry: :class:`repro.telemetry.Telemetry` — the typed home of
         ``record_beta`` / ``record_watermarks`` (both legacy kwargs
         deprecated).  ``trace`` / ``guard`` need the scenario runner and
@@ -842,7 +821,7 @@ def simulate_ensemble_dense(topo: Topology, links: LinkParams, ppm_u,
       ``.watermarks`` (:class:`repro.telemetry.Watermarks` or None).
     """
     opts = resolve_options(options, "simulate_ensemble_dense",
-                           engine=engine, interpret=interpret)
+                           engine=engine)
     tel = resolve_telemetry(telemetry, "simulate_ensemble_dense",
                             beta=record_beta, watermarks=record_watermarks)
     if tel.trace or tel.guard:
@@ -855,9 +834,11 @@ def simulate_ensemble_dense(topo: Topology, links: LinkParams, ppm_u,
             "simulate_ensemble_dense runs one launch per call; "
             "chunk_records is a run_scenario option")
     engine = opts.engine
-    interpret = opts.interpret
     record_beta = tel.beta
     record_watermarks = tel.watermarks
+    # The kernel variant that runs: dispatch budgets its telemetry buffers.
+    telemetry = dict(record_beta=bool(record_beta),
+                     record_watermarks=bool(record_watermarks))
     ppm_u = np.atleast_2d(np.asarray(ppm_u, np.float32))
     if ppm_u.shape[1] != topo.num_nodes:
         raise ValueError(
@@ -872,11 +853,11 @@ def simulate_ensemble_dense(topo: Topology, links: LinkParams, ppm_u,
 
     batched, lat_be, beta0_be, beta0_batched = _link_rows(
         links, b, topo.num_edges)
-    interp = _auto_interpret(interpret)
+    interp = _auto_interpret()
 
     # --- sparse ELL lane -------------------------------------------------
-    # Decided BEFORE densify: at the sparse regime's 10⁵–10⁶-node scale a
-    # (C, N, N) stack must never be materialized, and per-draw edge
+    # Decided BEFORE densify: at the sparse regime's scale a (C, N, N)
+    # stack must never be materialized, and per-draw edge
     # weights exist only as slot tables.
     edge_w_np = None if edge_w is None else np.asarray(edge_w, np.float64)
     per_draw_w = edge_w_np is not None and edge_w_np.ndim == 2
@@ -895,7 +876,8 @@ def simulate_ensemble_dense(topo: Topology, links: LinkParams, ppm_u,
         b_probe = ((b + SUBLANE - 1) // SUBLANE) * SUBLANE
         n_probe = ((n + TILE - 1) // TILE) * TILE
         sparse = select_engine(b_probe, n_probe, len(classes_probe),
-                               max_deg=max_in_degree(topo))[0] == "sparse"
+                               max_deg=max_in_degree(topo),
+                               **telemetry)[0] == "sparse"
     if per_draw_w and not sparse:
         raise ValueError(
             "per-draw (B, E) edge_w needs the sparse or segment-sum "
@@ -949,11 +931,12 @@ def simulate_ensemble_dense(topo: Topology, links: LinkParams, ppm_u,
     elif engine == "auto":
         # The tile-size heuristic replaces the old VMEM cliff; it applies
         # under interpret too so CPU validation exercises TPU dispatch.
-        chosen, tj = select_engine(b_pad, n_pad, c)
+        chosen, tj = select_engine(b_pad, n_pad, c, **telemetry)
     elif engine in ("fused", "tiled", "per-step"):
         chosen = engine
         tj = tile_j if tile_j is not None else (
-            select_engine(b_pad, n_pad, c)[1] if engine == "tiled" else n_pad)
+            select_engine(b_pad, n_pad, c, **telemetry)[1]
+            if engine == "tiled" else n_pad)
     else:
         raise ValueError(f"unknown engine {engine!r}")
     if chosen == "tiled" and tile_j is not None:
@@ -1029,7 +1012,6 @@ def simulate_ensemble_dense(topo: Topology, links: LinkParams, ppm_u,
 def simulate_fused(topo: Topology, links: LinkParams, ppm_u, steps: int,
                    kp: float, dt: float = 1e-3, beta_off: float = 0.0,
                    record_every: int = 1, omega_nom: float = OMEGA_NOM,
-                   interpret: Optional[bool] = None,
                    use_ref: bool = False, engine: Optional[str] = None,
                    tile_j: Optional[int] = None, init=None,
                    ctrl_mask=None, lat_classes=None,
@@ -1043,12 +1025,11 @@ def simulate_fused(topo: Topology, links: LinkParams, ppm_u, steps: int,
     :func:`simulate_ensemble_dense`, as do ``options=`` (EngineOptions)
     and ``telemetry=`` (Telemetry; ``.beta`` is then (R, N) per-node net
     occupancy in frames, ``.watermarks`` per-node (N,) aggregates).  The
-    legacy ``interpret=`` / ``record_beta=`` / ``record_watermarks=``
-    kwargs are one-release deprecation shims resolved here (so the
-    warning names this entry point, not the delegate).
+    legacy ``record_beta=`` / ``record_watermarks=`` kwargs are
+    one-release deprecation shims resolved here (so the warning names
+    this entry point, not the delegate).
     """
-    opts = resolve_options(options, "simulate_fused",
-                           engine=engine, interpret=interpret)
+    opts = resolve_options(options, "simulate_fused", engine=engine)
     tel = resolve_telemetry(telemetry, "simulate_fused",
                             beta=record_beta, watermarks=record_watermarks)
     if init is not None and not isinstance(init, DenseResult):
@@ -1071,7 +1052,6 @@ def simulate_fused(topo: Topology, links: LinkParams, ppm_u, steps: int,
 def simulate_dense(topo: Topology, links: LinkParams, ppm_u, steps: int,
                    kp: float, dt: float = 1e-3, beta_off: float = 0.0,
                    omega_nom: float = OMEGA_NOM,
-                   interpret: Optional[bool] = None,
                    use_ref: bool = False) -> DenseResult:
     """Fused-kernel synchronization run; returns (freq_ppm (T,N), psi (N,)).
 
@@ -1081,15 +1061,13 @@ def simulate_dense(topo: Topology, links: LinkParams, ppm_u, steps: int,
     """
     return simulate_fused(topo, links, ppm_u, steps, kp, dt=dt,
                           beta_off=beta_off, record_every=1,
-                          omega_nom=omega_nom, interpret=interpret,
-                          use_ref=use_ref)
+                          omega_nom=omega_nom, use_ref=use_ref)
 
 
 def simulate_dense_perstep(topo: Topology, links: LinkParams, ppm_u,
                            steps: int, kp: float, dt: float = 1e-3,
                            beta_off: float = 0.0,
                            omega_nom: float = OMEGA_NOM,
-                           interpret: Optional[bool] = None,
                            use_ref: bool = False) -> DenseResult:
     """The pre-fusion engine: one ``pallas_call`` per control period inside
     a ``lax.scan``.  Kept as the benchmark baseline — it re-streams the
@@ -1100,12 +1078,11 @@ def simulate_dense_perstep(topo: Topology, links: LinkParams, ppm_u,
         jnp.asarray(np.asarray(ppm_u, np.float32) * 1e-6))
     psi = jnp.zeros((n_pad,), jnp.float32)
     nu = nu_u
-    interp = _auto_interpret(interpret)
     dt_frames = float(omega_nom * dt)
 
     step = functools.partial(bittide_step, kp=float(kp),
                              beta_off=float(beta_off), dt_frames=dt_frames,
-                             interpret=interp, use_ref=use_ref)
+                             use_ref=use_ref)
 
     def body(carry, _):
         psi, nu = carry

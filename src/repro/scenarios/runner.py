@@ -38,7 +38,8 @@ Engines:
                   (``repro.kernels.bittide_sparse``) — same telemetry
                   contract and proportional-controller restriction as the
                   dense lanes, but O(N·deg) per period: bounded-degree
-                  scenario studies scale to 10⁵–10⁶ nodes.  No latency
+                  scenario studies scale past the dense lanes (up to
+                  ~5·10⁴ nodes at B = 8 on one TPU).  No latency
                   classes exist here (every slot carries its edge's own
                   latency in frames), so fully heterogeneous per-draw
                   (B, E) links AND per-draw (B, E) edge weights — chaos
@@ -118,7 +119,8 @@ import jax
 import jax.numpy as jnp
 
 from repro.core.controller import ControllerConfig
-from repro.core.envelopes import laplacian, reframe_guard_margins
+from repro.core.envelopes import (laplacian, laplacian_pinv,
+                                  reframe_guard_margins)
 from repro.core.frame_model import (EB_INIT, LinkParams, SimConfig,
                                     _convergence_time, broadcast_gain,
                                     simulate, simulate_ensemble)
@@ -127,11 +129,13 @@ from repro.core.reframing import (ReframePolicy, edge_occupancy,
 from repro.core.topology import Topology
 from repro.kernels.api import resolve_options
 from repro.kernels.bittide_sparse import ellify
-from repro.kernels.bittide_step import TILE, select_engine
+from repro.kernels.bittide_step import (TILE, fused_vmem_bytes,
+                                       select_engine, sparse_panel,
+                                       sparse_vmem_bytes, tiled_vmem_bytes)
 from repro.kernels.ops import (_auto_interpret, _fused_engine,
                                _host_watermarks, _lamsum_host, _pad_batch,
                                _pad_gain, _pad_table_rows, _perstep_engine,
-                               _sparse_engine, _sparse_tile, latency_classes)
+                               _sparse_engine, latency_classes)
 from repro.telemetry import Watermarks, coerce_trace, compile_stats
 from repro.telemetry.api import resolve_telemetry
 
@@ -525,13 +529,14 @@ def _build_sparse_tables(topo: Topology, comp, cfg: SimConfig,
 def _prep_sparse_segment(topo: Topology, links_seg: LinkParams, seg,
                          ctrl: ControllerConfig, ppm2d: np.ndarray,
                          cfg: SimConfig, tables: _SparseTables,
-                         seg_index: int, interp: bool):
+                         seg_index: int, variant: dict):
     """Host-side prep for one sparse-lane segment (once per segment).
 
     Mirrors :func:`_prep_dense_segment`: picks up the precomputed slot
     tables, folds λeff into traced (B_pad, N_pad) lamsum rows (per-draw
     when re-establishment or per-draw edge weights made the fold
-    per-draw), pads gains/mask/ν_u, and fixes the node-panel width.
+    per-draw), pads gains/mask/ν_u, and fixes the node-panel width for
+    the kernel ``variant`` (its ``record_*`` flags).
     Every returned shape is scenario-constant, so the chunk loop replays
     one compiled engine.
     """
@@ -548,7 +553,8 @@ def _prep_sparse_segment(topo: Topology, links_seg: LinkParams, seg,
     latf_j = _pad_table_rows(tables.latf[seg_index], b_pad)
     w_j = _pad_table_rows(tables.w[seg_index], b_pad)
     rows_t = max(latf_j.shape[0], w_j.shape[0])
-    ti = _sparse_tile(b_pad, n_pad, tables.k, rows_t, interp)
+    # Falls back to TILE and lets the kernel's own VMEM check raise.
+    ti = sparse_panel(b_pad, n_pad, tables.k, rows_t, **variant) or TILE
     mask_np = np.asarray(seg.ctrl_mask, np.float32)
     if mask_np.ndim == 2:
         mask_pad = np.ones((b_pad, n_pad), np.float32)
@@ -585,7 +591,7 @@ def _lam_stack(topo: Topology, inv: np.ndarray, lam_eff_row, edge_w,
 def _prep_dense_segment(topo: Topology, links_seg: LinkParams, seg, comp,
                         ctrl: ControllerConfig, ppm2d: np.ndarray,
                         cfg: SimConfig, engine: str, stacks: _DenseStacks,
-                        seg_index: int):
+                        seg_index: int, variant: dict):
     """Host-side prep for one dense-engine segment (done once per segment).
 
     Args:
@@ -594,6 +600,8 @@ def _prep_dense_segment(topo: Topology, links_seg: LinkParams, seg, comp,
       ppm2d: (B, N) per-draw unadjusted offsets (ppm) for this segment.
       stacks / seg_index: the precomputed per-segment adjacency stacks
         (see :class:`_DenseStacks`) — A is NOT re-densified here.
+      variant: the kernel's ``record_*`` flags — dispatch budgets the
+        telemetry buffers of the variant that runs.
 
     Picks up the precomputed A stack, folds λeff into the traced
     (B_pad, N_pad) lamsum rows (per-draw when re-establishment made λeff
@@ -615,11 +623,11 @@ def _prep_dense_segment(topo: Topology, links_seg: LinkParams, seg, comp,
     nu_u, b_pad = _pad_batch(ppm2d, n, n_pad)
 
     if engine == "auto":
-        chosen, tj = select_engine(b_pad, n_pad, c)
+        chosen, tj = select_engine(b_pad, n_pad, c, **variant)
     elif engine == "per-step":
         chosen, tj = "per-step", 0
     elif engine == "tiled":
-        chosen, tj = "tiled", select_engine(b_pad, n_pad, c)[1]
+        chosen, tj = "tiled", select_engine(b_pad, n_pad, c, **variant)[1]
     else:
         chosen, tj = "fused", n_pad
 
@@ -677,7 +685,6 @@ def run_scenario(topo: Topology, links: LinkParams, ctrl: ControllerConfig,
                  record_watermarks: Optional[bool] = None,
                  auto_reframe=None,
                  trace=None,
-                 interpret: Optional[bool] = None,
                  options=None, telemetry=None) -> ScenarioResult:
     """Run a dynamic-event scenario, chaining one engine across segments.
 
@@ -711,7 +718,7 @@ def run_scenario(topo: Topology, links: LinkParams, ctrl: ControllerConfig,
         derives the identical quantities host-side from its per-edge
         record), chunk-merged into ``ScenarioResult.watermarks`` —
         available with or without a full ``record_beta`` record, which
-        is how 10⁶-node sparse runs report peak excursions at all.
+        is how large sparse runs report peak excursions without one.
       auto_reframe: closed-loop buffer re-centering.  ``True`` (or a
         :class:`repro.core.reframing.ReframePolicy`) closes the
         reframing loop; when the guard trips, the runner splices an
@@ -753,9 +760,8 @@ def run_scenario(topo: Topology, links: LinkParams, ctrl: ControllerConfig,
         gain-sweep batch no longer shares one margin computed from the
         stiffest draw.
       options: :class:`repro.kernels.EngineOptions` — the typed home of
-        ``engine`` / ``interpret`` / ``chunk_records``.  Explicit
-        legacy kwargs win over the corresponding fields; ``interpret=``
-        warns (one release), the non-boolean two map silently.
+        ``engine`` / ``chunk_records``.  Explicit ``engine=`` /
+        ``chunk_records=`` win over the corresponding fields.
       telemetry: :class:`repro.telemetry.Telemetry` — the typed home of
         ``record_beta`` / ``record_watermarks`` / ``trace`` /
         ``auto_reframe`` (→ ``Telemetry.guard``); each legacy kwarg
@@ -785,7 +791,7 @@ def run_scenario(topo: Topology, links: LinkParams, ctrl: ControllerConfig,
             "telemetry=Telemetry(guard=...) runs the guard without "
             "surfacing the record)")
     opts = resolve_options(options, "run_scenario", engine=engine,
-                           interpret=interpret, chunk_records=chunk_records,
+                           chunk_records=chunk_records,
                            default_engine="segment-sum")
     beta_explicit = telemetry is not None or record_beta is not None
     tel = resolve_telemetry(
@@ -794,7 +800,6 @@ def run_scenario(topo: Topology, links: LinkParams, ctrl: ControllerConfig,
         trace=trace if trace else None,
         guard=auto_reframe if auto_reframe else None)
     engine = opts.engine
-    interpret = opts.interpret
     ppm_u = np.asarray(ppm_u, np.float32)
     single = ppm_u.ndim == 1
     comp = compiled or compile_scenario(scenario, topo, links, cfg)
@@ -902,7 +907,9 @@ def run_scenario(topo: Topology, links: LinkParams, ctrl: ControllerConfig,
     # once (the chunk loops never re-densify A or re-scatter slots).
     stacks = _build_dense_stacks(topo, comp, cfg) if dense else None
     tables = _build_sparse_tables(topo, comp, cfg) if sparse else None
-    interp = _auto_interpret(interpret)
+    interp = _auto_interpret()
+    variant = dict(record_beta=bool(rb_dense), record_watermarks=bool(rw),
+                   record_guard=guard_on)
 
     def live_state():
         """Exact threaded (ψ, ν) — (N,)/(B, N) float host views.  Every
@@ -959,7 +966,7 @@ def run_scenario(topo: Topology, links: LinkParams, ctrl: ControllerConfig,
                 deg_c = np.zeros(n, np.float64)
                 np.add.at(deg_c, np.asarray(topo.dst),
                           np.asarray(seg.edge_w, np.float64))
-                guard_cache[wkey] = (deg_c, np.linalg.pinv(
+                guard_cache[wkey] = (deg_c, laplacian_pinv(
                     laplacian(topo, np.asarray(seg.edge_w, np.float64))))
             deg_w, lap_pinv = guard_cache[wkey]
             src_np, dst_np = np.asarray(topo.src), np.asarray(topo.dst)
@@ -987,13 +994,14 @@ def run_scenario(topo: Topology, links: LinkParams, ctrl: ControllerConfig,
             (latf_j, w_j, lamsum_j, mask_j, nu_u_j, kp_j, boff_j, ti,
              b_pad, n_pad) = _prep_sparse_segment(
                 topo, links_seg, seg, ctrl, np.atleast_2d(ppm_seg), cfg,
-                tables, si, interp)
+                tables, si, variant)
             eng_label, tile_j = "sparse", ti
             tr.event("engine_dispatch", segment=si, engine="sparse",
                      tile_i=int(ti), b_pad=int(b_pad), n_pad=int(n_pad),
                      k=int(tables.k),
-                     vmem_est_bytes=int(4 * tables.k * ti
-                                        * (2 * b_pad + 1) + 12 * b_pad * ti))
+                     vmem_est_bytes=sparse_vmem_bytes(
+                         b_pad, n_pad, tables.k, ti,
+                         max(latf_j.shape[0], w_j.shape[0]), **variant))
             if psi_pad is None:
                 psi_pad, nu_pad = jnp.zeros_like(nu_u_j), nu_u_j
             dt_frames = float(cfg.omega_nom * cfg.dt)
@@ -1062,7 +1070,7 @@ def run_scenario(topo: Topology, links: LinkParams, ctrl: ControllerConfig,
                             _prep_sparse_segment(
                                 topo, links_seg, seg, ctrl,
                                 np.atleast_2d(ppm_seg), cfg, tables,
-                                si, interp)
+                                si, variant)
             continue
 
         if dense:
@@ -1073,15 +1081,19 @@ def run_scenario(topo: Topology, links: LinkParams, ctrl: ControllerConfig,
             (a, lam_list, lamsum_j, lat_j, mask_j, nu_u_j, kp_j, boff_j,
              chosen, tj, b_pad, n_pad) = _prep_dense_segment(
                 topo, links_seg, seg, comp, ctrl, np.atleast_2d(ppm_seg),
-                cfg, engine, stacks, si)
+                cfg, engine, stacks, si, variant)
             eng_label, tile_j = chosen, tj
             c_stack = int(a.shape[0])
+            if chosen == "fused":
+                vmem_est = fused_vmem_bytes(b_pad, n_pad, c_stack, **variant)
+            elif chosen == "tiled":
+                vmem_est = tiled_vmem_bytes(b_pad, n_pad, c_stack, tj,
+                                            **variant)
+            else:   # per-step: one double-buffered (C, TILE, TILE) tile
+                vmem_est = 2 * 4 * c_stack * TILE * TILE
             tr.event("engine_dispatch", segment=si, engine=chosen,
                      tile_j=int(tj), b_pad=int(b_pad), n_pad=int(n_pad),
-                     c=c_stack,
-                     vmem_est_bytes=int(
-                         4 * c_stack * n_pad
-                         * (n_pad if chosen == "fused" else max(tj, 1))))
+                     c=c_stack, vmem_est_bytes=int(vmem_est))
             if psi_pad is None:
                 psi_pad, nu_pad = jnp.zeros_like(nu_u_j), nu_u_j
             dt_frames = float(cfg.omega_nom * cfg.dt)
@@ -1217,7 +1229,7 @@ def run_scenario(topo: Topology, links: LinkParams, ctrl: ControllerConfig,
                             _prep_dense_segment(
                                 topo, links_seg, seg, comp, ctrl,
                                 np.atleast_2d(ppm_seg), cfg, engine,
-                                stacks, si)
+                                stacks, si, variant)
                         kp_np = np.asarray(kp_j)
                         boff_np = np.asarray(boff_j)
             continue

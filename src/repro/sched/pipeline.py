@@ -106,8 +106,7 @@ def pipeline_apply(stage_fn: Callable, stage_params, x, mesh, axis: str,
         return outs
 
     from jax.sharding import PartitionSpec as P
-    from jax.experimental.shard_map import shard_map
-    fn = shard_map(body, mesh=mesh,
-                   in_specs=(P(axis), P()), out_specs=P(),
-                   check_rep=False)
+    fn = jax.shard_map(body, mesh=mesh,
+                       in_specs=(P(axis), P()), out_specs=P(),
+                       check_vma=False)
     return fn(stage_params, x)

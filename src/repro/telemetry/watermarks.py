@@ -2,8 +2,8 @@
 
 The paper's hardware instrumentation exists to show *bounded buffer
 excursions* and tight frequency alignment — questions whose answers are
-peaks and spreads, not trajectories.  At the sparse lane's 10⁵–10⁶-node
-scale a full (R, B, N) β record is exactly what dies first, so the
+peaks and spreads, not trajectories.  At the sparse lane's scale a
+full (R, B, N) β record is exactly what dies first, so the
 engines carry these running aggregates **in VMEM scratch** instead,
 updated at every record point and emitted once at the end:
 

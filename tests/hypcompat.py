@@ -1,7 +1,8 @@
 """hypothesis with a deterministic fallback.
 
 The property tests use a small slice of the hypothesis API (``@given`` with
-``st.integers`` / ``st.floats`` / ``st.booleans`` / ``st.sampled_from`` and
+``st.integers`` / ``st.floats`` / ``st.booleans`` / ``st.sampled_from``,
+pinned ``@example(...)`` cases and
 ``@settings(max_examples=..., deadline=...)``).  Some deploy environments
 (including the CI container) don't ship hypothesis; rather than skipping the
 property tests entirely there, this shim replays each property on a fixed
@@ -11,18 +12,18 @@ installed the real library is used unchanged.
 
 Usage in test modules::
 
-    from hypcompat import given, settings, st
+    from hypcompat import example, given, settings, st
 """
 from __future__ import annotations
 
-__all__ = ["given", "settings", "st", "HAVE_HYPOTHESIS"]
+__all__ = ["example", "given", "settings", "st", "HAVE_HYPOTHESIS"]
 
 import functools
 import os
 import zlib
 
 try:  # pragma: no cover - exercised when hypothesis is installed
-    from hypothesis import given, settings
+    from hypothesis import example, given, settings
     from hypothesis import strategies as st
 
     HAVE_HYPOTHESIS = True
@@ -64,6 +65,16 @@ except ImportError:
 
     st = _Strategies()
 
+    def example(**pinned):
+        """Pin one case; the fallback runner replays it before its draws."""
+        def decorate(test):
+            test._hypcompat_examples = ([pinned]
+                                        + getattr(test, "_hypcompat_examples",
+                                                  []))
+            return test
+
+        return decorate
+
     def given(**strategies):
         def decorate(test):
             @functools.wraps(test)
@@ -72,6 +83,8 @@ except ImportError:
                 n = min(limit, FALLBACK_EXAMPLES)
                 # Seed from the test name so every run replays the same draws.
                 rng = _random.Random(zlib.crc32(test.__qualname__.encode()))
+                for pinned in getattr(test, "_hypcompat_examples", []):
+                    test(*args, **kwargs, **pinned)
                 for _ in range(max(n, 1)):
                     drawn = {k: s.draw(rng) for k, s in strategies.items()}
                     test(*args, **kwargs, **drawn)

@@ -4,8 +4,9 @@ and the named EngineOutputs tuple.
 
 Pins:
   * every legacy boolean kwarg (``record_beta``, ``record_watermarks``,
-    ``trace``, ``auto_reframe``, ``interpret``) warns EXACTLY once per
-    process, keyed on the kwarg name — not once per call site;
+    ``trace``, ``auto_reframe``) warns EXACTLY once per process, keyed
+    on the kwarg name — not once per call site;
+  * ``interpret`` is no option at all: the backend decides it;
   * ``engine=`` / ``chunk_records=`` migrate silently (they name real
     knobs, not observations);
   * the shimmed spelling and the typed spelling are BIT-identical;
@@ -70,12 +71,17 @@ def test_legacy_kwargs_warn_exactly_once(kwargs, token):
 
 
 def test_interpret_kwarg_warns_once():
+    # Its shim is gone with the option: the backend alone decides whether
+    # the kernels run in the interpreter, so every entry point refuses
+    # the kwarg outright instead of warning.
     ppm = _ppm()
-    got = _caught(lambda: simulate_fused(TOPO, LINKS, ppm, steps=24, kp=2e-7,
-                                         record_every=12, interpret=True))
-    assert len(got) == 1
-    assert "interpret" in str(got[0].message)
-    assert "EngineOptions" in str(got[0].message)
+    with pytest.raises(TypeError, match="interpret"):
+        simulate_fused(TOPO, LINKS, ppm, steps=24, kp=2e-7,
+                       record_every=12, interpret=True)
+    with pytest.raises(TypeError, match="interpret"):
+        run_scenario(TOPO, LINKS, CTRL, ppm, SC, CFG, interpret=True)
+    with pytest.raises(TypeError, match="interpret"):
+        EngineOptions(interpret=True)
 
 
 def test_engine_and_chunk_kwargs_are_silent():

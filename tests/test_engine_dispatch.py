@@ -29,10 +29,15 @@ def test_select_engine_regimes():
     # torus3d(8) pads to 512: beyond the resident cutoff -> tiled.
     engine, tj = select_engine(8, 512, 1)
     assert engine == "tiled" and tj == TILE_J_MAX
-    # Fig-18 scale (torus3d(22) pads to 10752): the widest panel that fits.
+    # Fig-18 scale (torus3d(22) pads to 10752): the widest panel that
+    # fits, with every telemetry buffer of the variant counted.
     engine, tj = select_engine(8, 10752, 1)
-    assert engine == "tiled" and tj == TILE
+    assert engine == "tiled" and tj == TILE_J_MAX
     assert tiled_vmem_bytes(8, 10752, 1, tj) <= VMEM_BUDGET_BYTES
+    tel = dict(record_beta=True, record_watermarks=True, record_guard=True)
+    assert select_engine(8, 10752, 1, **tel) == ("tiled", TILE_J_MAX)
+    assert (tiled_vmem_bytes(8, 10752, 1, tj, **tel)
+            > tiled_vmem_bytes(8, 10752, 1, tj))
     # A giant batch at a class count where no panel fits -> per-step.
     assert select_engine(4096, 10752, 8)[0] == "per-step"
 
@@ -57,15 +62,16 @@ def test_select_engine_sparse_regime_boundaries():
     assert sparse_vmem_bytes(8, 49152, 6, TILE_J_MAX) <= VMEM_BUDGET_BYTES
 
     # Degree pressure narrows the node panel before giving up...
-    assert select_engine(8, 49152, 1, max_deg=512) == ("sparse", TILE)
-    assert sparse_vmem_bytes(8, 49152, 512, TILE) <= VMEM_BUDGET_BYTES
-    assert sparse_vmem_bytes(8, 49152, 512, TILE_J_MAX) > VMEM_BUDGET_BYTES
+    assert select_engine(8, 49152, 1, max_deg=2048) == ("sparse", TILE)
+    assert sparse_vmem_bytes(8, 49152, 2048, TILE) <= VMEM_BUDGET_BYTES
+    assert sparse_vmem_bytes(8, 49152, 2048, TILE_J_MAX) > VMEM_BUDGET_BYTES
     # ...and a degree no panel can stream falls through to per-step.
     assert select_engine(8, 49152, 1, max_deg=4096) == ("per-step", 0)
 
-    # The resident (B, N) state itself must fit: past ~57k nodes at B=8
-    # (or under a tighter budget) even degree-6 graphs leave VMEM.
-    assert select_engine(8, 65536, 1, max_deg=6) == ("per-step", 0)
+    # The resident state and its node-major gather mirror must fit: past
+    # ~50k nodes at B=8 (or under a tighter budget) even degree-6 graphs
+    # leave VMEM.
+    assert select_engine(8, 57344, 1, max_deg=6) == ("per-step", 0)
     assert select_engine(8, 49152, 1, vmem_budget=8 * 2 ** 20,
                          max_deg=6) == ("per-step", 0)
     # Giant batches stay on per-step regardless of the degree bound.
@@ -73,15 +79,15 @@ def test_select_engine_sparse_regime_boundaries():
 
 
 def test_auto_dispatch_routes_bounded_degree_to_sparse():
-    """End-to-end: a 2k-node degree-4 graph with 8 latency classes (the
-    (8, 2048, 8) dense working set fits NO panel width) auto-routes to
+    """End-to-end: a 6k-node degree-4 graph with 8 latency classes (the
+    (8, 6144, 8) dense working set fits NO panel width) auto-routes to
     the sparse lane and stamps the result metadata."""
     from engine_harness import bounded_degree_topo
-    topo = bounded_degree_topo(2000, 4, 0)    # pads to 2048
+    topo = bounded_degree_topo(6000, 4, 0)    # pads to 6144
     rng = np.random.default_rng(5)
     cable = rng.choice(np.linspace(2.0, 200.0, 8), size=topo.num_edges)
     links = make_links(topo, cable_m=cable)
-    assert tiled_vmem_bytes(8, 2048, 8, TILE) > VMEM_BUDGET_BYTES
+    assert tiled_vmem_bytes(8, 6144, 8, TILE) > VMEM_BUDGET_BYTES
     res = simulate_fused(topo, links, rng.uniform(-8, 8, topo.num_nodes),
                          steps=2, kp=2e-9, record_every=1)
     assert res.engine == "sparse"

@@ -36,7 +36,7 @@ def test_kernel_matches_ref(topo):
     a, lam, lat, npad = densify(topo, links)
     psi, nu, nu_u = rand_state(npad, 0)
     kw = dict(kp=2e-9, beta_off=1.5, dt_frames=125000.0)
-    p1, n1 = bittide_step(psi, nu, nu_u, a, lam, lat, interpret=True, **kw)
+    p1, n1 = bittide_step(psi, nu, nu_u, a, lam, lat, **kw)
     p2, n2, _ = bittide_dense_step_ref(psi, nu, nu_u, a, lam, lat, **kw)
     np.testing.assert_allclose(np.asarray(n1), np.asarray(n2), rtol=1e-5, atol=1e-11)
     np.testing.assert_allclose(np.asarray(p1), np.asarray(p2), rtol=1e-5, atol=1e-4)
@@ -54,7 +54,7 @@ def test_kernel_multiple_latency_classes():
     assert a.shape[0] == 2  # two classes
     psi, nu, nu_u = rand_state(npad, 1)
     kw = dict(kp=2e-9, beta_off=0.0, dt_frames=125000.0)
-    p1, n1 = bittide_step(psi, nu, nu_u, a, lam, lat, interpret=True, **kw)
+    p1, n1 = bittide_step(psi, nu, nu_u, a, lam, lat, **kw)
     p2, n2, _ = bittide_dense_step_ref(psi, nu, nu_u, a, lam, lat, **kw)
     np.testing.assert_allclose(np.asarray(n1), np.asarray(n2), rtol=1e-5, atol=1e-11)
     np.testing.assert_allclose(np.asarray(p1), np.asarray(p2), rtol=1e-5, atol=1e-4)
@@ -69,7 +69,7 @@ def test_property_kernel_matches_ref(seed, n, kp, beta_off):
     a, lam, lat, npad = densify(topo, links)
     psi, nu, nu_u = rand_state(npad, seed)
     kw = dict(kp=kp, beta_off=beta_off, dt_frames=12500.0)
-    p1, n1 = bittide_step(psi, nu, nu_u, a, lam, lat, interpret=True, **kw)
+    p1, n1 = bittide_step(psi, nu, nu_u, a, lam, lat, **kw)
     p2, n2, _ = bittide_dense_step_ref(psi, nu, nu_u, a, lam, lat, **kw)
     np.testing.assert_allclose(np.asarray(n1), np.asarray(n2), rtol=1e-4, atol=1e-10)
     np.testing.assert_allclose(np.asarray(p1), np.asarray(p2), rtol=1e-4, atol=1e-3)
@@ -105,7 +105,7 @@ def test_padding_nodes_inert():
     assert npad == TILE
     psi = jnp.zeros((npad,), jnp.float32)
     nu_u = jnp.zeros((npad,), jnp.float32).at[8:].set(5e-6)
-    p1, n1 = bittide_step(psi, psi, nu_u, a, lam, lat, interpret=True,
+    p1, n1 = bittide_step(psi, psi, nu_u, a, lam, lat,
                           kp=2e-9, beta_off=0.0, dt_frames=125000.0)
     # pad nodes see zero occupancy error -> nu = nu_u exactly
     np.testing.assert_allclose(np.asarray(n1[8:]), 5e-6, rtol=1e-6, atol=1e-12)

@@ -151,7 +151,6 @@ def test_compressed_psum_multidevice():
     r = run_py("""
         import jax, jax.numpy as jnp, numpy as np
         from jax.sharding import Mesh, PartitionSpec as P
-        from jax.experimental.shard_map import shard_map
         from repro.optim.compression import compressed_psum
 
         mesh = Mesh(np.array(jax.devices()), ("dp",))
@@ -159,9 +158,9 @@ def test_compressed_psum_multidevice():
         g = jnp.asarray(rng.normal(0, 1, (8, 16)).astype(np.float32))
         e = jnp.zeros((8, 16), jnp.float32)
 
-        fn = shard_map(lambda g, e: compressed_psum(g, e, "dp"),
-                       mesh=mesh, in_specs=(P("dp"), P("dp")),
-                       out_specs=(P(), P("dp")), check_rep=False)
+        fn = jax.shard_map(lambda g, e: compressed_psum(g, e, "dp"),
+                           mesh=mesh, in_specs=(P("dp"), P("dp")),
+                           out_specs=(P(), P("dp")), check_vma=False)
         mean, new_e = fn(g, e)
         ref = np.asarray(g).mean(axis=0)
         got = np.asarray(mean)[0]

@@ -32,7 +32,8 @@ from repro.core import (ControllerConfig, ReframePolicy, SimConfig,
                         fully_connected, make_links, reframe, reframe_net,
                         reframe_state, ring, simulate, torus3d)
 from repro.core import frame_level as fl
-from repro.core.envelopes import reframe_guard_margin, reframe_guard_margins
+from repro.core.envelopes import (laplacian, laplacian_pinv,
+                                  reframe_guard_margin, reframe_guard_margins)
 from repro.core.frame_model import EB_INIT, OMEGA_NOM
 from repro.core.reframing import (check_rotation_invariant, graph_shifts,
                                   node_net_occupancy, potential_residual)
@@ -578,3 +579,31 @@ def test_auto_reframe_per_draw_guard_margins():
         assert r.guard_latency == 1
         np.testing.assert_array_equal(r.shift[0], 0)
     assert max(np.abs(r.shift[1]).max() for r in res.reframes) > 0
+
+
+def _isolate_node_3(topo):
+    """Weights that cut node 3 out of fully_connected(4) in both
+    directions: symmetric, but the graph falls into two parts."""
+    w = np.ones(topo.num_edges)
+    w[(np.asarray(topo.src) == 3) | (np.asarray(topo.dst) == 3)] = 0.0
+    return w
+
+
+@pytest.mark.parametrize("case", ["connected", "partitioned", "asymmetric"])
+def test_laplacian_pinv_matches_svd_pinv(case):
+    """The LU form (L + J/n)⁻¹ − J/n of a connected symmetric Laplacian is
+    its pseudo-inverse; a partitioned graph or direction-asymmetric
+    weights fall back to the SVD.  Either way the result is
+    ``np.linalg.pinv`` to float64 rounding."""
+    from engine_harness import bounded_degree_topo
+    from repro.core import fully_connected, torus3d
+    if case == "connected":
+        lap = laplacian(torus3d(4))
+    elif case == "partitioned":
+        topo = fully_connected(4)
+        lap = laplacian(topo, _isolate_node_3(topo))
+    else:
+        lap = laplacian(bounded_degree_topo(24, 3, 1))
+        assert not np.array_equal(lap, lap.T)
+    np.testing.assert_allclose(laplacian_pinv(lap), np.linalg.pinv(lap),
+                               rtol=0, atol=1e-9)

@@ -14,9 +14,10 @@ slots and multi-panel streaming, and the lane's error contracts.
 """
 import numpy as np
 import pytest
-from hypcompat import given, settings, st
+from hypcompat import example, given, settings, st
 
-from engine_harness import (BETA_ATOL_CROSS_FRAMES, FREQ_ATOL_PPM,
+from engine_harness import (BETA_ATOL_CROSS_FRAMES, BETA_RTOL_CROSS,
+                            FREQ_ATOL_PPM,
                             bounded_degree_topo, node_recon, parity_ppm,
                             random_latency_links)
 from repro.core import (ControllerConfig, SimConfig, fully_connected,
@@ -144,6 +145,8 @@ def test_kernel_shape_and_tile_errors():
 @given(n=st.integers(12, 40), max_deg=st.integers(1, 5),
        gseed=st.integers(0, 2 ** 16), lseed=st.integers(0, 2 ** 16),
        heterogeneous=st.booleans())
+# β near 273 frames differs from segment-sum by 0.75 float32 ulp here.
+@example(n=12, max_deg=4, gseed=0, lseed=101, heterogeneous=False)
 def test_sparse_matches_segment_sum_on_random_graphs(n, max_deg, gseed,
                                                      lseed, heterogeneous):
     """Satellite property: on random bounded-degree digraphs × random
@@ -168,7 +171,8 @@ def test_sparse_matches_segment_sum_on_random_graphs(n, max_deg, gseed,
     np.testing.assert_allclose(res[0], ref.freq_ppm, rtol=0,
                                atol=FREQ_ATOL_PPM)
     np.testing.assert_allclose(res.beta, node_recon(topo, ref.beta),
-                               rtol=0, atol=BETA_ATOL_CROSS_FRAMES)
+                               rtol=BETA_RTOL_CROSS,
+                               atol=BETA_ATOL_CROSS_FRAMES)
 
 
 def test_isolated_nodes_hold_their_oscillator():
