@@ -1,0 +1,9 @@
+"""Puts the benchmark's harness and the program's sources on the path."""
+import sys
+from pathlib import Path
+
+BENCH = Path(__file__).resolve().parents[1]
+ROOT = BENCH.parents[1]
+for p in (str(ROOT / "src"), str(BENCH)):
+    if p not in sys.path:
+        sys.path.insert(0, p)
