@@ -3,16 +3,12 @@ benches. Prints ``name,us_per_call,derived`` CSV (stdout), one row each.
 
     PYTHONPATH=src python -m benchmarks.run [--only substring]
                                            [--smoke] [--json PATH]
-                                           [--trace PATH]
 
 ``--smoke`` runs the fast CI subset (kernel_perf.SMOKE plus the
 serving_goodput gate) — the per-PR perf-trajectory gate scripts/ci.sh
 uses.  ``--json PATH`` also
 writes the rows as a JSON baseline (see benchmarks/README.md for how the
-fields are meant to be read).  ``--trace PATH`` records the whole harness
-run as a flight-recorder JSONL (one ``bench`` span per lane, one
-``compile_stats`` snapshot at the end — scripts/trace_report.py renders
-it); CI archives it next to BENCH_kernels.json.
+fields are meant to be read).
 """
 import argparse
 import json
@@ -42,8 +38,6 @@ def main() -> None:
                     help="fast CI subset: fused/ensemble engine benches only")
     ap.add_argument("--json", default=None, metavar="PATH",
                     help="also write rows to PATH as a JSON baseline")
-    ap.add_argument("--trace", default=None, metavar="PATH",
-                    help="write a flight-recorder JSONL of the run to PATH")
     args = ap.parse_args()
 
     from repro.compile_cache import enable_compile_cache
@@ -60,9 +54,6 @@ def main() -> None:
                    + straggler_bench.ALL + serving_bench.ALL
                    + roofline_report.ALL)
 
-    from repro.telemetry import compile_stats, coerce_trace
-    tr = coerce_trace(bool(args.trace), name="bench-harness")
-
     print("name,us_per_call,derived")
     rows = {}
     failed = 0
@@ -70,8 +61,7 @@ def main() -> None:
         if args.only and args.only not in fn.__name__:
             continue
         try:
-            with tr.span("bench", name=fn.__name__):
-                name, us, derived = fn()
+            name, us, derived = fn()
             print(f"{name},{us:.1f},{derived}", flush=True)
             # JSON rows are keyed by the python bench name so a bench that
             # flips between erroring and passing keeps a stable key across
@@ -80,27 +70,18 @@ def main() -> None:
                                  "derived": _derived_fields(derived)}
             if "FAIL" in derived:
                 failed += 1
-            tr.event("mark", bench=fn.__name__, us_per_call=round(us, 1),
-                     verdict="FAIL" if "FAIL" in derived else "PASS")
         except Exception as e:  # noqa: BLE001
             failed += 1
             print(f"{fn.__name__},nan,ERROR:{type(e).__name__}:{e}", flush=True)
             traceback.print_exc(file=sys.stderr)
             rows[fn.__name__] = {"name": None, "us_per_call": None,
                                  "derived": {"error": f"{type(e).__name__}:{e}"}}
-            tr.event("mark", bench=fn.__name__, verdict="ERROR",
-                     error=f"{type(e).__name__}:{e}")
 
     if args.json:
         with open(args.json, "w") as f:
             json.dump(rows, f, indent=2, sort_keys=True)
             f.write("\n")
         print(f"wrote {len(rows)} rows to {args.json}", file=sys.stderr)
-    if args.trace:
-        tr.event("compile_stats", sizes=compile_stats())
-        tr.to_jsonl(args.trace)
-        print(f"wrote {len(tr)} trace events to {args.trace}",
-              file=sys.stderr)
     sys.exit(1 if failed else 0)
 
 

@@ -12,8 +12,7 @@
 # is the full PR gate.
 #
 # Writes BENCH_kernels.json at the repo root (the fused/tiled-engine perf
-# trajectory; see benchmarks/README.md) plus RUN_TRACE.jsonl, the bench
-# harness's flight-recorder record (render it with scripts/trace_report.py).
+# trajectory; see benchmarks/README.md).
 # Exits nonzero if lint or tests
 # fail, any smoke bench reports FAIL, or the baseline comparison finds a
 # hard gate.
@@ -132,8 +131,7 @@ else
          "serve_bittide --smoke) green"
 fi
 
-python -m benchmarks.run --smoke --json BENCH_kernels.json \
-    --trace RUN_TRACE.jsonl
+python -m benchmarks.run --smoke --json BENCH_kernels.json
 python scripts/compare_bench.py BENCH_kernels.json \
     benchmarks/baselines/BENCH_kernels.json
 if [ "$FAST" -eq 1 ]; then

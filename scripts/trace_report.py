@@ -6,11 +6,11 @@ human-readable run report.
 
 The report has three parts:
   1. the per-kind summary table (``RunTrace.summary()``);
-  2. a wall-clock timeline of every span/event, indented by kind, with
-     the load-bearing fields of each record inlined;
+  2. a wall-clock timeline of every span/event in start order, indented
+     under the span it ran in, with the load-bearing fields of each
+     record inlined;
   3. a health section: engine dispatch regimes, guard trips / reframe
-     splices, chaos verdict counts, bench PASS/FAIL marks, and the
-     jit-cache delta.  Zero new compiles against a WARM cache is the
+     splices, chaos verdict counts, and the jit-cache delta.  Zero new compiles against a WARM cache is the
      contract; a cold first run legitimately compiles once, so a
      non-zero delta is reported loudly but only fails the exit code
      under ``--selftest`` (which warms the cache before tracing).
@@ -34,13 +34,21 @@ from repro.telemetry import RunTrace  # noqa: E402
 _TIMELINE_FIELDS = {
     "engine_dispatch": ("segment", "engine", "b_pad", "n_pad", "k", "c",
                         "records", "vmem_est_bytes"),
+    "scenario": ("engine",),
     "segment": ("name", "draws"),
+    "segment.compile": (),
+    "segment.stacks": (),
+    "segment.upload": (),
+    "segment.prep": ("segment",),
+    "segment.splice": ("segment",),
+    "guard": ("name", "segment"),
     "chunk": ("engine", "segment", "launch", "records"),
+    "chunk.dispatch": (),
+    "chunk.wait": (),
+    "chunk.fetch": (),
     "guard_eval": ("record", "guard", "tripped"),
     "reframe": ("record", "segment", "auto", "max_shift"),
     "chaos_draw": ("draw", "verdict", "margin", "peak", "reframed"),
-    "bench": ("name",),
-    "mark": ("bench", "verdict", "us_per_call", "error"),
 }
 
 
@@ -52,12 +60,21 @@ def _fmt(v) -> str:
 
 def _timeline(tr: RunTrace) -> list[str]:
     lines = ["", "timeline (t in s since trace epoch):"]
-    for ev in tr.events:
-        dur = f" [{ev.dur * 1e3:8.1f} ms]" if ev.dur is not None else " " * 12
+    parent = {ev.id: ev.parent for ev in tr.events if ev.id is not None}
+
+    def depth(ev) -> int:
+        d, p = 0, ev.parent
+        while p is not None:
+            d, p = d + 1, parent.get(p)
+        return d
+
+    for ev in sorted(tr.events, key=lambda e: e.t):
+        dur = f" [{ev.dur * 1e3:8.1f} ms]" if ev.dur is not None else " " * 14
         fields = _TIMELINE_FIELDS.get(ev.kind, tuple(sorted(ev.data)))
         kv = " ".join(f"{k}={_fmt(ev.data[k])}" for k in fields
                       if k in ev.data)
-        lines.append(f"  {ev.t:9.3f}{dur}  {ev.kind:<15} {kv}")
+        kind = "  " * depth(ev) + ev.kind
+        lines.append(f"  {ev.t:9.3f}{dur}  {kind:<19} {kv}")
     return lines
 
 
@@ -90,18 +107,6 @@ def _health(tr: RunTrace, strict: bool = False) -> tuple[list[str], int]:
             verdicts[v] = verdicts.get(v, 0) + 1
         lines.append("  chaos draws: " + ", ".join(
             f"{k}={v}" for k, v in sorted(verdicts.items())))
-
-    marks = tr.by_kind("mark")
-    bench_marks = [e for e in marks if "bench" in e.data]
-    if bench_marks:
-        bad = [e for e in bench_marks
-               if e.data.get("verdict") not in (None, "PASS")]
-        lines.append(f"  bench lanes: {len(bench_marks)} "
-                     f"({len(bench_marks) - len(bad)} PASS, {len(bad)} not)")
-        for e in bad:
-            lines.append(f"    {e.data.get('bench')}: "
-                         f"{e.data.get('verdict')} "
-                         f"{e.data.get('error', '')}".rstrip())
 
     for e in tr.by_kind("compile_stats"):
         delta = e.data.get("delta")

@@ -98,7 +98,8 @@ def max_in_degree(topo: Topology) -> int:
 
 def ellify(topo: Topology, lat_frames, edge_w=None, tile: int = TILE,
            n_pad: Optional[int] = None, max_deg: Optional[int] = None):
-    """Edge list → slot-major ELL tables for the sparse engine.
+    """Edge list → slot-major ELL tables for the sparse engine, placed
+    on the device (:func:`ell_tables` builds them on the host).
 
     Args:
       topo: the directed multigraph (duplicate edges land in distinct
@@ -121,6 +122,14 @@ def ellify(topo: Topology, lat_frames, edge_w=None, tile: int = TILE,
       w (R_w, K, N_pad) float32) with R = 1 for shared inputs or B for
       per-draw inputs (the two leading axes are independent).
     """
+    return tuple(jnp.asarray(x) for x in ell_tables(
+        topo, lat_frames, edge_w=edge_w, tile=tile, n_pad=n_pad,
+        max_deg=max_deg))
+
+
+def ell_tables(topo: Topology, lat_frames, edge_w=None, tile: int = TILE,
+               n_pad: Optional[int] = None, max_deg: Optional[int] = None):
+    """The tables of :func:`ellify` as host NumPy arrays."""
     n = topo.num_nodes
     e = topo.num_edges
     if n_pad is None:
@@ -165,7 +174,7 @@ def ellify(topo: Topology, lat_frames, edge_w=None, tile: int = TILE,
         nbr[slot, dst] = src.astype(np.int32)
         latf[:, slot, dst] = lat2
         wt[:, slot, dst] = w2
-    return jnp.asarray(nbr), jnp.asarray(latf), jnp.asarray(wt)
+    return nbr, latf, wt
 
 
 def _mirror_width(b: int) -> int:
@@ -513,6 +522,7 @@ def bittide_sparse_pallas(psi, nu, nu_u, nbr, latf, w, lamsum, kp, beta_off,
     measure = record_beta or record_watermarks or record_guard
     out = pl.pallas_call(
         kern,
+        name="bittide_sparse",
         grid=(num_records, record_every + (1 if measure else 0),
               i_panels),
         in_specs=in_specs,

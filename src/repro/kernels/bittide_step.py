@@ -256,6 +256,7 @@ def bittide_step_pallas(psi, nu, nu_u, a, lam_eff, lat_frames,
 
     out = pl.pallas_call(
         kern,
+        name="bittide_step",
         grid=(i_tiles, j_tiles),
         in_specs=[
             pl.BlockSpec((c, 1), lambda i, j: (0, 0)),           # lat (C,1)
@@ -765,6 +766,7 @@ def bittide_fused_pallas(psi, nu, nu_u, a, deg, lamsum, lat_frames,
         out_shape.append(jax.ShapeDtypeStruct((b, 1), jnp.int32))
     out = pl.pallas_call(
         kern,
+        name="bittide_fused",
         grid=(num_records,),
         in_specs=in_specs,
         out_specs=out_specs,
@@ -1072,6 +1074,7 @@ def bittide_tiled_fused_pallas(psi, nu, nu_u, a, deg, lamsum, lat_frames,
     measure = record_beta or record_watermarks or record_guard
     out = pl.pallas_call(
         kern,
+        name="bittide_tiled",
         grid=(num_records, record_every + (1 if measure else 0),
               j_tiles),
         in_specs=in_specs,

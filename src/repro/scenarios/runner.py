@@ -128,7 +128,7 @@ from repro.core.reframing import (ReframePolicy, edge_occupancy,
                                   node_net_occupancy, shift_assignment)
 from repro.core.topology import Topology
 from repro.kernels.api import resolve_options
-from repro.kernels.bittide_sparse import ellify
+from repro.kernels.bittide_sparse import ell_tables
 from repro.kernels.bittide_step import (TILE, fused_vmem_bytes,
                                        select_engine, sparse_panel,
                                        sparse_vmem_bytes, tiled_vmem_bytes)
@@ -136,7 +136,8 @@ from repro.kernels.ops import (_auto_interpret, _fused_engine,
                                _host_watermarks, _lamsum_host, _pad_batch,
                                _pad_gain, _pad_table_rows, _perstep_engine,
                                _sparse_engine, latency_classes)
-from repro.telemetry import Watermarks, coerce_trace, compile_stats
+from repro.telemetry import (NULL_TRACE, Watermarks, coerce_trace,
+                             compile_stats)
 from repro.telemetry.api import resolve_telemetry
 
 from .compiler import CompiledScenario, compile_scenario
@@ -147,7 +148,27 @@ __all__ = ["AppliedReframe", "ScenarioResult", "run_scenario"]
 _DENSE_ENGINES = ("auto", "fused", "tiled", "per-step")
 
 
-def _guard_band_cols(b_pad: int, b: int, target: float, guard_rows):
+def _put(tr, x: np.ndarray):
+    """Place a host array on the device, counted in ``h2d_bytes``."""
+    tr.count("h2d_bytes", x.nbytes)
+    return jnp.asarray(x)
+
+
+def _fetch(tr, x) -> np.ndarray:
+    """Read a device array back, counted (padded) in ``d2h_bytes``."""
+    tr.count("d2h_bytes", x.nbytes)
+    return np.asarray(x)
+
+
+def _fetch_watermarks(tr, wm_dev, num_records: int, b: Optional[int],
+                      n: int) -> Watermarks:
+    """:func:`_host_watermarks`, its reads counted in ``d2h_bytes``."""
+    tr.count("d2h_bytes", sum(x.nbytes for x in wm_dev))
+    return _host_watermarks(wm_dev, num_records, b, n)
+
+
+def _guard_band_cols(b_pad: int, b: int, target: float, guard_rows,
+                     tr=NULL_TRACE):
     """Padded (B_pad, 1) f32 in-kernel guard-band columns.
 
     Padding draws get an unbounded band (their zero state must never trip
@@ -156,7 +177,7 @@ def _guard_band_cols(b_pad: int, b: int, target: float, guard_rows):
     ghi = np.full((b_pad, 1), 1e30, np.float32)
     glo[:b, 0] = target - guard_rows
     ghi[:b, 0] = target + guard_rows
-    return jnp.asarray(glo), jnp.asarray(ghi)
+    return _put(tr, glo), _put(tr, ghi)
 
 
 @dataclasses.dataclass(frozen=True)
@@ -424,7 +445,7 @@ class _DenseStacks:
 
 
 def _build_dense_stacks(topo: Topology, comp, cfg: SimConfig,
-                        tile: int = TILE) -> _DenseStacks:
+                        tile: int = TILE, tr=NULL_TRACE) -> _DenseStacks:
     """Build every segment's (C, N_pad, N_pad) A stack up front.
 
     Closes the ROADMAP host-densify item: the old path re-densified the
@@ -476,9 +497,15 @@ def _build_dense_stacks(topo: Topology, comp, cfg: SimConfig,
         inv_list.append(inv)
         key = (inv.tobytes(), w.tobytes())
         if key not in by_key:
-            by_key[key] = jax.device_put(master.astype(np.float32))
+            a32 = master.astype(np.float32)
+            with tr.span("segment.upload"):
+                tr.count("h2d_bytes", a32.nbytes)
+                by_key[key] = jax.device_put(a32)
         out.append(by_key[key])
-    lam_dummy = jax.device_put(np.zeros((c, 1, 1), np.float32))
+    dummy = np.zeros((c, 1, 1), np.float32)
+    with tr.span("segment.upload"):
+        tr.count("h2d_bytes", dummy.nbytes)
+        lam_dummy = jax.device_put(dummy)
     return _DenseStacks(out, lam_dummy, classes, n_pad,
                         class_rows=per_draw, inv=inv_list)
 
@@ -505,7 +532,7 @@ class _SparseTables:
 
 
 def _build_sparse_tables(topo: Topology, comp, cfg: SimConfig,
-                         tile: int = TILE) -> _SparseTables:
+                         tile: int = TILE, tr=NULL_TRACE) -> _SparseTables:
     """Build every segment's slot tables up front (deduped, one device
     placement per unique (latency, weight) parameter set)."""
     n_pad = ((topo.num_nodes + tile - 1) // tile) * tile
@@ -516,11 +543,14 @@ def _build_sparse_tables(topo: Topology, comp, cfg: SimConfig,
         w_np = np.asarray(seg.edge_w, np.float64)
         key = (lat_f.tobytes(), w_np.tobytes())
         if key not in by_key:
-            nbr_j, latf_j, w_j = ellify(topo, lat_f, edge_w=w_np,
-                                        n_pad=n_pad)
-            if nbr is None:
-                nbr = jax.device_put(nbr_j)
-            by_key[key] = (jax.device_put(latf_j), jax.device_put(w_j))
+            tabs = ell_tables(topo, lat_f, edge_w=w_np, n_pad=n_pad)
+            with tr.span("segment.upload"):
+                if nbr is None:
+                    tr.count("h2d_bytes", tabs[0].nbytes)
+                    nbr = jax.device_put(tabs[0])
+                tr.count("h2d_bytes", tabs[1].nbytes + tabs[2].nbytes)
+                by_key[key] = (jax.device_put(tabs[1]),
+                               jax.device_put(tabs[2]))
         latf_list.append(by_key[key][0])
         w_list.append(by_key[key][1])
     return _SparseTables(nbr, latf_list, w_list, n_pad)
@@ -529,7 +559,7 @@ def _build_sparse_tables(topo: Topology, comp, cfg: SimConfig,
 def _prep_sparse_segment(topo: Topology, links_seg: LinkParams, seg,
                          ctrl: ControllerConfig, ppm2d: np.ndarray,
                          cfg: SimConfig, tables: _SparseTables,
-                         seg_index: int, variant: dict):
+                         seg_index: int, variant: dict, tr=NULL_TRACE):
     """Host-side prep for one sparse-lane segment (once per segment).
 
     Mirrors :func:`_prep_dense_segment`: picks up the precomputed slot
@@ -564,12 +594,13 @@ def _prep_sparse_segment(topo: Topology, links_seg: LinkParams, seg,
         mask_pad[:n] = mask_np
     kp_j = _pad_gain(broadcast_gain(ctrl.kp, b), b_pad)
     boff_j = _pad_gain(broadcast_gain(ctrl.beta_off, b, "beta_off"), b_pad)
-    return (latf_j, w_j, jnp.asarray(lamsum_pad), jnp.asarray(mask_pad),
+    tr.count("h2d_bytes", nu_u.nbytes + kp_j.nbytes + boff_j.nbytes)
+    return (latf_j, w_j, _put(tr, lamsum_pad), _put(tr, mask_pad),
             nu_u, kp_j, boff_j, ti, b_pad, n_pad)
 
 
 def _lam_stack(topo: Topology, inv: np.ndarray, lam_eff_row, edge_w,
-               c: int, n_pad: int):
+               c: int, n_pad: int, tr=NULL_TRACE):
     """(C, N_pad, N_pad) λeff tensor for one draw on the per-step lane.
 
     The same per-edge w·λeff scatter ``densify`` performs (float32
@@ -585,13 +616,13 @@ def _lam_stack(topo: Topology, inv: np.ndarray, lam_eff_row, edge_w,
          else np.asarray(edge_w, np.float64))
     np.add.at(lam, (inv, dst, src),
               np.asarray(lam_eff_row, np.float64) * w)
-    return jnp.asarray(lam)
+    return _put(tr, lam)
 
 
 def _prep_dense_segment(topo: Topology, links_seg: LinkParams, seg, comp,
                         ctrl: ControllerConfig, ppm2d: np.ndarray,
                         cfg: SimConfig, engine: str, stacks: _DenseStacks,
-                        seg_index: int, variant: dict):
+                        seg_index: int, variant: dict, tr=NULL_TRACE):
     """Host-side prep for one dense-engine segment (done once per segment).
 
     Args:
@@ -638,10 +669,10 @@ def _prep_dense_segment(topo: Topology, links_seg: LinkParams, seg, comp,
         inv_seg = stacks.inv[seg_index]
         if beta0.ndim == 2:
             lam_list = [_lam_stack(topo, inv_seg, beta0[bi], seg.edge_w,
-                                   c, n_pad) for bi in range(b)]
+                                   c, n_pad, tr) for bi in range(b)]
         else:
             lam0 = _lam_stack(topo, inv_seg, beta0_rows[0], seg.edge_w,
-                              c, n_pad)
+                              c, n_pad, tr)
             lam_list = [lam0] * max(b, 1)
     else:
         lam_list = [stacks.lam_dummy] * max(b, 1)
@@ -669,9 +700,10 @@ def _prep_dense_segment(topo: Topology, links_seg: LinkParams, seg, comp,
         mask_pad[:n] = mask_np
     kp_j = _pad_gain(broadcast_gain(ctrl.kp, b), b_pad)
     boff_j = _pad_gain(broadcast_gain(ctrl.beta_off, b, "beta_off"), b_pad)
-    return (a, lam_list, jnp.asarray(lamsum_pad),
-            jnp.asarray(np.ascontiguousarray(lat_pad)),
-            jnp.asarray(mask_pad), nu_u, kp_j, boff_j, chosen, tj,
+    tr.count("h2d_bytes", nu_u.nbytes + kp_j.nbytes + boff_j.nbytes)
+    return (a, lam_list, _put(tr, lamsum_pad),
+            _put(tr, np.ascontiguousarray(lat_pad)),
+            _put(tr, mask_pad), nu_u, kp_j, boff_j, chosen, tj,
             b_pad, n_pad)
 
 
@@ -799,10 +831,24 @@ def run_scenario(topo: Topology, links: LinkParams, ctrl: ControllerConfig,
         watermarks=record_watermarks,
         trace=trace if trace else None,
         guard=auto_reframe if auto_reframe else None)
+    tr = coerce_trace(tel.trace, name="run_scenario")
+    with tr.span("scenario", engine=opts.engine):
+        return _run_scenario(topo, links, ctrl, ppm_u, scenario, cfg,
+                             compiled, opts, tel, beta_explicit, tr)
+
+
+def _run_scenario(topo: Topology, links: LinkParams, ctrl: ControllerConfig,
+                  ppm_u, scenario: Scenario, cfg: SimConfig,
+                  compiled: Optional[CompiledScenario], opts, tel,
+                  beta_explicit: bool, tr) -> ScenarioResult:
+    """The body of :func:`run_scenario`, inside its ``scenario`` span."""
     engine = opts.engine
     ppm_u = np.asarray(ppm_u, np.float32)
     single = ppm_u.ndim == 1
-    comp = compiled or compile_scenario(scenario, topo, links, cfg)
+    comp = compiled
+    if not comp:
+        with tr.span("segment.compile"):
+            comp = compile_scenario(scenario, topo, links, cfg)
     chunk = opts.chunk_records or comp.chunk_records
     for s in comp.segments:
         if chunk < 1 or s.records % chunk:
@@ -849,7 +895,6 @@ def run_scenario(topo: Topology, links: LinkParams, ctrl: ControllerConfig,
     rb_seg = tel.beta if beta_explicit else cfg.record_beta
     rb_dense = tel.beta if beta_explicit else False
     rw = tel.watermarks
-    tr = coerce_trace(tel.trace, name="run_scenario")
     cs0 = dict(compile_stats()) if tr else None
 
     guard_on = bool(tel.guard)
@@ -866,27 +911,29 @@ def run_scenario(topo: Topology, links: LinkParams, ctrl: ControllerConfig,
             # record in the RESULT by default so pre-redesign callers
             # still see ScenarioResult.beta.
             rb_seg = rb_dense = True
-        if policy.margin is None:
-            # Per-draw margins: each draw's OWN gain and disturbance
-            # bound — one margin computed from the stiffest draw
-            # under-guarded the rest of a gain-sweep batch.
-            kp_rows = np.asarray(broadcast_gain(ctrl.kp, b_g), np.float64)
-            ppm_rows = np.broadcast_to(
-                np.abs(np.atleast_2d(ppm_u)).max(axis=1), (b_g,))
-            dppm_rows = np.zeros(b_g, np.float64)
-            for s in comp.segments:
-                d = np.abs(np.asarray(s.dppm, np.float64))
-                dppm_rows = np.maximum(
-                    dppm_rows, d.max(axis=1) if d.ndim == 2 else d.max())
-            lat_max = max(float(np.asarray(s.latency_s).max())
-                          for s in comp.segments) * cfg.omega_nom
-            margins = reframe_guard_margins(
-                topo, kp_rows, cfg.dt, cfg.record_every,
-                (ppm_rows + dppm_rows) * 1e-6, lat_max, cfg.omega_nom)
-        else:
-            margins = np.full(b_g, float(policy.margin))
-        guard_rows = np.asarray(policy.guard(margins),
-                                np.float64).reshape(-1)
+        with tr.span("guard", name="margins"):
+            if policy.margin is None:
+                # Per-draw margins: each draw's OWN gain and disturbance
+                # bound — one margin computed from the stiffest draw
+                # under-guarded the rest of a gain-sweep batch.
+                kp_rows = np.asarray(broadcast_gain(ctrl.kp, b_g),
+                                     np.float64)
+                ppm_rows = np.broadcast_to(
+                    np.abs(np.atleast_2d(ppm_u)).max(axis=1), (b_g,))
+                dppm_rows = np.zeros(b_g, np.float64)
+                for s in comp.segments:
+                    d = np.abs(np.asarray(s.dppm, np.float64))
+                    dppm_rows = np.maximum(
+                        dppm_rows, d.max(axis=1) if d.ndim == 2 else d.max())
+                lat_max = max(float(np.asarray(s.latency_s).max())
+                              for s in comp.segments) * cfg.omega_nom
+                margins = reframe_guard_margins(
+                    topo, kp_rows, cfg.dt, cfg.record_every,
+                    (ppm_rows + dppm_rows) * 1e-6, lat_max, cfg.omega_nom)
+            else:
+                margins = np.full(b_g, float(policy.margin))
+            guard_rows = np.asarray(policy.guard(margins),
+                                    np.float64).reshape(-1)
 
     rec_period = cfg.dt * cfg.record_every
     beta0_base = np.asarray(links.beta0, np.float64)
@@ -905,8 +952,13 @@ def run_scenario(topo: Topology, links: LinkParams, ctrl: ControllerConfig,
     eng_label, tile_j = engine, 0
     # All segments' dense adjacency stacks / sparse slot tables, built
     # once (the chunk loops never re-densify A or re-scatter slots).
-    stacks = _build_dense_stacks(topo, comp, cfg) if dense else None
-    tables = _build_sparse_tables(topo, comp, cfg) if sparse else None
+    stacks = tables = None
+    if dense or sparse:
+        with tr.span("segment.stacks"):
+            if dense:
+                stacks = _build_dense_stacks(topo, comp, cfg, tr=tr)
+            else:
+                tables = _build_sparse_tables(topo, comp, cfg, tr=tr)
     interp = _auto_interpret()
     variant = dict(record_beta=bool(rb_dense), record_watermarks=bool(rw),
                    record_guard=guard_on)
@@ -919,37 +971,42 @@ def run_scenario(topo: Topology, links: LinkParams, ctrl: ControllerConfig,
             return (np.zeros_like(ppm_u, np.float64),
                     ppm_u.astype(np.float64) * 1e-6)
         if dense or sparse:
-            psi_now = np.asarray(psi_pad)[:b, :n]
-            nu_now = np.asarray(nu_pad)[:b, :n]
+            psi_now = _fetch(tr, psi_pad)[:b, :n]
+            nu_now = _fetch(tr, nu_pad)[:b, :n]
             return (psi_now[0], nu_now[0]) if single else (psi_now, nu_now)
         return state.psi, state.nu
 
     for si, seg in enumerate(comp.segments):
         lat_frames = np.asarray(seg.latency_s, np.float64) * cfg.omega_nom
         if seg.reestablish:
-            psi_now, nu_now = live_state()
-            lam_eff = _apply_reestablish(
-                lam_eff, seg.reestablish, beta0_base, psi_now, nu_now,
-                lat_frames, topo)
+            with tr.span("segment.splice", segment=si):
+                psi_now, nu_now = live_state()
+                lam_eff = _apply_reestablish(
+                    lam_eff, seg.reestablish, beta0_base, psi_now, nu_now,
+                    lat_frames, topo)
         for ev in seg.reframe:
             # Explicit Reframe events: resolved at the boundary against
             # the live state (like re-establishment), applied as a λeff
             # rewrite whose Δλ is exactly the pointer shift.
-            psi_now, nu_now = live_state()
-            lam_eff, shift = _rotation_shifts(
-                topo, lam_eff, psi_now, nu_now, lat_frames, seg.edge_w,
-                ev.mode, ev.target, edges=ev.edges, explicit=ev.shift)
-            reframes.append(AppliedReframe(
-                record=seg.start_record, time=seg.start_record * rec_period,
-                shift=shift, auto=False))
-            tr.event("reframe", record=int(seg.start_record), auto=False,
-                     segment=si, max_shift=int(np.abs(shift).max()))
-        dppm32 = np.asarray(seg.dppm, np.float32)
-        ppm_seg = (ppm_u + dppm32 if (single or dppm32.ndim == 2)
-                   else ppm_u + dppm32[None])
-        links_seg = LinkParams(latency_s=seg.latency_s,
-                               beta0=np.array(lam_eff, copy=True))
-        lam_rows.append(_lam_table(lam_eff, seg.latency_s, cfg.omega_nom))
+            with tr.span("reframe", record=int(seg.start_record),
+                         auto=False, segment=si):
+                psi_now, nu_now = live_state()
+                lam_eff, shift = _rotation_shifts(
+                    topo, lam_eff, psi_now, nu_now, lat_frames, seg.edge_w,
+                    ev.mode, ev.target, edges=ev.edges, explicit=ev.shift)
+                reframes.append(AppliedReframe(
+                    record=seg.start_record,
+                    time=seg.start_record * rec_period, shift=shift,
+                    auto=False))
+                tr.note(max_shift=int(np.abs(shift).max()))
+        with tr.span("segment.prep", segment=si):
+            dppm32 = np.asarray(seg.dppm, np.float32)
+            ppm_seg = (ppm_u + dppm32 if (single or dppm32.ndim == 2)
+                       else ppm_u + dppm32[None])
+            links_seg = LinkParams(latency_s=seg.latency_s,
+                                   beta0=np.array(lam_eff, copy=True))
+            lam_rows.append(_lam_table(lam_eff, seg.latency_s,
+                                       cfg.omega_nom))
         if policy is not None:
             # Guard preparation: the dense record is the per-NODE net
             # occupancy, but the buffer wall is per EDGE.  The
@@ -963,11 +1020,13 @@ def run_scenario(topo: Topology, links: LinkParams, ctrl: ControllerConfig,
             # (one segment per record) pay it once, not per segment.
             wkey = np.asarray(seg.edge_w, np.float64).tobytes()
             if wkey not in guard_cache:
-                deg_c = np.zeros(n, np.float64)
-                np.add.at(deg_c, np.asarray(topo.dst),
-                          np.asarray(seg.edge_w, np.float64))
-                guard_cache[wkey] = (deg_c, laplacian_pinv(
-                    laplacian(topo, np.asarray(seg.edge_w, np.float64))))
+                with tr.span("guard", name="pinv", segment=si):
+                    deg_c = np.zeros(n, np.float64)
+                    np.add.at(deg_c, np.asarray(topo.dst),
+                              np.asarray(seg.edge_w, np.float64))
+                    guard_cache[wkey] = (deg_c, laplacian_pinv(
+                        laplacian(topo, np.asarray(seg.edge_w,
+                                                   np.float64))))
             deg_w, lap_pinv = guard_cache[wkey]
             src_np, dst_np = np.asarray(topo.src), np.asarray(topo.dst)
 
@@ -991,10 +1050,13 @@ def run_scenario(topo: Topology, links: LinkParams, ctrl: ControllerConfig,
             # split as the dense lanes, but the traced tables are the
             # precomputed slot tables — per-draw weights and fully
             # heterogeneous per-draw latencies included.
-            (latf_j, w_j, lamsum_j, mask_j, nu_u_j, kp_j, boff_j, ti,
-             b_pad, n_pad) = _prep_sparse_segment(
-                topo, links_seg, seg, ctrl, np.atleast_2d(ppm_seg), cfg,
-                tables, si, variant)
+            with tr.span("segment.prep", segment=si):
+                (latf_j, w_j, lamsum_j, mask_j, nu_u_j, kp_j, boff_j, ti,
+                 b_pad, n_pad) = _prep_sparse_segment(
+                    topo, links_seg, seg, ctrl, np.atleast_2d(ppm_seg), cfg,
+                    tables, si, variant, tr)
+                if psi_pad is None:
+                    psi_pad, nu_pad = jnp.zeros_like(nu_u_j), nu_u_j
             eng_label, tile_j = "sparse", ti
             tr.event("engine_dispatch", segment=si, engine="sparse",
                      tile_i=int(ti), b_pad=int(b_pad), n_pad=int(n_pad),
@@ -1002,11 +1064,11 @@ def run_scenario(topo: Topology, links: LinkParams, ctrl: ControllerConfig,
                      vmem_est_bytes=sparse_vmem_bytes(
                          b_pad, n_pad, tables.k, ti,
                          max(latf_j.shape[0], w_j.shape[0]), **variant))
-            if psi_pad is None:
-                psi_pad, nu_pad = jnp.zeros_like(nu_u_j), nu_u_j
             dt_frames = float(cfg.omega_nom * cfg.dt)
             if guard_on and gband is None:
-                gband = _guard_band_cols(b_pad, b, policy.target, guard_rows)
+                with tr.span("guard", name="band"):
+                    gband = _guard_band_cols(b_pad, b, policy.target,
+                                             guard_rows, tr)
             seg_done = 0
             while seg_done < seg.records:
                 # Traced stop cap: a post-splice partial chunk keeps the
@@ -1014,28 +1076,34 @@ def run_scenario(topo: Topology, links: LinkParams, ctrl: ControllerConfig,
                 stop = min(chunk, seg.records - seg_done) - 1
                 with tr.span("chunk", engine="sparse", segment=si,
                              launch=launches, records=int(stop + 1)):
-                    out = _sparse_engine(
-                        psi_pad, nu_pad, nu_u_j, kp_j, boff_j, mask_j,
-                        tables.nbr, latf_j, w_j, lamsum_j, dt_frames,
-                        int(chunk), int(cfg.record_every), int(ti), interp,
-                        rb_dense, rw, record_guard=guard_on,
-                        guard_lo=gband[0] if guard_on else None,
-                        guard_hi=gband[1] if guard_on else None,
-                        guard_stop=stop if guard_on else None)
-                    psi_pad, nu_pad = out.psi, out.nu
-                    trips = (np.asarray(out.guard_state)[:b, 0]
-                             if guard_on else None)
-                    tstar = int(trips.min()) if guard_on else chunk
-                    valid = min(tstar, stop) + 1
-                    if rb_dense:
-                        beta_chunks.append(
-                            np.asarray(out.beta)[:valid, :b, :n]
-                            .transpose(1, 0, 2))
-                    freq_chunks.append(
-                        np.asarray(out.freq)[:valid, :b, :n]
-                        .transpose(1, 0, 2) * 1e6)
+                    with tr.span("chunk.dispatch"):
+                        out = _sparse_engine(
+                            psi_pad, nu_pad, nu_u_j, kp_j, boff_j, mask_j,
+                            tables.nbr, latf_j, w_j, lamsum_j, dt_frames,
+                            int(chunk), int(cfg.record_every), int(ti),
+                            interp, rb_dense, rw, record_guard=guard_on,
+                            guard_lo=gband[0] if guard_on else None,
+                            guard_hi=gband[1] if guard_on else None,
+                            guard_stop=stop if guard_on else None)
+                    with tr.span("chunk.wait"):
+                        jax.block_until_ready(out)
+                    with tr.span("chunk.fetch"):
+                        psi_pad, nu_pad = out.psi, out.nu
+                        trips = (_fetch(tr, out.guard_state)[:b, 0]
+                                 if guard_on else None)
+                        tstar = int(trips.min()) if guard_on else chunk
+                        valid = min(tstar, stop) + 1
+                        if rb_dense:
+                            beta_chunks.append(
+                                _fetch(tr, out.beta)[:valid, :b, :n]
+                                .transpose(1, 0, 2))
+                        freq_chunks.append(
+                            _fetch(tr, out.freq)[:valid, :b, :n]
+                            .transpose(1, 0, 2) * 1e6)
+                        if rw:
+                            wm_c = _fetch_watermarks(tr, out.watermarks,
+                                                     valid, b, n)
                 if rw:
-                    wm_c = _host_watermarks(out.watermarks, valid, b, n)
                     wm_acc = wm_c if wm_acc is None else wm_acc.merge(wm_c)
                 launches += 1
                 seg_done += valid
@@ -1050,27 +1118,27 @@ def run_scenario(topo: Topology, links: LinkParams, ctrl: ControllerConfig,
                     # Same per-draw trip + rotation as the dense lanes
                     # (the in-kernel measurement is the identical
                     # per-node net occupancy quantity).
-                    psi_now, nu_now = live_state()
-                    lam_eff, shift = _rotation_shifts(
-                        topo, lam_eff, psi_now, nu_now, lat_frames,
-                        seg.edge_w, "graph", policy.target,
-                        lap_pinv=lap_pinv, rows_mask=(trips == tstar))
-                    reframes.append(AppliedReframe(
-                        record=rec_done, time=rec_done * rec_period,
-                        shift=shift, auto=True, guard_latency=1))
-                    tr.event("reframe", record=int(rec_done), auto=True,
-                             segment=si,
-                             max_shift=int(np.abs(shift).max()))
-                    if seg_done < seg.records:
-                        links_seg = LinkParams(
-                            latency_s=seg.latency_s,
-                            beta0=np.array(lam_eff, copy=True))
-                        (latf_j, w_j, lamsum_j, mask_j, nu_u_j, kp_j,
-                         boff_j, ti, b_pad, n_pad) = \
-                            _prep_sparse_segment(
-                                topo, links_seg, seg, ctrl,
-                                np.atleast_2d(ppm_seg), cfg, tables,
-                                si, variant)
+                    with tr.span("reframe", record=int(rec_done), auto=True,
+                                 segment=si):
+                        psi_now, nu_now = live_state()
+                        lam_eff, shift = _rotation_shifts(
+                            topo, lam_eff, psi_now, nu_now, lat_frames,
+                            seg.edge_w, "graph", policy.target,
+                            lap_pinv=lap_pinv, rows_mask=(trips == tstar))
+                        reframes.append(AppliedReframe(
+                            record=rec_done, time=rec_done * rec_period,
+                            shift=shift, auto=True, guard_latency=1))
+                        tr.note(max_shift=int(np.abs(shift).max()))
+                        if seg_done < seg.records:
+                            links_seg = LinkParams(
+                                latency_s=seg.latency_s,
+                                beta0=np.array(lam_eff, copy=True))
+                            (latf_j, w_j, lamsum_j, mask_j, nu_u_j, kp_j,
+                             boff_j, ti, b_pad, n_pad) = \
+                                _prep_sparse_segment(
+                                    topo, links_seg, seg, ctrl,
+                                    np.atleast_2d(ppm_seg), cfg, tables,
+                                    si, variant, tr)
             continue
 
         if dense:
@@ -1078,10 +1146,16 @@ def run_scenario(topo: Topology, links: LinkParams, ctrl: ControllerConfig,
             # ONCE per segment; the chunk loop below replays the jitted
             # engine on device-resident padded state with zero host
             # rebuilds (A was densified before the segment loop).
-            (a, lam_list, lamsum_j, lat_j, mask_j, nu_u_j, kp_j, boff_j,
-             chosen, tj, b_pad, n_pad) = _prep_dense_segment(
-                topo, links_seg, seg, comp, ctrl, np.atleast_2d(ppm_seg),
-                cfg, engine, stacks, si, variant)
+            with tr.span("segment.prep", segment=si):
+                (a, lam_list, lamsum_j, lat_j, mask_j, nu_u_j, kp_j,
+                 boff_j, chosen, tj, b_pad, n_pad) = _prep_dense_segment(
+                    topo, links_seg, seg, comp, ctrl,
+                    np.atleast_2d(ppm_seg), cfg, engine, stacks, si,
+                    variant, tr)
+                if psi_pad is None:
+                    psi_pad, nu_pad = jnp.zeros_like(nu_u_j), nu_u_j
+                kp_np = _fetch(tr, kp_j)
+                boff_np = _fetch(tr, boff_j)
             eng_label, tile_j = chosen, tj
             c_stack = int(a.shape[0])
             if chosen == "fused":
@@ -1094,13 +1168,11 @@ def run_scenario(topo: Topology, links: LinkParams, ctrl: ControllerConfig,
             tr.event("engine_dispatch", segment=si, engine=chosen,
                      tile_j=int(tj), b_pad=int(b_pad), n_pad=int(n_pad),
                      c=c_stack, vmem_est_bytes=int(vmem_est))
-            if psi_pad is None:
-                psi_pad, nu_pad = jnp.zeros_like(nu_u_j), nu_u_j
             dt_frames = float(cfg.omega_nom * cfg.dt)
-            kp_np = np.asarray(kp_j)
-            boff_np = np.asarray(boff_j)
             if guard_on and gband is None:
-                gband = _guard_band_cols(b_pad, b, policy.target, guard_rows)
+                with tr.span("guard", name="band"):
+                    gband = _guard_band_cols(b_pad, b, policy.target,
+                                             guard_rows, tr)
             seg_done = 0
             while seg_done < seg.records:
                 # Traced stop cap: a post-splice partial chunk keeps the
@@ -1128,11 +1200,17 @@ def run_scenario(topo: Topology, links: LinkParams, ctrl: ControllerConfig,
                                           if guard_on else None),
                                 guard_stop=stop_i if guard_on else None)
 
-                        rows = [launch_ps(bi, stop) for bi in range(b)]
-                        trips = (np.array([int(r.guard_state)
-                                           for r in rows])
-                                 if guard_on else None)
-                        tstar = int(trips.min()) if guard_on else chunk
+                        with tr.span("chunk.dispatch"):
+                            rows = [launch_ps(bi, stop) for bi in range(b)]
+                        with tr.span("chunk.wait"):
+                            jax.block_until_ready(rows)
+                        trips, tstar = None, chunk
+                        if guard_on:
+                            with tr.span("chunk.fetch"):
+                                trips = np.array(
+                                    [int(_fetch(tr, r.guard_state))
+                                     for r in rows])
+                            tstar = int(trips.min())
                         if guard_on and tstar <= stop \
                                 and bool((trips > tstar).any()):
                             # This lane launches draws separately, so the
@@ -1142,50 +1220,60 @@ def run_scenario(topo: Topology, links: LinkParams, ctrl: ControllerConfig,
                             # record — the deterministic prefix lands
                             # their state exactly there, through the same
                             # executable (the cap is traced).
-                            for bi in np.flatnonzero(trips > tstar):
-                                rows[int(bi)] = launch_ps(int(bi),
-                                                          int(tstar))
+                            with tr.span("chunk.dispatch"):
+                                for bi in np.flatnonzero(trips > tstar):
+                                    rows[int(bi)] = launch_ps(int(bi),
+                                                              int(tstar))
+                            with tr.span("chunk.wait"):
+                                jax.block_until_ready(rows)
                         valid = min(tstar, stop) + 1
-                        psi_pad = psi_pad.at[:b].set(
-                            jnp.stack([r.psi for r in rows]))
-                        nu_pad = nu_pad.at[:b].set(
-                            jnp.stack([r.nu for r in rows]))
-                        freq_chunks.append(np.stack(
-                            [np.asarray(r.freq)[:valid, :n]
-                             for r in rows]) * 1e6)
-                        if rb_dense:
-                            beta_chunks.append(np.stack(
-                                [np.asarray(r.beta)[:valid, :n]
-                                 for r in rows]))
-                        if rw:
-                            wm_c = Watermarks.stack(
-                                [_host_watermarks(r.watermarks, valid,
-                                                  None, n) for r in rows])
+                        with tr.span("chunk.fetch"):
+                            psi_pad = psi_pad.at[:b].set(
+                                jnp.stack([r.psi for r in rows]))
+                            nu_pad = nu_pad.at[:b].set(
+                                jnp.stack([r.nu for r in rows]))
+                            freq_chunks.append(np.stack(
+                                [_fetch(tr, r.freq)[:valid, :n]
+                                 for r in rows]) * 1e6)
+                            if rb_dense:
+                                beta_chunks.append(np.stack(
+                                    [_fetch(tr, r.beta)[:valid, :n]
+                                     for r in rows]))
+                            if rw:
+                                wm_c = Watermarks.stack(
+                                    [_fetch_watermarks(tr, r.watermarks,
+                                                       valid, None, n)
+                                     for r in rows])
                     else:
-                        out = _fused_engine(
-                            psi_pad, nu_pad, nu_u_j, kp_j, boff_j, mask_j, a,
-                            lam_list[0], lamsum_j, lat_j, dt_frames,
-                            int(chunk), int(cfg.record_every), chosen,
-                            int(tj), interp, False, rb_dense, rw,
-                            record_guard=guard_on,
-                            guard_lo=gband[0] if guard_on else None,
-                            guard_hi=gband[1] if guard_on else None,
-                            guard_stop=stop if guard_on else None)
-                        psi_pad, nu_pad = out.psi, out.nu
-                        trips = (np.asarray(out.guard_state)[:b, 0]
-                                 if guard_on else None)
-                        tstar = int(trips.min()) if guard_on else chunk
-                        valid = min(tstar, stop) + 1
-                        if rb_dense:
-                            beta_chunks.append(
-                                np.asarray(out.beta)[:valid, :b, :n]
-                                .transpose(1, 0, 2))
-                        if rw:
-                            wm_c = _host_watermarks(out.watermarks, valid,
-                                                    b, n)
-                        freq_chunks.append(
-                            np.asarray(out.freq)[:valid, :b, :n]
-                            .transpose(1, 0, 2) * 1e6)
+                        with tr.span("chunk.dispatch"):
+                            out = _fused_engine(
+                                psi_pad, nu_pad, nu_u_j, kp_j, boff_j,
+                                mask_j, a, lam_list[0], lamsum_j, lat_j,
+                                dt_frames, int(chunk),
+                                int(cfg.record_every), chosen, int(tj),
+                                interp, False, rb_dense, rw,
+                                record_guard=guard_on,
+                                guard_lo=gband[0] if guard_on else None,
+                                guard_hi=gband[1] if guard_on else None,
+                                guard_stop=stop if guard_on else None)
+                        with tr.span("chunk.wait"):
+                            jax.block_until_ready(out)
+                        with tr.span("chunk.fetch"):
+                            psi_pad, nu_pad = out.psi, out.nu
+                            trips = (_fetch(tr, out.guard_state)[:b, 0]
+                                     if guard_on else None)
+                            tstar = int(trips.min()) if guard_on else chunk
+                            valid = min(tstar, stop) + 1
+                            if rb_dense:
+                                beta_chunks.append(
+                                    _fetch(tr, out.beta)[:valid, :b, :n]
+                                    .transpose(1, 0, 2))
+                            if rw:
+                                wm_c = _fetch_watermarks(
+                                    tr, out.watermarks, valid, b, n)
+                            freq_chunks.append(
+                                _fetch(tr, out.freq)[:valid, :b, :n]
+                                .transpose(1, 0, 2) * 1e6)
                 if rw:
                     wm_acc = wm_c if wm_acc is None else wm_acc.merge(wm_c)
                 launches += 1
@@ -1202,36 +1290,36 @@ def run_scenario(topo: Topology, links: LinkParams, ctrl: ControllerConfig,
                     # the freeze record rotate — a drifting draw must not
                     # perturb its well-behaved batchmates (they keep λeff
                     # bit-exactly and log a zero shift row).
-                    psi_now, nu_now = live_state()
-                    lam_eff, shift = _rotation_shifts(
-                        topo, lam_eff, psi_now, nu_now, lat_frames,
-                        seg.edge_w, "graph", policy.target,
-                        lap_pinv=lap_pinv, rows_mask=(trips == tstar))
-                    reframes.append(AppliedReframe(
-                        record=rec_done, time=rec_done * rec_period,
-                        shift=shift, auto=True, guard_latency=1))
-                    tr.event("reframe", record=int(rec_done), auto=True,
-                             segment=si,
-                             max_shift=int(np.abs(shift).max()))
-                    # The rotation rewrites only traced inputs (the
-                    # lamsum fold / per-step λeff tensors), so the
-                    # re-prepped segment replays the SAME compiled
-                    # engine — zero recompiles across splices.  On a
-                    # segment's final record the next segment's own
-                    # prep picks the shifted lam_eff up, so skip the
-                    # re-prep there (its outputs would be discarded).
-                    if seg_done < seg.records:
-                        links_seg = LinkParams(
-                            latency_s=seg.latency_s,
-                            beta0=np.array(lam_eff, copy=True))
-                        (a, lam_list, lamsum_j, lat_j, mask_j, nu_u_j,
-                         kp_j, boff_j, chosen, tj, b_pad, n_pad) = \
-                            _prep_dense_segment(
-                                topo, links_seg, seg, comp, ctrl,
-                                np.atleast_2d(ppm_seg), cfg, engine,
-                                stacks, si, variant)
-                        kp_np = np.asarray(kp_j)
-                        boff_np = np.asarray(boff_j)
+                    with tr.span("reframe", record=int(rec_done), auto=True,
+                                 segment=si):
+                        psi_now, nu_now = live_state()
+                        lam_eff, shift = _rotation_shifts(
+                            topo, lam_eff, psi_now, nu_now, lat_frames,
+                            seg.edge_w, "graph", policy.target,
+                            lap_pinv=lap_pinv, rows_mask=(trips == tstar))
+                        reframes.append(AppliedReframe(
+                            record=rec_done, time=rec_done * rec_period,
+                            shift=shift, auto=True, guard_latency=1))
+                        tr.note(max_shift=int(np.abs(shift).max()))
+                        # The rotation rewrites only traced inputs (the
+                        # lamsum fold / per-step λeff tensors), so the
+                        # re-prepped segment replays the SAME compiled
+                        # engine — zero recompiles across splices.  On a
+                        # segment's final record the next segment's own
+                        # prep picks the shifted lam_eff up, so skip the
+                        # re-prep there (its outputs would be discarded).
+                        if seg_done < seg.records:
+                            links_seg = LinkParams(
+                                latency_s=seg.latency_s,
+                                beta0=np.array(lam_eff, copy=True))
+                            (a, lam_list, lamsum_j, lat_j, mask_j, nu_u_j,
+                             kp_j, boff_j, chosen, tj, b_pad, n_pad) = \
+                                _prep_dense_segment(
+                                    topo, links_seg, seg, comp, ctrl,
+                                    np.atleast_2d(ppm_seg), cfg, engine,
+                                    stacks, si, variant, tr)
+                            kp_np = _fetch(tr, kp_j)
+                            boff_np = _fetch(tr, boff_j)
             continue
 
         tr.event("engine_dispatch", segment=si, engine="segment-sum",
@@ -1285,27 +1373,28 @@ def run_scenario(topo: Topology, links: LinkParams, ctrl: ControllerConfig,
                          tripped=int(np.count_nonzero(tripped)))
                 if tripped.any():
                     first = int(np.flatnonzero(hit.any(axis=0))[0])
-                    lam_eff, shift = _rotation_shifts(
-                        topo, lam_eff, res.psi, res.nu, lat_frames,
-                        seg.edge_w, "graph", policy.target,
-                        lap_pinv=lap_pinv, rows_mask=tripped)
-                    reframes.append(AppliedReframe(
-                        record=rec_done, time=rec_done * rec_period,
-                        shift=shift, auto=True,
-                        guard_latency=int(chunk - first)))
-                    tr.event("reframe", record=int(rec_done), auto=True,
-                             segment=si,
-                             max_shift=int(np.abs(shift).max()))
-                    links_seg = LinkParams(latency_s=seg.latency_s,
-                                           beta0=np.array(lam_eff, copy=True))
+                    with tr.span("reframe", record=int(rec_done), auto=True,
+                                 segment=si):
+                        lam_eff, shift = _rotation_shifts(
+                            topo, lam_eff, res.psi, res.nu, lat_frames,
+                            seg.edge_w, "graph", policy.target,
+                            lap_pinv=lap_pinv, rows_mask=tripped)
+                        reframes.append(AppliedReframe(
+                            record=rec_done, time=rec_done * rec_period,
+                            shift=shift, auto=True,
+                            guard_latency=int(chunk - first)))
+                        tr.note(max_shift=int(np.abs(shift).max()))
+                        links_seg = LinkParams(
+                            latency_s=seg.latency_s,
+                            beta0=np.array(lam_eff, copy=True))
 
     axis = 1 if (dense or sparse or not single) else 0
     freq = np.concatenate(freq_chunks, axis=axis)
     if dense or sparse:
         if single:
             freq = freq[0]
-        psi_f = np.asarray(psi_pad)[:b, :n]
-        nu_f = np.asarray(nu_pad)[:b, :n]
+        psi_f = _fetch(tr, psi_pad)[:b, :n]
+        nu_f = _fetch(tr, nu_pad)[:b, :n]
         if rb_dense:
             beta = np.concatenate(beta_chunks, axis=1)
             if single:

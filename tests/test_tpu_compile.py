@@ -25,6 +25,7 @@ unwritten memory with NaN; each lane runs under it once, with every
 telemetry output on and a guard that freezes after the first record.
 """
 import os
+import re
 
 import jax
 import jax.numpy as jnp
@@ -65,13 +66,15 @@ def one_chip():
     jax.config.update("jax_enable_compilation_cache", cache_was)
 
 
-def _compile(fn, sharding, *shapes):
+def _compile(fn, sharding, kernel, *shapes):
     """Lower and compile ``fn`` for the described chip; assert the Pallas
-    kernel is in the executable."""
+    kernel is in the executable under its stable name (the instruction
+    name the device trace shows)."""
     args = [jax.ShapeDtypeStruct(shape, dtype, sharding=sharding)
             for shape, dtype in shapes]
     compiled = jax.jit(fn).lower(*args).compile()
-    assert "tpu_custom_call" in compiled.as_text()
+    assert re.search(rf'%{kernel}(\.\d+)? = [^\n]*custom_call_target='
+                     '"tpu_custom_call"', compiled.as_text())
     return compiled
 
 
@@ -93,7 +96,9 @@ def _compile_dense(kernel, one_chip, n, c, tel, **kw):
         return kernel(psi, nu, nu_u, a, deg, lamsum, lat, kp, boff, 125.0,
                       num_records=4, record_every=10, ctrl_mask=mask,
                       interpret=False, **tel, **_guard(tel), **kw)
-    return _compile(fn, one_chip, *_batched_state(n),
+    name = ("bittide_tiled" if kernel is bittide_tiled_fused_pallas
+            else "bittide_fused")
+    return _compile(fn, one_chip, name, *_batched_state(n),
                     ((c, n, n), F32), ((1, n), F32), ((B, c), F32))
 
 
@@ -129,7 +134,7 @@ def test_sparse_compiles_at_fig18_torus(one_chip, tel):
             psi, nu, nu_u, nbr, latf, w, lamsum, kp, boff, 125.0,
             num_records=4, record_every=10, tile_i=tile_i, ctrl_mask=mask,
             interpret=False, **tel, **_guard(tel))
-    _compile(fn, one_chip, *_batched_state(TORUS_N),
+    _compile(fn, one_chip, "bittide_sparse", *_batched_state(TORUS_N),
              ((TORUS_K, TORUS_N), I32), ((1, TORUS_K, TORUS_N), F32),
              ((1, TORUS_K, TORUS_N), F32))
 
@@ -140,8 +145,8 @@ def test_per_step_kernel_compiles(one_chip):
     def fn(psi, nu, nu_u, a, lam, lat):
         return bittide_step_pallas(psi, nu, nu_u, a, lam, lat, 2e-8, 0.0,
                                    125.0, emit_beta=True, interpret=False)
-    _compile(fn, one_chip, ((n,), F32), ((n,), F32), ((n,), F32),
-             ((c, n, n), F32), ((c, n, n), F32), ((c,), F32))
+    _compile(fn, one_chip, "bittide_step", ((n,), F32), ((n,), F32),
+             ((n,), F32), ((c, n, n), F32), ((c, n, n), F32), ((c,), F32))
 
 
 @pytest.mark.parametrize("lane", ["fused", "tiled", "sparse"])
