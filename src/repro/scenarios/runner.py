@@ -26,14 +26,14 @@ Engines:
                   controller, shared base links (per-draw λeff from
                   re-establishment is supported; per-draw base latencies
                   belong on segment-sum).  The per-segment (C, N, N)
-                  adjacency stacks are built ONCE up front
-                  (:func:`_build_dense_stacks`): segment-to-segment
-                  diff-updates touch only the edges whose latency class
-                  or weight changed, repeated parameter sets (swap-back
-                  events) are deduped, and each unique stack is placed on
-                  the device a single time — the chunk loop then replays
-                  the jitted engine with zero host rebuilds and zero
-                  re-transfers.
+                  adjacency stacks are built ONCE up front, on the
+                  device (:func:`_build_dense_stacks`): repeated
+                  parameter sets (swap-back events) are deduped, and
+                  each unique stack uploads only its O(E) edge list,
+                  which one jitted scatter turns into a fresh device
+                  buffer — no N² adjacency exists on the host, and the
+                  chunk loop then replays the jitted engine with zero
+                  host rebuilds and zero re-transfers.
 ``sparse``        the edge-major ELL Pallas lane
                   (``repro.kernels.bittide_sparse``) — same telemetry
                   contract and proportional-controller restriction as the
@@ -111,6 +111,7 @@ are shared across draws).
 from __future__ import annotations
 
 import dataclasses
+import functools
 from typing import List, Optional
 
 import numpy as np
@@ -129,7 +130,7 @@ from repro.core.reframing import (ReframePolicy, edge_occupancy,
 from repro.core.topology import Topology
 from repro.kernels.api import resolve_options
 from repro.kernels.bittide_sparse import ell_tables
-from repro.kernels.bittide_step import (TILE, fused_vmem_bytes,
+from repro.kernels.bittide_step import (SUBLANE, TILE, fused_vmem_bytes,
                                        select_engine, sparse_panel,
                                        sparse_vmem_bytes, tiled_vmem_bytes)
 from repro.kernels.ops import (_auto_interpret, _fused_engine,
@@ -419,12 +420,9 @@ class _DenseStacks:
     """Per-segment dense adjacency stacks, built once per scenario run.
 
     ``a[si]`` is the device-resident (C, N_pad, N_pad) float32 adjacency
-    of segment ``si`` over the scenario's global latency-class axis.  The
-    builder walks the segments ONCE on the host, diff-updating a single
-    master array — only the edges whose latency class or link weight
-    changed between consecutive segments are touched — and dedupes
-    identical parameter sets (a swap-back event reuses the original
-    device buffer), so each unique stack is transferred to the device
+    of segment ``si`` over the scenario's global latency-class axis.
+    Identical parameter sets are deduped (a swap-back event reuses the
+    original device buffer), so each unique stack is built on the device
     exactly once per run however many chunks replay it.  ``lam_dummy``
     is a shared zero (C, 1, 1) placeholder for the fused/tiled engines'
     unused λeff argument (dead in the Pallas jaxpr — those kernels fold
@@ -444,15 +442,45 @@ class _DenseStacks:
         self.num_unique = len({id(x) for x in a})
 
 
+@functools.partial(jax.jit, static_argnames=("c", "n_pad"))
+def _scatter_stack(edges, c: int, n_pad: int):
+    """(C, N_pad, N_pad) float32 adjacency from one edge list, on device.
+
+    ``edges`` is the (4, E) int32 (class, dst, src, weight) of every
+    edge, the float32 weight bit-cast into the last row so the list
+    crosses in one transfer.  The zeros are made inside the jit, so XLA
+    scatters in place into one fresh buffer.  E is fixed for a fabric
+    (dropped links keep weight 0), so one compile serves every call.
+
+    The scatter runs in the TPU's (8, 128) tile order, into a (C,
+    N_pad/8, N_pad/128, 8, 128) buffer whose transpose back to (C, N_pad,
+    N_pad) is a bitcast of the tiled layout.  Scattered straight into
+    (C, N_pad, N_pad), XLA scatters a flat buffer and then relays it out,
+    holding a second N² buffer.
+    """
+    cls, dst, src = edges[0], edges[1], edges[2]
+    w = jax.lax.bitcast_convert_type(edges[3], jnp.float32)
+    t = jnp.zeros((c, n_pad // SUBLANE, n_pad // TILE, SUBLANE, TILE),
+                  jnp.float32)
+    t = t.at[cls, dst // SUBLANE, src // TILE, dst % SUBLANE,
+             src % TILE].add(w)
+    return t.transpose(0, 1, 3, 2, 4).reshape(c, n_pad, n_pad)
+
+
 def _build_dense_stacks(topo: Topology, comp, cfg: SimConfig,
-                        tile: int = TILE, tr=NULL_TRACE) -> _DenseStacks:
+                        tr=NULL_TRACE) -> _DenseStacks:
     """Build every segment's (C, N_pad, N_pad) A stack up front.
 
-    Closes the ROADMAP host-densify item: the old path re-densified the
-    full stack inside the segment loop on every ``run_scenario`` call;
-    Fig-18-scale scenario studies pay O(C·N²) per segment for what is
-    usually a 2-edge cable swap.  Here segment 0 pays the full scatter
-    and each subsequent segment pays O(|changed edges|).
+    The host keeps only what is O(E): each segment's edge→class map and
+    edge weights, deduped on their bytes.  Each unique set uploads its
+    (4, E) edge list and :func:`_scatter_stack` scatters it into a fresh
+    device buffer.
+
+    The result equals :func:`repro.kernels.densify` cell for cell
+    whenever each (class, dst, src) cell receives one edge or its
+    weights sum exactly in float32 (0/1 weights, so every fabric here).
+    Parallel edges with fractional weights may accumulate in another
+    order, so such a cell can differ by one float32 ulp.
 
     Under per-draw column-signature latency classes (chaos campaigns) the
     compiler has already assigned every segment's edges to the global
@@ -467,14 +495,7 @@ def _build_dense_stacks(topo: Topology, comp, cfg: SimConfig,
     else:
         classes = np.asarray(comp.lat_classes, np.float64)
         c = len(classes)
-    n_pad = ((topo.num_nodes + tile - 1) // tile) * tile
-    dst = np.asarray(topo.dst, np.int64)
-    src = np.asarray(topo.src, np.int64)
-    # float64 master: diff-updates subtract and re-add edge weights, which
-    # stays exact for the 0/1-ish weights but would accumulate rounding in
-    # float32 over many segments.
-    master = np.zeros((c, n_pad, n_pad), np.float64)
-    prev_inv = prev_w = None
+    n_pad = ((topo.num_nodes + TILE - 1) // TILE) * TILE
     by_key, out, inv_list = {}, [], []
     for si, seg in enumerate(comp.segments):
         if per_draw is not None:
@@ -485,22 +506,15 @@ def _build_dense_stacks(topo: Topology, comp, cfg: SimConfig,
             _, inv = latency_classes(lat_frames, lat_classes=classes)
             inv = np.asarray(inv, np.int64)
         w = np.asarray(seg.edge_w, np.float64)
-        if prev_inv is None:
-            np.add.at(master, (inv, dst, src), w)
-        else:
-            ch = np.nonzero((inv != prev_inv) | (w != prev_w))[0]
-            if len(ch):
-                np.add.at(master, (prev_inv[ch], dst[ch], src[ch]),
-                          -prev_w[ch])
-                np.add.at(master, (inv[ch], dst[ch], src[ch]), w[ch])
-        prev_inv, prev_w = inv, w
         inv_list.append(inv)
         key = (inv.tobytes(), w.tobytes())
         if key not in by_key:
-            a32 = master.astype(np.float32)
+            edges = np.stack([inv.astype(np.int32), topo.dst, topo.src,
+                              w.astype(np.float32).view(np.int32)])
             with tr.span("segment.upload"):
-                tr.count("h2d_bytes", a32.nbytes)
-                by_key[key] = jax.device_put(a32)
+                tr.count("h2d_bytes", edges.nbytes)
+                edges_d = jax.device_put(edges)
+            by_key[key] = _scatter_stack(edges_d, c=c, n_pad=n_pad)
         out.append(by_key[key])
     dummy = np.zeros((c, 1, 1), np.float32)
     with tr.span("segment.upload"):

@@ -19,16 +19,20 @@ def compile_stats() -> dict:
 
     fused and tiled share one jitted wrapper (the engine choice is a
     static argument of ``_fused_engine``), so they share a key here.
+    ``dense-stacks`` is the scenario runner's device builder of the
+    dense lanes' adjacency stacks.
     """
     from repro.core.frame_model import _jitted_run, _jitted_run_ensemble
     from repro.kernels.ops import (_fused_engine, _perstep_engine,
                                    _sparse_engine)
+    from repro.scenarios.runner import _scatter_stack
     return {
         "fused/tiled": _fused_engine._cache_size(),
         "per-step": _perstep_engine._cache_size(),
         "sparse": _sparse_engine._cache_size(),
         "segment-sum": _jitted_run()._cache_size(),
         "segment-sum-ensemble": _jitted_run_ensemble()._cache_size(),
+        "dense-stacks": _scatter_stack._cache_size(),
     }
 
 

@@ -17,9 +17,10 @@ last three):
 
     scenario          span: one run_scenario call, parent of the rest
     segment.compile   span: compile_scenario (when not passed compiled=)
-    segment.stacks    span: the dense adjacency stacks / sparse slot
-                      tables built up front
-    segment.upload    span: their device_put (child of segment.stacks)
+    segment.stacks    span: the dense adjacency stacks (scattered on the
+                      device) / sparse slot tables built up front
+    segment.upload    span: their device_put — the dense stacks' edge
+                      lists (child of segment.stacks)
     segment.prep      span: a segment's prep at its start (ppm and λeff
                       folds, λ table, padding, uploads, initial state)
     segment.splice    span: a re-establish with its live-state read

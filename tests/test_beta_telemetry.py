@@ -22,7 +22,8 @@ independent references:
 plus the chaining/compile contracts: split runs are bit-identical to
 unsplit ones with β on, ``DenseResult.beta_final`` is exact, scenario
 replays with β add zero compiles across segments, and the runner's
-precomputed adjacency stacks dedupe swap-back segments.
+adjacency stacks, scattered on the device from edge lists, equal
+``densify`` cell for cell, dedupe swap-back segments, and compile once.
 """
 import numpy as np
 import pytest
@@ -30,18 +31,19 @@ import pytest
 from engine_harness import (BETA_PARITY_CASES, KERNEL_ENGINES,
                             node_recon as _node_recon,
                             zero_mean_ppm as _zero_mean_ppm)
-from repro.core import (ControllerConfig, SimConfig, fully_connected,
-                        make_links, simulate, torus3d)
+from repro.core import (ControllerConfig, SimConfig, Topology,
+                        fully_connected, make_links, ring, simulate, torus3d)
 from repro.core.envelopes import (check_occupancy_envelope, default_slack,
                                   freq_step_envelope, latency_step_envelope)
 from repro.core.frame_level import simulate_frames
 from repro.kernels import simulate_ensemble_dense, simulate_fused
 from repro.kernels.ops import (_fused_engine, _perstep_engine,
                                _sparse_engine)
-from repro.scenarios import (FreqStep, LatencyStep, Mark, Scenario,
-                             edges_between, run_scenario)
+from repro.scenarios import (FreqStep, LatencyStep, LinkDrop, LinkRestore,
+                             Mark, Scenario, edges_between, run_scenario)
 from repro.scenarios.runner import _build_dense_stacks
 from repro.scenarios.compiler import compile_scenario
+from repro.telemetry import Telemetry, no_new_compiles
 
 ENGINES = KERNEL_ENGINES
 
@@ -321,13 +323,23 @@ def test_scenario_beta_parity_through_latency_step(reestablish):
 
 # ------------------------------------------- precomputed adjacency stacks
 
-def test_dense_stacks_dedupe_and_match_densify():
-    """The runner's up-front A stacks equal per-segment densify output
-    exactly, and a swap-back scenario reuses the original device buffer
-    (diff-update + dedupe)."""
+def _assert_stacks_match_densify(topo, links, comp, cfg, stacks):
     from repro.core.frame_model import LinkParams
     from repro.kernels import densify
 
+    assert len(stacks.a) == comp.num_segments
+    for seg, a_dev in zip(comp.segments, stacks.a):
+        a_ref, _, _, _ = densify(
+            topo, LinkParams(latency_s=seg.latency_s,
+                             beta0=np.asarray(links.beta0)),
+            cfg.omega_nom, lat_classes=comp.lat_classes, edge_w=seg.edge_w)
+        np.testing.assert_array_equal(np.asarray(a_dev), np.asarray(a_ref))
+
+
+def test_dense_stacks_dedupe_and_match_densify():
+    """The runner's up-front A stacks equal per-segment densify output
+    exactly, and a swap-back scenario reuses the original device buffer
+    (one edge-list scatter per unique parameter set)."""
     topo = fully_connected(8)
     links = make_links(topo, cable_m=2.0)
     cfg = SimConfig(dt=1e-3, steps=240, record_every=12)
@@ -344,9 +356,84 @@ def test_dense_stacks_dedupe_and_match_densify():
     assert stacks.num_unique == 2
     assert stacks.a[0] is stacks.a[2]
     assert stacks.a[1] is stacks.a[3]
-    for seg, a_dev in zip(comp.segments, stacks.a):
-        a_ref, _, _, _ = densify(
-            topo, LinkParams(latency_s=seg.latency_s,
-                             beta0=np.asarray(links.beta0)),
-            cfg.omega_nom, lat_classes=comp.lat_classes, edge_w=seg.edge_w)
-        np.testing.assert_array_equal(np.asarray(a_dev), np.asarray(a_ref))
+    _assert_stacks_match_densify(topo, links, comp, cfg, stacks)
+
+
+def _torus8_drop_swap():
+    """torus3d(8): a link drops (weight 0) and comes back (weight 1)
+    around a cable swap that is later swapped back."""
+    topo = torus3d(8)
+    sw, drop = edges_between(topo, 0, 1), edges_between(topo, 9, 10)
+    sc = Scenario(events=(
+        LinkDrop(t=0.012, edges=drop),
+        LatencyStep(t=0.024, edges=sw, cable_m=1000.0),
+        LinkRestore(t=0.036, edges=drop),
+        LatencyStep(t=0.048, edges=sw, cable_m=2.0),      # swap back
+    ))
+    # segments: base, dropped, dropped+long, long, base again
+    return topo, sc, "tiled", 4, [(0, 4)]
+
+
+def _ring_parallel_drop():
+    """A bounded-degree multigraph: ring(16) with four doubled links,
+    one copy of a doubled link dropping (0/1 weights, cells of 2)."""
+    r = ring(16)
+    topo = Topology(16, src=np.concatenate([r.src, r.src[:4]]),
+                    dst=np.concatenate([r.dst, r.dst[:4]]),
+                    name="ring16-parallel")
+    sc = Scenario(events=(
+        LinkDrop(t=0.024, edges=(r.num_edges,)),
+        LatencyStep(t=0.036, edges=(2, r.num_edges + 2), cable_m=1000.0),
+        LinkRestore(t=0.048, edges=(r.num_edges,)),
+    ))
+    return topo, sc, "fused", 4, []
+
+
+@pytest.mark.parametrize("case", [_torus8_drop_swap, _ring_parallel_drop],
+                         ids=["torus8-tiled", "ring16-parallel-fused"])
+def test_dense_stacks_match_densify_through_drops_and_swaps(case):
+    """Every segment's device-built stack equals densify exactly through
+    LinkDrop/LinkRestore weights and latency-class moves, on a fabric
+    whose cells each hold one edge and on one with parallel edges; the
+    run on the named lane matches segment-sum."""
+    topo, sc, engine, unique, same = case()
+    links = make_links(topo, cable_m=2.0)
+    cfg = SimConfig(dt=1e-3, steps=72, record_every=12)
+    comp = compile_scenario(sc, topo, links, cfg)
+    stacks = _build_dense_stacks(topo, comp, cfg)
+    assert stacks.num_unique == unique
+    _assert_stacks_match_densify(topo, links, comp, cfg, stacks)
+    for i, j in same:
+        assert stacks.a[i] is stacks.a[j]
+    ctrl, ppm = ControllerConfig(kp=2e-8), _zero_mean_ppm(topo.num_nodes,
+                                                          0.5)
+    res = run_scenario(topo, links, ctrl, ppm, sc, cfg, compiled=comp,
+                       engine=engine)
+    ref = run_scenario(topo, links, ctrl, ppm, sc, cfg, compiled=comp)
+    assert res.engine == engine
+    np.testing.assert_allclose(res.freq_ppm, ref.freq_ppm, rtol=0,
+                               atol=1e-6)
+
+
+@pytest.mark.parametrize("engine", ["fused", "tiled"])
+def test_back_to_back_runs_add_no_compile(engine):
+    """Two run_scenario calls on one fabric with fresh draws: the second
+    compiles nothing — neither the engine nor the device stack builder,
+    which compiles once per fabric, not once per call."""
+    topo = fully_connected(8)
+    links = make_links(topo, cable_m=2.0)
+    cfg = SimConfig(dt=1e-3, steps=96, record_every=12)
+    sc = Scenario(events=(LatencyStep(t=0.048, edges=edges_between(
+        topo, 0, 2), cable_m=1000.0, reestablish=True),))
+    ctrl = ControllerConfig(kp=2e-8)
+    ppm = np.stack([_zero_mean_ppm(8, 2.0, seed=s) for s in range(4)])
+    run_scenario(topo, links, ctrl, ppm, sc, cfg, engine=engine,
+                 telemetry=Telemetry(beta=True))
+    with no_new_compiles():
+        res = run_scenario(topo, links, ctrl, ppm[::-1] * 0.5, sc, cfg,
+                           engine=engine,
+                           telemetry=Telemetry(beta=True, trace=True))
+    assert res.engine == engine
+    (cs,) = res.trace.by_kind("compile_stats")
+    assert cs.data["after"]["dense-stacks"] >= 1
+    assert all(v == 0 for v in cs.data["delta"].values())

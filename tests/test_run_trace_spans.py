@@ -261,7 +261,8 @@ def test_transfer_counters_match_padded_shapes(engine):
     records = [c.data["records"] for c in tr.by_kind("chunk")]
     if engine == "fused":
         c = disp[0].data["c"]
-        h2d = (2 * c * n_pad * n_pad * 4 + c * 4        # stacks, λ dummy
+        e = res.topo.num_edges
+        h2d = (2 * 4 * e * 4 + c * 4                   # edge lists, λ dummy
                + segs * (2 * state + b_pad * c * 4 + n_pad * 4
                          + 2 * b_pad * 4))              # prep
         reads = segs * 2 * b_pad * 4                    # kp, β_off back

@@ -149,6 +149,21 @@ def test_per_step_kernel_compiles(one_chip):
              ((n,), F32), ((c, n, n), F32), ((c, n, n), F32), ((c,), F32))
 
 
+@pytest.mark.parametrize("c,n_pad,e", [(2, 128, 56),
+                                       (1, TORUS_N, 10648 * TORUS_K)],
+                         ids=["testbed", "fig18-torus"])
+def test_stack_builder_holds_one_stack(one_chip, c, n_pad, e):
+    """The scenario runner's device stack builder scatters in place: the
+    executable holds the (C, N_pad, N_pad) output and no second N²
+    buffer (its tile-order transpose is a bitcast)."""
+    from repro.scenarios.runner import _scatter_stack
+    edges = jax.ShapeDtypeStruct((4, e), I32, sharding=one_chip)
+    mem = _scatter_stack.lower(edges, c=c, n_pad=n_pad).compile(
+        ).memory_analysis()
+    assert mem.output_size_in_bytes == c * n_pad * n_pad * 4
+    assert mem.temp_size_in_bytes <= 16 * e
+
+
 @pytest.mark.parametrize("lane", ["fused", "tiled", "sparse"])
 def test_lane_keeps_tpu_pipeline_semantics(lane):
     """Two 128-node panels, three records of two periods, β + watermarks
