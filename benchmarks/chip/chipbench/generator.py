@@ -13,18 +13,19 @@ import numpy as np
 WARMUP_CALL = -1   # the call index of the set-up's warm-up call
 
 
-def draws(config: dict, traffic: dict, seed: int, call: int) -> np.ndarray:
+def draws(config: dict, traffic: dict, kinds, seed: int,
+          call: int) -> np.ndarray:
     """(draws, nodes) float32 unadjusted oscillator offsets in ppm,
     uniform in ±``oscillator_ppm``."""
     from .reference import topology_nodes
     rng = np.random.default_rng([int(seed) % 2**64, int(call) + 1])
     lim = float(config["oscillator_ppm"])
-    shape = (int(traffic["draws"]), topology_nodes(config["topology"]))
+    shape = (int(traffic["draws"]), topology_nodes(config["topology"], kinds))
     return rng.uniform(-lim, lim, shape).astype(np.float32)
 
 
-def work_per_call(config: dict, traffic: dict) -> int:
+def work_per_call(config: dict, traffic: dict, kinds) -> int:
     """Simulated node·periods of one call."""
     from .reference import periods_of, topology_nodes
-    return (int(traffic["draws"]) * topology_nodes(config["topology"])
+    return (int(traffic["draws"]) * topology_nodes(config["topology"], kinds)
             * periods_of(config))

@@ -19,7 +19,10 @@ and reports the per-layer metrics, ``busy_s``/``window_s`` and a
 
 After the window closes and the device's peak memory is read, the
 reference re-runs a sample of the window's calls, drawn from the seed,
-and the comparison decides ``correct``.  Each compared number is printed
+and the comparison decides ``correct``; in a cell whose mix has a
+guard, the reference replays the guard decisions it cannot tell
+(``compare.Replay``) and the run prints, per compared call, how many it
+held and how many it replayed.  Each compared number is printed
 beside its limit, as the last lines of standard error and as the last
 key (``checks``) of the result line, which is the last line of standard
 output.
@@ -113,7 +116,8 @@ def enable_compile_cache(root: Path) -> str:
 class Readings:
     """What a per-layer metric reader (``metrics/<name>.py``) may read."""
 
-    calls: List[dict]                 # wall_s, launch_s, launches per call
+    calls: List[dict]                 # wall_s, launch_s, launches,
+                                      # reframes per call
     lane: str                         # the engine lane the results report
     shape: dict                       # draws, nodes, classes, periods, ...
     peaks: dict                       # this device's row of peaks.json
@@ -142,15 +146,15 @@ class _Sample:
             self.kept[call] = answer()
 
 
-def _shape(config: dict, traffic: dict) -> dict:
+def _shape(config: dict, traffic: dict, kinds) -> dict:
     lats = {reference.cable_frames(config, config["cable_m"])}
     lats |= {reference.cable_frames(config, ev["cable_m"])
-             for ev in traffic.get("events", [])}
+             for ev in traffic.get("events", []) if "cable_m" in ev}
     periods = reference.periods_of(config)
     tel = traffic["telemetry"]
     return {"draws": int(traffic["draws"]),
-            "nodes": reference.topology_nodes(config["topology"]),
-            "edges": len(reference.build_fabric(config).src),
+            "nodes": reference.topology_nodes(config["topology"], kinds),
+            "edges": len(reference.build_fabric(config, kinds).src),
             "classes": len(lats), "periods": periods,
             "records": periods // int(traffic["record_every"]),
             "measure": bool(tel["beta"] or tel["watermarks"])}
@@ -164,10 +168,10 @@ def run_cell(spec: Spec, name: str, seed: int, seconds: float, trace: bool,
     from . import program as prog_mod
     from . import trace as trace_mod
     cell = spec.cell(name)
-    cfg, tr = cell.config, cell.traffic
-    prog = prog_mod.Program(cfg, tr)
-    shape = _shape(cfg, tr)
-    work = generator.work_per_call(cfg, tr)
+    cfg, tr, kinds = cell.config, cell.traffic, cell.kinds
+    prog = prog_mod.Program(cfg, tr, kinds)
+    shape = _shape(cfg, tr, kinds)
+    work = generator.work_per_call(cfg, tr, kinds)
 
     def new_trace():
         from repro.telemetry import RunTrace
@@ -176,7 +180,8 @@ def run_cell(spec: Spec, name: str, seed: int, seconds: float, trace: bool,
     # Set-up: one warm-up call on draws of its own compiles (or loads
     # from the persistent cache) every program the window calls.
     compiles = _CompileCount()
-    warm = prog.call(generator.draws(cfg, tr, seed, generator.WARMUP_CALL),
+    warm = prog.call(generator.draws(cfg, tr, kinds, seed,
+                                     generator.WARMUP_CALL),
                      trace=new_trace())
     lane = warm.engine
     del warm
@@ -191,7 +196,7 @@ def run_cell(spec: Spec, name: str, seed: int, seconds: float, trace: bool,
         start = time.perf_counter()
         while time.perf_counter() - start < seconds:
             k = len(calls)
-            ppm = generator.draws(cfg, tr, seed, k)
+            ppm = generator.draws(cfg, tr, kinds, seed, k)
             rt = new_trace()
             if trace and k == 0:
                 jax.profiler.start_trace(prof_dir)
@@ -206,7 +211,8 @@ def run_cell(spec: Spec, name: str, seed: int, seconds: float, trace: bool,
             launch = (sum(e.dur for e in rt.by_kind("chunk"))
                       if rt is not None else None)
             calls.append({"wall_s": wall, "launch_s": launch,
-                          "launches": int(res.num_launches)})
+                          "launches": int(res.num_launches),
+                          "reframes": len(res.reframes)})
             if res.engine != lane:
                 raise RuntimeError(f"call {k} ran on lane {res.engine!r}, "
                                    f"the warm-up on {lane!r}")
@@ -238,19 +244,27 @@ def run_cell(spec: Spec, name: str, seed: int, seconds: float, trace: bool,
     del prog
 
     # Correctness, after the window and the memory reading: the
-    # reference re-runs the sampled calls from their inputs.
-    per_call = []
+    # reference re-runs the sampled calls from their inputs, and where
+    # the mix has a guard replays the decisions it cannot tell.
+    per_call, decisions = [], {}
+    check_t0 = time.perf_counter()
     for k, got in sorted(sample.kept.items()):
-        ref = reference.simulate(cfg, tr, generator.draws(cfg, tr, seed, k))
-        per_call.append(compare.gaps(got, ref))
+        replay = compare.Replay(got, cell.limits) if tr.get("guard") else None
+        ref = reference.simulate(cfg, tr, kinds,
+                                 generator.draws(cfg, tr, kinds, seed, k),
+                                 replay=replay)
+        per_call.append(compare.gaps(got, ref, replay))
+        if replay is not None:
+            decisions[k] = dict(replay.counts)
     correct, failed, checks = compare.judge(per_call, cell.limits)
+    check_s = time.perf_counter() - check_t0
 
     dev = dict(device)
     dev["memory_peak_bytes"] = mem
     line = {"correct": bool(correct), "attempted": len(calls),
             "failed": int(failed)}
+    walls = np.asarray([c["wall_s"] for c in calls])
     if not trace:
-        walls = np.asarray([c["wall_s"] for c in calls])
         values = {
             "node_periods_per_s": work * len(calls) / window_wall,
             "draws_per_s": shape["draws"] * len(calls) / window_wall,
@@ -283,15 +297,28 @@ def run_cell(spec: Spec, name: str, seed: int, seconds: float, trace: bool,
                              "idle_gaps": summary.idle_gaps}
     line["metrics"] = metrics
     line["device"] = dev
+    if decisions:
+        line["decisions"] = decisions
     line["checks"] = checks
 
     print(json.dumps({"cell": name, "lane": lane, "calls": len(calls),
                       "window_wall_s": window_wall,
                       "calls_wall_s": end - start,
+                      "call_s_min_median_max": [
+                          float(np.min(walls)), float(np.median(walls)),
+                          float(np.max(walls))],
+                      "launches_per_call": sorted(
+                          {c["launches"] for c in calls}),
+                      "reframes_per_call": sorted(
+                          {c["reframes"] for c in calls}),
+                      "check_s": check_s,
                       "compared_calls": sorted(sample.kept),
                       "setup_compiles": n_setup_compiles,
                       "setup_cache_hits": n_setup_hits,
                       "window_compiles": window_compiles}), file=log)
+    for k, d in decisions.items():
+        print(f"decisions call {k}: " + ", ".join(
+            f"{n} {v}" for n, v in d.items()), file=log)
     for n, c in checks.items():
         print(f"check {n} {c['value']!r} limit {c['limit']!r}", file=log)
     return line

@@ -40,3 +40,27 @@ def counter_mb(r, name: str) -> Optional[float]:
     if traces is None:
         return None
     return sum(t.counters.get(name, 0) for t in traces) / len(traces) / 1e6
+
+
+def outer_span_ms(r, kinds: Iterable[str],
+                  nest: Iterable[str]) -> Optional[float]:
+    """As ``span_ms``, counting a span of ``kinds`` only where no span of
+    ``nest`` encloses it: metrics whose kinds may nest in each other then
+    count no time twice."""
+    traces = window_traces(r)
+    if traces is None:
+        return None
+    kinds, nest = set(kinds), set(nest)
+    total = 0.0
+    for t in traces:
+        spans = {e.id: e for e in t.events
+                 if e.dur is not None and e.id is not None}
+        for e in t.events:
+            if e.dur is None or e.kind not in kinds:
+                continue
+            up = spans.get(e.parent)
+            while up is not None and up.kind not in nest:
+                up = spans.get(up.parent)
+            if up is None:
+                total += e.dur
+    return 1e3 * total / len(traces)

@@ -14,8 +14,30 @@ adding files and entries, never by editing the harness:
     <path>/lanes/<lane>.py           ``count(shape) -> {"flops", "bytes"}``
                                      and ``TRACE_NAMES`` of its kernel
     <path>/peaks.json                peaks by device kind, with source
+    <path>/kinds/topology/<kind>.py  a fabric kind (``topology.kind``)
+    <path>/kinds/controller/<kind>.py  a controller kind
+    <path>/kinds/event/<kind>.py     an event kind of a mix's ``events``
 
 A configuration's file is the one its ``BENCHMARK.json`` entry names.
+
+A kind file has two halves.  Its reference half is NumPy and imports
+nothing of the program; ``chipbench/reference.py`` runs on it.  Its
+program half, ``program(...)``, imports ``repro`` inside the function
+and builds the program's object from the repo's own builders; only
+``chipbench/program.py`` calls it.  The reference, the comparison and
+the generator import nothing of the program:
+
+    topology    ``nodes(t) -> int``, ``pairs(t) -> (E, 2)`` directed
+                (src, dst) pairs, ``TRANSITIVE`` (every node maps to
+                every other by a relabelling that keeps the fabric);
+                ``program(t) -> Topology``
+    controller  ``reference(c, deg, dtype) -> step(net, nu_u, nu) -> nu'``
+                (the per-period update, in ``dtype``);
+                ``program(c) -> ControllerConfig``
+    event       ``period(ev, config) -> int`` and ``apply(ev, config,
+                live)`` (the reference's mutation of ``reference.Live``
+                at the start of that period); ``program(ev, topo,
+                config)`` -> the program's event
 """
 from __future__ import annotations
 
@@ -23,11 +45,23 @@ import dataclasses
 import importlib.util
 import json
 from pathlib import Path
-from typing import List
+from typing import Dict, List
 
 
 class SpecError(ValueError):
     """A name in ``BENCHMARK.json`` that resolves to no file."""
+
+
+@dataclasses.dataclass(frozen=True)
+class Kinds:
+    """The kind files a cell's configuration and mix name."""
+
+    topology: object     # module of kinds/topology/<kind>.py
+    controller: object   # module of kinds/controller/<kind>.py
+    events: Dict[str, object]   # event kind -> module of kinds/event/<kind>.py
+
+    def event(self, ev: dict):
+        return self.events[ev["kind"]]
 
 
 @dataclasses.dataclass(frozen=True)
@@ -39,6 +73,7 @@ class Cell:
     limits: dict
     end_to_end: List[dict]
     per_layer: List[dict]
+    kinds: Kinds
 
 
 class Spec:
@@ -67,7 +102,8 @@ class Spec:
         """Import ``<path>/<kind>/<name>.py`` as a module of its own."""
         f = self.find(kind, name + ".py")
         spec = importlib.util.spec_from_file_location(
-            f"chipbench_{kind}_{name.replace('.', '_')}", f)
+            f"chipbench_{kind.replace('/', '_')}_{name.replace('.', '_')}",
+            f)
         mod = importlib.util.module_from_spec(spec)
         spec.loader.exec_module(mod)
         return mod
@@ -76,6 +112,16 @@ class Spec:
         """The module that reads a per-layer metric: ``metrics/<r>.py``
         for a metric named ``<r>`` or ``<r>.<kind>``."""
         return self.module("metrics", metric.split(".")[0])
+
+    def kinds(self, config: dict, traffic: dict) -> Kinds:
+        """The kind files of a configuration and a mix."""
+        return Kinds(
+            topology=self.module("kinds/topology",
+                                 config["topology"]["kind"]),
+            controller=self.module("kinds/controller",
+                                   config["controller"]["kind"]),
+            events={ev["kind"]: self.module("kinds/event", ev["kind"])
+                    for ev in traffic.get("events", [])})
 
     def lanes(self) -> dict:
         """{lane: module} of every ``lanes/<lane>.py``."""
@@ -101,11 +147,13 @@ class Spec:
         # cell that reports the end-to-end metric it moves.
         e2e = [m for m in self.data["end_to_end"] if mine(m)]
         moved = {m["name"] for m in e2e}
+        traffic = self.load_json("traffic", w["traffic"] + ".json")
         return Cell(
             name=name, chips=int(w["chips"]), config=config,
-            traffic=self.load_json("traffic", w["traffic"] + ".json"),
+            traffic=traffic,
             limits=self.load_json("limits", name + ".json"),
             end_to_end=e2e,
             per_layer=[m for m in self.data["per_layer"]
                        if name in m.get("workloads", [name])
-                       and m["moves"] in moved])
+                       and m["moves"] in moved],
+            kinds=self.kinds(config, traffic))

@@ -16,6 +16,10 @@ from pathlib import Path  # noqa: E402
 
 sys.path.insert(0, str(Path(__file__).resolve().parent))
 
+from chipbench.host import fix_allocator  # noqa: E402
+
+fix_allocator()   # before anything large is allocated
+
 from chipbench.harness import main  # noqa: E402
 
 if __name__ == "__main__":

@@ -110,8 +110,8 @@ def test_splice_not_reestablished_is_not_correct(small_spec, monkeypatch):
     instead of re-establishing the buffers; β after the splice shows it."""
     real = program.Program.__init__
 
-    def keep_lam(self, config, traffic):
-        real(self, config, traffic)
+    def keep_lam(self, config, traffic, kinds):
+        real(self, config, traffic, kinds)
         self.scenario = dataclasses.replace(self.scenario, events=tuple(
             dataclasses.replace(ev, reestablish=False)
             for ev in self.scenario.events))

@@ -13,7 +13,7 @@ def spec():
 
 def _shape(spec, cell):
     c = spec.cell(cell)
-    return harness._shape(c.config, c.traffic)
+    return harness._shape(c.config, c.traffic, c.kinds)
 
 
 def test_shapes_of_the_cells(spec):

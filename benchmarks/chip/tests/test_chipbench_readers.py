@@ -117,3 +117,61 @@ def test_every_new_metric_is_in_the_benchmark_once_per_cell():
     testbed = {m["name"] for m in spec.cell("testbed.splice_mc").per_layer}
     assert set(READERS) <= torus and not set(READERS) & testbed
     assert {n + ".mc" for n in READERS} <= testbed
+
+
+# The guard's two readers, on the RunTrace of one guarded call of the
+# Fig-18 torus (margin 1 frame: 9 rotations, 10 launches) recorded on a
+# TPU v5e (``data/guard_runtrace.jsonl``).  No cell lists them yet: they
+# wait for a guarded deployment.
+GUARD_READERS = ("guard_ms_per_call", "reframe_ms_per_call")
+RECORDED = _chipbench_path.BENCH / "tests/data/guard_runtrace.jsonl"
+
+
+def _recorded(monkeypatch):
+    rt = trace_mod.RunTrace.from_jsonl(str(RECORDED))
+    monkeypatch.setattr(trace_mod, "profiled_traces", lambda: [rt])
+    return rt, [{"wall_s": 16.5, "launch_s": 1.47, "launches": 10}]
+
+
+def test_guard_readers_on_a_recorded_guarded_call(monkeypatch):
+    rt, calls = _recorded(monkeypatch)
+    spec = Spec(_chipbench_path.ROOT)
+    got = {n: spec.reader(n).read(_readings(calls))
+           for n in GUARD_READERS + ("splice_ms_per_call",)}
+    guard = [e.dur for e in rt.by_kind("guard")]
+    reframe = [e.dur for e in rt.by_kind("reframe")]
+    assert len(guard) == 3 and len(reframe) == 9
+    assert got["guard_ms_per_call"] == pytest.approx(1e3 * sum(guard))
+    assert got["reframe_ms_per_call"] == pytest.approx(1e3 * sum(reframe))
+    assert got["guard_ms_per_call"] > got["reframe_ms_per_call"] > 0
+    # The call has no re-establish: its splice time is the two together.
+    assert got["splice_ms_per_call"] == pytest.approx(
+        got["guard_ms_per_call"] + got["reframe_ms_per_call"])
+
+
+def test_guard_readers_count_nested_spans_once(monkeypatch):
+    """A guard span inside a reframe span counts in the reframe's time
+    only; a guard span inside another guard span in the outer one's."""
+    rt = trace_mod.RunTrace(name="cell")
+    ev = trace_mod.TraceEvent
+    rt.events += [ev(kind="scenario", t=0.0, dur=9.0, id=0),
+                  ev(kind="reframe", t=1.0, dur=1.0, id=1, parent=0),
+                  ev(kind="guard", t=1.2, dur=0.4, id=2, parent=1),
+                  ev(kind="guard", t=3.0, dur=2.0, id=3, parent=0),
+                  ev(kind="guard", t=3.5, dur=0.5, id=4, parent=3)]
+    monkeypatch.setattr(trace_mod, "profiled_traces", lambda: [rt])
+    calls = [{"wall_s": 9.0, "launch_s": 0.0, "launches": 1}]
+    spec = Spec(_chipbench_path.ROOT)
+    assert spec.reader("guard_ms_per_call").read(_readings(calls)) == \
+        pytest.approx(2000.0)
+    assert spec.reader("reframe_ms_per_call").read(_readings(calls)) == \
+        pytest.approx(1000.0)
+
+
+@pytest.mark.parametrize("name", GUARD_READERS)
+def test_guard_readers_none_untraced_zero_unguarded(name):
+    reader = Spec(_chipbench_path.ROOT).reader(name)
+    plain = [{"wall_s": 1.0, "launch_s": None, "launches": 1}] * 2
+    assert reader.read(_readings(plain)) is None
+    calls = [_call(), _call()]
+    assert reader.read(_readings(calls)) == 0

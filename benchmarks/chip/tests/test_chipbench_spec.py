@@ -40,6 +40,8 @@ def test_cell_files_resolve(cell):
 def test_cell_in_a_temporary_directory(tmp_path):
     """A new configuration, mix, metric and limits need files and
     BENCHMARK.json entries only."""
+    shutil.copytree(ROOT / "benchmarks/chip", tmp_path / "benchmarks/chip",
+                    ignore=shutil.ignore_patterns("__pycache__"))
     bench = tmp_path / "extra"
     for kind in ("configs", "traffic", "limits", "metrics"):
         (bench / kind).mkdir(parents=True)
@@ -57,7 +59,7 @@ def test_cell_in_a_temporary_directory(tmp_path):
     (bench / "metrics/calls_seen.py").write_text(
         "def read(r):\n    return float(len(r.calls))\n")
     data = _bench()
-    data["paths"] = ["extra"]
+    data["paths"] = ["benchmarks/chip", "extra"]
     data["configs"] = [{"name": "ring_like", "source": "test",
                         "file": "extra/configs/ring_like.json",
                         "reduced": [], "why": "test"}]
