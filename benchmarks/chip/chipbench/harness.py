@@ -20,12 +20,13 @@ and reports the per-layer metrics, ``busy_s``/``window_s`` and a
 After the window closes and the device's peak memory is read, the
 reference re-runs a sample of the window's calls, drawn from the seed,
 and the comparison decides ``correct``; in a cell whose mix has a
-guard, the reference replays the guard decisions it cannot tell
-(``compare.Replay``) and the run prints, per compared call, how many it
-held and how many it replayed.  Each compared number is printed
-beside its limit, as the last lines of standard error and as the last
-key (``checks``) of the result line, which is the last line of standard
-output.
+guard (``compare.Replay``), or whose controller decides
+(``compare.PulseReplay``: FINC/FDEC pulses, integer readout), the
+reference replays the decisions it cannot tell and the run prints, per
+compared call, how many it held and how many it replayed.  Each compared
+number is printed beside its limit, as the last lines of standard error
+and as the last key (``checks``) of the result line, which is the last
+line of standard output.
 
 Exits non-zero, printing no result, when JAX finds no TPU or fewer chips
 than the cell asks for, and when the program or a cell file is missing.
@@ -245,17 +246,17 @@ def run_cell(spec: Spec, name: str, seed: int, seconds: float, trace: bool,
 
     # Correctness, after the window and the memory reading: the
     # reference re-runs the sampled calls from their inputs, and where
-    # the mix has a guard replays the decisions it cannot tell.
+    # the mix has a guard or the controller decides, replays the
+    # decisions it cannot tell.
     per_call, decisions = [], {}
     check_t0 = time.perf_counter()
     for k, got in sorted(sample.kept.items()):
-        replay = compare.Replay(got, cell.limits) if tr.get("guard") else None
-        ref = reference.simulate(cfg, tr, kinds,
-                                 generator.draws(cfg, tr, kinds, seed, k),
-                                 replay=replay)
-        per_call.append(compare.gaps(got, ref, replay))
-        if replay is not None:
-            decisions[k] = dict(replay.counts)
+        gaps, counts = compare.check_call(
+            cfg, tr, kinds, generator.draws(cfg, tr, kinds, seed, k), got,
+            cell.limits)
+        per_call.append(gaps)
+        if counts is not None:
+            decisions[k] = counts
     correct, failed, checks = compare.judge(per_call, cell.limits)
     check_s = time.perf_counter() - check_t0
 

@@ -4,7 +4,9 @@ The window calls ``repro.scenarios.run_scenario`` with
 ``EngineOptions(engine="auto")``, so dispatch stays free and a dispatch
 change shows in the numbers.  Topology, links, controller and events are
 built once, from the configuration and traffic files, by the program
-halves of their kind files (``spec.Kinds``); a mix's ``guard`` entry
+halves of their kind files (``spec.Kinds``); the controller kind also
+names the ``SimConfig`` fields it needs (integer readout is
+``quantize_beta``), and this module names none.  A mix's ``guard`` entry
 (``{"margin_frames": m}``) turns on the reframing guard,
 ``ReframePolicy(D, margin=m)``, at the configuration's
 ``elastic_buffer_depth`` D.  Each call gets its own oscillator
@@ -43,11 +45,12 @@ class Program:
                     velocity=config["signal_velocity_m_per_s"])
         self.links = make_links(self.topo, cable_m=config["cable_m"],
                                 beta0=config["beta0_frames"], **phys)
-        self.ctrl = kinds.controller.program(config["controller"])
+        self.ctrl, sim = kinds.controller.program(config["controller"])
         periods = int(round(config["duration_s"] / config["dt_s"]))
         self.cfg = SimConfig(omega_nom=config["omega_nom_hz"],
                              dt=config["dt_s"], steps=periods,
-                             record_every=int(traffic["record_every"]))
+                             record_every=int(traffic["record_every"]),
+                             **sim)
         self.scenario = Scenario(events=tuple(
             kinds.event(ev).program(ev, self.topo, config)
             for ev in traffic.get("events", [])))

@@ -9,16 +9,22 @@ are the oscillator draws the harness generated.  The model
 ν = ω/ω_nom − 1) advances every draw one control period at a time:
 
     β_e   = ψ_src − ν_src·ω·l_e + λeff_e − ψ_dst            per directed edge
-    net_i = Σ_{e→i} β_e
+    net_i = Σ_{e→i} read(β_e)
     ν_i'  = the controller kind's update of net_i           (proportional:
             ν_u,i + c_i + ν_u,i·c_i, c_i = kp·(net_i − β_off·deg_i))
     ψ_i'  = ψ_i + ν_i'·ω·dt
 
-and every ``record_every`` periods records ν (ppm) and net_i of the
-post-update state.  An event kind mutates the live state (``Live``) at
-the start of its period: a latency step swaps the cable of both directed
-edges of a link and, with ``reestablish``, recomputes their λeff from the
-live state so that their buffers restart at the β0 set-point.
+and every ``record_every`` periods records ν (ppm) and Σ_{e→i} β_e of the
+post-update state.  ``read`` is the controller kind's readout: β_e as it
+is, or, for a kind that reads whole frames, rounded to the nearest
+integer (``np.rint``, half to even as ``jnp.round``) before the sum; the
+record stays unrounded.  A controller may keep state (FINC/FDEC's
+c_est); one that decides is compared record by record from the program's
+own state (``_replay_pulses``, ``compare.PulseReplay``).  An event kind
+mutates the live state (``Live``) at the start of its period: a latency
+step swaps the cable of both directed edges of a link and, with
+``reestablish``, recomputes their λeff from the live state so that their
+buffers restart at the β0 set-point.
 
 A mix with a ``guard`` entry (``{"margin_frames": m}``) closes the
 reframing loop the kernel lanes run (the in-kernel guard band, the
@@ -51,7 +57,8 @@ neighbour sum taken in the program's algebraic form,
 as TPU ``Precision.HIGH`` computes a float32 matmul — three bfloat16
 passes (a_hi·b_hi + a_hi·b_lo + a_lo·b_hi, summed in float32).  It is
 the step below the ``Precision.HIGHEST`` contraction the configuration
-states.
+states.  With integer readout each edge is read alone, its neighbour
+term ψ_src − ν_src·lat_e taken through the same three passes.
 """
 from __future__ import annotations
 
@@ -250,6 +257,24 @@ def _events(config: dict, traffic: dict, kinds) -> dict:
     return out
 
 
+def controller(config: dict, kinds, fabric: Fabric, dtype):
+    """The configuration's controller kind, in ``dtype``, on ``fabric``."""
+    deg = np.bincount(fabric.dst, minlength=fabric.nodes).astype(dtype)
+    return kinds.controller.reference(config["controller"], deg, dtype)
+
+
+def decides(ctl) -> bool:
+    """Whether a controller's records follow from pulse decisions the
+    comparison replays (``compare.PulseReplay``): a kind with a ``want``
+    (FINC/FDEC).  The replay's bound is written for pulses read from
+    whole frames; other pairings are refused until a cell needs them."""
+    pulses = hasattr(ctl, "want")
+    if pulses != (ctl.readout == "integer"):
+        raise ValueError("the pulse replay holds pulses with integer "
+                         "readout only")
+    return pulses
+
+
 def simulate(config: dict, traffic: dict, kinds, ppm: np.ndarray,
              precision: str = "float32", replay=None) -> dict:
     """Run one call's draws; returns the records the program reports.
@@ -260,15 +285,23 @@ def simulate(config: dict, traffic: dict, kinds, ppm: np.ndarray,
       replay: where the mix has a guard, an object with ``bind(fabric,
         pinv_norm)``, ``trips(record, own, slack)`` and
         ``potentials(record, draw, x)`` (``compare.Replay``) that decides
-        what the reference applies; None keeps the reference's own.
+        what the reference applies; where the controller decides
+        (``decides``), a ``compare.PulseReplay`` holding the program's
+        records, from which the reference runs record by record
+        (``_replay_pulses``); None keeps the reference's own.
     Returns a dict of float64 arrays of float32 values: ``freq_ppm`` and
     ``beta`` (B, T, N), and the watermarks ``beta_abs_max``,
     ``nu_min_ppm``, ``nu_max_ppm`` (B, N); with a guard also
     ``reframes``, [(record, (B, E) int64 shift)], and ``edges``, the
-    (E, 2) (src, dst) pairs the shifts index.
+    (E, 2) (src, dst) pairs the shifts index.  With a ``PulseReplay``
+    the arrays hold float64 values.
     """
     if precision not in PRECISIONS:
         raise ValueError(f"precision must be one of {PRECISIONS}")
+    if getattr(replay, "pulses", False):
+        if precision != "float32":
+            raise ValueError("the pulse replay is the reference itself")
+        return _replay_pulses(config, traffic, kinds, ppm, replay)
     fab = build_fabric(config, kinds)
     dt = np.float32
     dt_frames = dt(config["omega_nom_hz"] * config["dt_s"])
@@ -281,7 +314,7 @@ def simulate(config: dict, traffic: dict, kinds, ppm: np.ndarray,
 
     nsum = _NodeSum(fab.dst, fab.nodes)
     deg = nsum.deg.astype(dt)
-    control = kinds.controller.reference(config["controller"], deg, dt)
+    ctl = controller(config, kinds, fab, dt)
     guard: Optional[Guard] = None
     if traffic.get("guard"):
         guard = Guard(config["elastic_buffer_depth"], traffic["guard"],
@@ -297,11 +330,25 @@ def simulate(config: dict, traffic: dict, kinds, ppm: np.ndarray,
     live.nu = nu_u.copy()
 
     if precision != "high":
+        def edges(psi, nu):
+            """β_e of every edge."""
+            return (psi[:, fab.src] - nu[:, fab.src] * live.lat
+                    + live.lam.astype(dt) - psi[:, fab.dst])
+
         def net(psi, nu):
             """Σ_{e→i} β_e, edge by edge."""
-            return nsum(psi[:, fab.src] - nu[:, fab.src] * live.lat
-                        + live.lam.astype(dt) - psi[:, fab.dst])
+            return nsum(edges(psi, nu))
     else:
+        def edges(psi, nu):
+            """β_e with the neighbour term as the program's contraction
+            gives it at Precision.HIGH, one edge at a time."""
+            lat = live.lat
+            out = np.zeros((b, len(fab.src)), dt)
+            for c in np.unique(lat):
+                term = _contract_high((psi - nu * dt(c))[:, fab.src])
+                out[:, lat == c] = term[:, lat == c]
+            return out - psi[:, fab.dst] + live.lam.astype(dt)
+
         def net(psi, nu):
             """The program's form: per-class contraction of ψ − ν·lat_c
             at Precision.HIGH, minus ψ·deg, plus the λeff fold."""
@@ -315,13 +362,21 @@ def simulate(config: dict, traffic: dict, kinds, ppm: np.ndarray,
                 acc = acc + nsum(term)
             return acc - psi * deg + lam_t
 
+    if ctl.readout == "integer":
+        def read(psi, nu):
+            """The controller's input: each edge read as a whole number."""
+            return nsum(np.rint(edges(psi, nu)))
+    else:
+        read = net
+
     freq, beta, reframes = [], [], []
+    state = ctl.init(live.nu.shape)
     lam_t = nsum(live.lam).astype(dt)
     for p in range(periods):
         for mod, ev in events.get(p, ()):
             mod.apply(ev, config, live)
             lam_t = nsum(live.lam).astype(dt)
-        live.nu = control(net(live.psi, live.nu), nu_u, live.nu)
+        live.nu, state = ctl.step(read(live.psi, live.nu), nu_u, state)
         live.psi = live.psi + live.nu * dt_frames
         if (p + 1) % rec_every:
             continue
@@ -351,12 +406,107 @@ def simulate(config: dict, traffic: dict, kinds, ppm: np.ndarray,
         live.lam = live.lam + shift
         lam_t = nsum(live.lam).astype(dt)
         reframes.append((t + 1, shift))
-    freq = np.stack(freq, axis=1)
-    beta = np.stack(beta, axis=1)
-    out = {"freq_ppm": freq, "beta": beta,
-           "beta_abs_max": np.abs(beta).max(axis=1),
-           "nu_min_ppm": freq.min(axis=1), "nu_max_ppm": freq.max(axis=1)}
+    out = _records(freq, beta)
     if guard is not None:
         out["reframes"] = reframes
         out["edges"] = np.stack([fab.src, fab.dst], axis=1)
     return out
+
+
+def _records(freq: list, beta: list) -> dict:
+    freq = np.stack(freq, axis=1)
+    beta = np.stack(beta, axis=1)
+    return {"freq_ppm": freq, "beta": beta,
+            "beta_abs_max": np.abs(beta).max(axis=1),
+            "nu_min_ppm": freq.min(axis=1), "nu_max_ppm": freq.max(axis=1)}
+
+
+def _replay_pulses(config: dict, traffic: dict, kinds, ppm: np.ndarray,
+                   replay) -> dict:
+    """The reference record by record from the program's own state, for a
+    controller that decides (module docstring; ``compare.PulseReplay``
+    holds the decisions and says which it can tell).
+
+    Each record interval starts from the program's state at the record
+    before it (the first from ψ = 0, ν = ν_u and the controller's initial
+    state): ν from its ν record, c_est from that ν (``PulseReplay.state``,
+    so its pulse count n), and ψ, up to a constant, from its
+    net-occupancy record through L⁺; the constant is the reference's own
+    mean ψ, carried from interval to interval.
+    The interval then runs in float64 with every decision the rule makes.
+    At its end the reference records its own ν and β, and takes the last
+    period's decisions again from the program's own state at that period,
+    which the records at the end give: ψ(R) from the net record, ψ(R − 1)
+    = ψ(R) − ν(R)·dt, and ν(R − 1) from ν(R) less the reference's own
+    last step.  A node's count after a period inside the budget follows
+    from that period's readout alone (``compare.PulseReplay``), so where
+    every decision of that period lies farther from turning than the
+    bound, the count the rule gives must be the program's.
+    """
+    if traffic.get("events"):
+        raise ValueError(
+            "the pulse replay takes no events yet: a splice re-established "
+            "from the live state would carry the reconstruction's error "
+            "into λeff, which the bound does not hold")
+    f64 = np.float64
+    fab = build_fabric(config, kinds)
+    periods = periods_of(config)
+    rec_every = int(traffic["record_every"])
+    if periods % rec_every:
+        raise ValueError("periods must be a multiple of record_every")
+    records = periods // rec_every
+    nsum = _NodeSum(fab.dst, fab.nodes)
+    lap = Laplacian(fab, nsum)
+    ctl = controller(config, kinds, fab, f64)
+    dt_frames = f64(np.float32(config["omega_nom_hz"] * config["dt_s"]))
+    b, n = ppm.shape[0], fab.nodes
+    nu_u = (np.asarray(ppm, f64) * 1e-6).astype(np.float32).astype(f64)
+    lat = fab.lat_frames.astype(np.float32).astype(f64)
+    lam = np.full((b, len(fab.src)), config.get("beta0_frames", 0.0), f64)
+    replay.bind(fab, lap, nsum, (b, records, n), ctl)
+
+    def edges(psi, nu):
+        return psi[:, fab.src] - nu[:, fab.src] * lat + lam - psi[:, fab.dst]
+
+    def read(beta_e):
+        return nsum(np.rint(beta_e))
+
+    def potentials(net, nu):
+        """ψ, mean zero, of a net-occupancy record and the ν beside it:
+        L ψ = Σ_{e→i} (λ_e − ν_src·lat_e) − net."""
+        return lap.pinv(nsum(lam - nu[:, fab.src] * lat) - net)
+
+    freq, beta = [], []
+    psi, nu = np.zeros((b, n)), nu_u.copy()
+    state = ctl.init((b, n))
+    for t in range(records):
+        if t:
+            nu, net = replay.record(t - 1)
+            state = replay.state(nu, nu_u)
+            psi = potentials(net, nu) + psi.mean(axis=1, keepdims=True)
+        inside = np.ones((b, n), bool)
+        nu_max = np.abs(nu)
+        for _ in range(rec_every):
+            net = read(edges(psi, nu))
+            inside &= replay.margin(net, state) > 0
+            last_state, last_nu = state, nu
+            nu, state = ctl.step(net, nu_u, state)
+            psi = psi + nu * dt_frames
+            nu_max = np.maximum(nu_max, np.abs(nu))
+        beta.append(nsum(edges(psi, nu)))
+        # The program's state at the interval's last period.
+        nu_end, net_end = replay.record(t)
+        nu_max = np.maximum(nu_max, np.abs(nu_end))
+        psi_last = potentials(net_end, nu_end) - nu_end * dt_frames
+        beta_last = edges(psi_last, nu_end - (nu - last_nu))
+        tol = replay.edge_tolerance(
+            1.01 * np.abs(psi).max(axis=1) + 1.0, beta_last, nu_max, nu_u,
+            lat, np.abs(lam).max(axis=1), dt_frames)
+        sure = 0.5 - np.abs(beta_last - np.rint(beta_last)) > tol
+        net_last = read(beta_last)
+        held = (inside & (nsum((~sure).astype(f64)) == 0)
+                & (replay.slack(net_last, nu_u, last_state, rec_every,
+                                nu_max) > 0))
+        own, _ = ctl.step(net_last, nu_u, last_state)
+        freq.append(replay.decide(t, own, nu_end, held, nu_u) * 1e6)
+    return _records(freq, beta)

@@ -31,9 +31,17 @@ the generator import nothing of the program:
                 (src, dst) pairs, ``TRANSITIVE`` (every node maps to
                 every other by a relabelling that keeps the fabric);
                 ``program(t) -> Topology``
-    controller  ``reference(c, deg, dtype) -> step(net, nu_u, nu) -> nu'``
-                (the per-period update, in ``dtype``);
-                ``program(c) -> ControllerConfig``
+    controller  ``reference(c, deg, dtype)`` -> an object with
+                ``readout`` ("continuous", or "integer": each edge's
+                occupancy rounded to a whole frame before the node sum),
+                ``init(shape) -> state`` and ``step(net, nu_u, state) ->
+                (nu', state')``, the per-period update in ``dtype``; a
+                FINC/FDEC kind, which decides in pulses and reads whole
+                frames, also states its rule: ``kp``, ``fs``, ``budget``
+                (pulses a period) and ``want(net, state)``, with state
+                ``{"c_est": ...}``, which ``compare.PulseReplay`` holds
+                (``kinds/controller/discrete.py``); ``program(c) ->
+                (ControllerConfig, {SimConfig field: value})``
     event       ``period(ev, config) -> int`` and ``apply(ev, config,
                 live)`` (the reference's mutation of ``reference.Live``
                 at the start of that period); ``program(ev, topo,

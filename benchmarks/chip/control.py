@@ -27,16 +27,15 @@ from chipbench.spec import Spec  # noqa: E402
 def control_gaps(cell, seed: int) -> list:
     """The compared numbers of the control, call by call.  Where the mix
     has a guard, the control makes its own trips and rotations from its
-    own state, and the reference replays them as it would the
-    program's."""
+    own state, and where the controller decides, its own pulses from its
+    own readout; the reference replays them as it would the program's."""
     cfg, tr, kinds = cell.config, cell.traffic, cell.kinds
     out = []
     for k in range(int(tr["check_calls"])):
         ppm = generator.draws(cfg, tr, kinds, seed, k)
         got = reference.simulate(cfg, tr, kinds, ppm, precision="high")
-        replay = compare.Replay(got, cell.limits) if tr.get("guard") else None
-        ref = reference.simulate(cfg, tr, kinds, ppm, replay=replay)
-        out.append(compare.gaps(got, ref, replay))
+        out.append(compare.check_call(cfg, tr, kinds, ppm, got,
+                                      cell.limits)[0])
     return out
 
 

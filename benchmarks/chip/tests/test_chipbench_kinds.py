@@ -1,6 +1,7 @@
 """Fabric, controller and event kinds are files: a new kind needs files
 and ``BENCHMARK.json`` entries only, and the kinds in use give the
-reference the outputs it gave before they moved into files."""
+reference the outputs it gave before they moved into files (the
+FINC/FDEC kind's from when it was added)."""
 import hashlib
 import json
 import shutil
@@ -93,9 +94,107 @@ def test_topology_kind_as_files(tmp_path):
             assert (tmp_path / rel).read_bytes() == f.read_bytes()
 
 
+FINC_HW = '''"""FINC/FDEC in the boards' own units: a gain in pulses per frame of
+summed occupancy error and a pulse in ppm (``{"kind": "finc_hw",
+"steps_per_frame": g, "step_ppm": s, "pulses_per_update": P}``), so
+fs = s·1e-6 and kp = g·fs; each edge read as whole frames."""
+import numpy as np
+
+
+class FincHw:
+    readout = "integer"
+
+    def __init__(self, c, deg, dtype):
+        self.fs = dtype(c["step_ppm"] * 1e-6)
+        self.kp = dtype(c["steps_per_frame"] * c["step_ppm"] * 1e-6)
+        self.budget = int(c["pulses_per_update"])
+        self.dtype = dtype
+
+    def init(self, shape):
+        return {"c_est": np.zeros(shape, self.dtype)}
+
+    def want(self, net, state):
+        return (self.kp * net - state["c_est"]) / self.fs
+
+    def step(self, net, nu_u, state):
+        pulses = np.clip(np.rint(self.want(net, state)), -self.budget,
+                         self.budget)
+        c = state["c_est"] + pulses * self.fs
+        return nu_u + c + nu_u * c, {"c_est": c}
+
+
+def reference(c, deg, dtype):
+    return FincHw(c, deg, dtype)
+
+
+def program(c):
+    from repro.core import ControllerConfig, hardware_gain
+    fs = c["step_ppm"] * 1e-6
+    return (ControllerConfig(kind="discrete", fs=fs,
+                             kp=hardware_gain(c["steps_per_frame"], fs),
+                             pulses_per_update=int(c["pulses_per_update"])),
+            {"quantize_beta": True})
+'''
+
+
+def test_controller_kind_as_files(tmp_path, monkeypatch):
+    """A FINC/FDEC kind in the boards' units, which no harness file knows,
+    from a path of its own: it keeps state and reads whole frames, and the
+    harness runs it (on segment-sum, which runs FINC/FDEC today) and holds
+    its pulses through the replay with no harness edit."""
+    from test_chipbench_discrete import DATA, on_segment_sum
+    shutil.copytree(ROOT / "benchmarks/chip", tmp_path / "benchmarks/chip",
+                    ignore=shutil.ignore_patterns("__pycache__"))
+    extra = tmp_path / "extra"
+    for kind in ("kinds/controller", "configs", "traffic", "limits"):
+        (extra / kind).mkdir(parents=True)
+    (extra / "kinds/controller/finc_hw.py").write_text(FINC_HW)
+    cfg = json.loads((DATA / "testbed_fc8_finc.json").read_text())
+    cfg.update(name="testbed_hw", duration_s=0.1, controller={
+        "kind": "finc_hw", "steps_per_frame": 0.2, "step_ppm": 0.1,
+        "pulses_per_update": 50})
+    (extra / "configs/testbed_hw.json").write_text(json.dumps(cfg))
+    mix = json.loads((DATA / "finc_mc.json").read_text())
+    mix["draws"] = 16
+    (extra / "traffic/hw_mc.json").write_text(json.dumps(mix))
+    shutil.copy(DATA / "finc_limits_2000.json",
+                extra / "limits/testbed_hw.mc.json")
+    data = json.loads((ROOT / "BENCHMARK.json").read_text())
+    data["paths"] = ["benchmarks/chip", "extra"]
+    data["configs"] = [{"name": "testbed_hw", "source": "test",
+                        "file": "extra/configs/testbed_hw.json",
+                        "reduced": [], "why": "test"}]
+    data["workloads"] = [{"name": "testbed_hw.mc", "config": "testbed_hw",
+                          "traffic": "hw_mc", "chips": 1, "why": "test"}]
+    for m in data["end_to_end"]:
+        if m["name"] == "draws_per_s":
+            m["workloads"] = ["testbed_hw.mc"]
+    (tmp_path / "BENCHMARK.json").write_text(json.dumps(data))
+
+    on_segment_sum(monkeypatch)
+    spec = Spec(tmp_path)
+    cell = spec.cell("testbed_hw.mc")
+    prog = program.Program(cell.config, cell.traffic, cell.kinds)
+    assert prog.ctrl.kind == "discrete" and prog.cfg.quantize_beta
+    line = harness.run_cell(spec, "testbed_hw.mc", 2**31 + 9, 0.2, False,
+                            time.perf_counter(), CPU)
+    assert line["correct"] is True, line["checks"]
+    assert line["checks"]["pulse_records"]["value"] == 0
+    assert all(d["records_held"] > d["records_replayed"]
+               for d in line["decisions"].values())
+    for f in (ROOT / "benchmarks/chip").rglob("*.py"):
+        if "__pycache__" not in f.parts:
+            rel = f.relative_to(ROOT)
+            assert (tmp_path / rel).read_bytes() == f.read_bytes()
+
+
 # sha256 of the reference's outputs at a small seeded size, computed
-# before the kinds moved into files.
+# before the kinds moved into files (the discrete kind's when it came).
 PINNED = {
+    ("discrete", "float32"):
+        "5700ff1cecf3b95f5d4d8e524f49c7022094e7944af84562306d2e3f424a9802",
+    ("discrete", "high"):
+        "7be7ee70bea9efbbbe6a68d30f7ed2c1dd41e34d6414870a793b7697d5e297dd",
     ("fully_connected", "float32"):
         "54b73826b3138fdbd36b76e76ade7fef4061ab64ee5802e56abd87d4a8d3f178",
     ("fully_connected", "high"):
@@ -109,7 +208,13 @@ PINNED = {
 
 def _small(kind):
     bench = ROOT / "benchmarks/chip"
-    if kind == "fully_connected":
+    if kind == "discrete":
+        from test_chipbench_discrete import DATA
+        cfg = json.loads((DATA / "testbed_fc8_finc.json").read_text())
+        cfg["duration_s"] = 0.1
+        mix = json.loads((DATA / "finc_mc.json").read_text())
+        mix.update(draws=4, record_every=200)
+    elif kind == "fully_connected":
         cfg = json.loads((bench / "configs/testbed_fc8.json").read_text())
         mix = json.loads((bench / "traffic/splice_mc.json").read_text())
         mix["draws"] = 4
