@@ -156,16 +156,74 @@ def _put(tr, x: np.ndarray):
 
 
 def _fetch(tr, x) -> np.ndarray:
-    """Read a device array back, counted (padded) in ``d2h_bytes``."""
+    """Read a device array back, counted in ``d2h_bytes`` and
+    ``d2h_reads``."""
     tr.count("d2h_bytes", x.nbytes)
+    tr.count("d2h_reads", 1)
     return np.asarray(x)
 
 
 def _fetch_watermarks(tr, wm_dev, num_records: int, b: Optional[int],
                       n: int) -> Watermarks:
-    """:func:`_host_watermarks`, its reads counted in ``d2h_bytes``."""
+    """:func:`_host_watermarks`, its reads counted in ``d2h_bytes`` and
+    ``d2h_reads``."""
     tr.count("d2h_bytes", sum(x.nbytes for x in wm_dev))
+    tr.count("d2h_reads", len(wm_dev))
     return _host_watermarks(wm_dev, num_records, b, n)
+
+
+@functools.partial(jax.jit, static_argnames=("b", "n"))
+def _unpad(xs, b: int, n: int):
+    """Slice each (..., B_pad, N_pad) array of ``xs`` to (..., b, n) and
+    pack the slices, bit-cast to uint32, into one flat buffer.
+
+    The kernel lanes pad N to ``TILE`` lanes and B to ``SUBLANE`` rows:
+    an 8-node fabric's record is 1/16 data.  Sliced here, only the data
+    crosses to the host, in one transfer.
+    """
+    return jnp.concatenate([
+        jax.lax.bitcast_convert_type(x[..., :b, :n], jnp.uint32).ravel()
+        for x in xs])
+
+
+def _fetch_unpadded(tr, xs, b: int, n: int) -> List[np.ndarray]:
+    """Host copies of ``xs`` (32-bit device arrays, padded on their last
+    two axes) cut to (..., b, n), read back in one transfer."""
+    buf = np.asarray(_unpad(tuple(xs), b=b, n=n))
+    tr.count("d2h_bytes", buf.nbytes)
+    tr.count("d2h_reads", 1)
+    out, at = [], 0
+    for x in xs:
+        shape = x.shape[:-2] + (min(b, x.shape[-2]), min(n, x.shape[-1]))
+        size = int(np.prod(shape))
+        out.append(buf[at:at + size].view(x.dtype).reshape(shape))
+        at += size
+    return out
+
+
+def _read_chunk(tr, out, b: int, n: int, chunk: int, stop: int):
+    """A batched kernel lane's chunk on the host, read in one transfer.
+
+    Returns ``(trips, tstar, valid, freq, beta, wm)``: the (b,) in-kernel
+    guard trip records and their earliest (None and ``chunk`` with the
+    guard off), the records that count (to the earliest trip, else to
+    ``stop``), and those records as the (b, valid, n) ν stream in ppm,
+    the (b, valid, n) β stream and the chunk's :class:`Watermarks` (each
+    None unless the chunk recorded it)."""
+    guard = out.guard_state is not None
+    xs = (((out.guard_state,) if guard else ()) + (out.freq,)
+          + ((out.beta,) if out.beta is not None else ())
+          + tuple(out.watermarks or ()))
+    host = iter(_fetch_unpadded(tr, xs, b, n))
+    trips = next(host)[:, 0] if guard else None
+    tstar = int(trips.min()) if guard else chunk
+    valid = min(tstar, stop) + 1
+    freq = next(host)[:valid].transpose(1, 0, 2) * 1e6
+    beta = (next(host)[:valid].transpose(1, 0, 2)
+            if out.beta is not None else None)
+    wm = (_host_watermarks(tuple(host), valid, b, n)
+          if out.watermarks is not None else None)
+    return trips, tstar, valid, freq, beta, wm
 
 
 def _guard_band_cols(b_pad: int, b: int, target: float, guard_rows,
@@ -985,8 +1043,7 @@ def _run_scenario(topo: Topology, links: LinkParams, ctrl: ControllerConfig,
             return (np.zeros_like(ppm_u, np.float64),
                     ppm_u.astype(np.float64) * 1e-6)
         if dense or sparse:
-            psi_now = _fetch(tr, psi_pad)[:b, :n]
-            nu_now = _fetch(tr, nu_pad)[:b, :n]
+            psi_now, nu_now = _fetch_unpadded(tr, (psi_pad, nu_pad), b, n)
             return (psi_now[0], nu_now[0]) if single else (psi_now, nu_now)
         return state.psi, state.nu
 
@@ -1103,20 +1160,11 @@ def _run_scenario(topo: Topology, links: LinkParams, ctrl: ControllerConfig,
                         jax.block_until_ready(out)
                     with tr.span("chunk.fetch"):
                         psi_pad, nu_pad = out.psi, out.nu
-                        trips = (_fetch(tr, out.guard_state)[:b, 0]
-                                 if guard_on else None)
-                        tstar = int(trips.min()) if guard_on else chunk
-                        valid = min(tstar, stop) + 1
+                        (trips, tstar, valid, freq_c, beta_c,
+                         wm_c) = _read_chunk(tr, out, b, n, chunk, stop)
+                        freq_chunks.append(freq_c)
                         if rb_dense:
-                            beta_chunks.append(
-                                _fetch(tr, out.beta)[:valid, :b, :n]
-                                .transpose(1, 0, 2))
-                        freq_chunks.append(
-                            _fetch(tr, out.freq)[:valid, :b, :n]
-                            .transpose(1, 0, 2) * 1e6)
-                        if rw:
-                            wm_c = _fetch_watermarks(tr, out.watermarks,
-                                                     valid, b, n)
+                            beta_chunks.append(beta_c)
                 if rw:
                     wm_acc = wm_c if wm_acc is None else wm_acc.merge(wm_c)
                 launches += 1
@@ -1168,8 +1216,9 @@ def _run_scenario(topo: Topology, links: LinkParams, ctrl: ControllerConfig,
                     variant, tr)
                 if psi_pad is None:
                     psi_pad, nu_pad = jnp.zeros_like(nu_u_j), nu_u_j
-                kp_np = _fetch(tr, kp_j)
-                boff_np = _fetch(tr, boff_j)
+                if chosen == "per-step":
+                    kp_np = _fetch(tr, kp_j)
+                    boff_np = _fetch(tr, boff_j)
             eng_label, tile_j = chosen, tj
             c_stack = int(a.shape[0])
             if chosen == "fused":
@@ -1274,20 +1323,11 @@ def _run_scenario(topo: Topology, links: LinkParams, ctrl: ControllerConfig,
                             jax.block_until_ready(out)
                         with tr.span("chunk.fetch"):
                             psi_pad, nu_pad = out.psi, out.nu
-                            trips = (_fetch(tr, out.guard_state)[:b, 0]
-                                     if guard_on else None)
-                            tstar = int(trips.min()) if guard_on else chunk
-                            valid = min(tstar, stop) + 1
+                            (trips, tstar, valid, freq_c, beta_c,
+                             wm_c) = _read_chunk(tr, out, b, n, chunk, stop)
+                            freq_chunks.append(freq_c)
                             if rb_dense:
-                                beta_chunks.append(
-                                    _fetch(tr, out.beta)[:valid, :b, :n]
-                                    .transpose(1, 0, 2))
-                            if rw:
-                                wm_c = _fetch_watermarks(
-                                    tr, out.watermarks, valid, b, n)
-                            freq_chunks.append(
-                                _fetch(tr, out.freq)[:valid, :b, :n]
-                                .transpose(1, 0, 2) * 1e6)
+                                beta_chunks.append(beta_c)
                 if rw:
                     wm_acc = wm_c if wm_acc is None else wm_acc.merge(wm_c)
                 launches += 1
@@ -1332,8 +1372,9 @@ def _run_scenario(topo: Topology, links: LinkParams, ctrl: ControllerConfig,
                                     topo, links_seg, seg, comp, ctrl,
                                     np.atleast_2d(ppm_seg), cfg, engine,
                                     stacks, si, variant, tr)
-                            kp_np = _fetch(tr, kp_j)
-                            boff_np = _fetch(tr, boff_j)
+                            if chosen == "per-step":
+                                kp_np = _fetch(tr, kp_j)
+                                boff_np = _fetch(tr, boff_j)
             continue
 
         tr.event("engine_dispatch", segment=si, engine="segment-sum",
@@ -1407,8 +1448,7 @@ def _run_scenario(topo: Topology, links: LinkParams, ctrl: ControllerConfig,
     if dense or sparse:
         if single:
             freq = freq[0]
-        psi_f = _fetch(tr, psi_pad)[:b, :n]
-        nu_f = _fetch(tr, nu_pad)[:b, :n]
+        psi_f, nu_f = _fetch_unpadded(tr, (psi_pad, nu_pad), b, n)
         if rb_dense:
             beta = np.concatenate(beta_chunks, axis=1)
             if single:

@@ -20,12 +20,13 @@ def compile_stats() -> dict:
     fused and tiled share one jitted wrapper (the engine choice is a
     static argument of ``_fused_engine``), so they share a key here.
     ``dense-stacks`` is the scenario runner's device builder of the
-    dense lanes' adjacency stacks.
+    dense lanes' adjacency stacks, ``read-back`` its device slice of the
+    kernel lanes' padded outputs before they are read back.
     """
     from repro.core.frame_model import _jitted_run, _jitted_run_ensemble
     from repro.kernels.ops import (_fused_engine, _perstep_engine,
                                    _sparse_engine)
-    from repro.scenarios.runner import _scatter_stack
+    from repro.scenarios.runner import _scatter_stack, _unpad
     return {
         "fused/tiled": _fused_engine._cache_size(),
         "per-step": _perstep_engine._cache_size(),
@@ -33,6 +34,7 @@ def compile_stats() -> dict:
         "segment-sum": _jitted_run()._cache_size(),
         "segment-sum-ensemble": _jitted_run_ensemble()._cache_size(),
         "dense-stacks": _scatter_stack._cache_size(),
+        "read-back": _unpad._cache_size(),
     }
 
 

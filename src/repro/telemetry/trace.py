@@ -42,8 +42,10 @@ last three):
     mark              freeform user annotation
 
 Counters (:meth:`RunTrace.count`): ``h2d_bytes``, the padded bytes of
-every host array the run places on the device; ``d2h_bytes``, those of
-every device array it reads back.
+every host array the run places on the device; ``d2h_bytes``, the bytes
+of every device array it reads back (the kernel lanes' records and
+state cut to their unpadded draws and nodes on the device first);
+``d2h_reads``, the number of those blocking reads.
 
 Export is JSON-lines (a header line with the counters first, then one
 event per line) and round-trips through :meth:`RunTrace.from_jsonl`.

@@ -9,7 +9,9 @@ and the spans ``run_scenario`` opens on every kernel lane.
 3. A traced FC8 scenario (re-established LatencyStep, explicit graph
    Reframe, guard on) emits every span kind on each kernel lane; each
    ``chunk``'s dispatch / wait / fetch children lie inside it.
-4. ``h2d_bytes`` / ``d2h_bytes`` equal the bytes of the padded shapes.
+4. ``h2d_bytes`` equals the bytes of the padded shapes, ``d2h_bytes``
+   those of the unpadded ones, and ``d2h_reads`` one read a chunk and
+   one a state read; the unpadded reads equal the padded lane's.
 5. With ``annotate=True`` a ``jax.profiler`` capture holds one host
    event per span, with its name, at the span's start mapped onto the
    profiler clock.
@@ -24,7 +26,7 @@ import pytest
 from engine_harness import zero_mean_ppm
 from repro.core import (ControllerConfig, ReframePolicy, SimConfig,
                         fully_connected, make_links)
-from repro.kernels import EngineOptions
+from repro.kernels import EngineOptions, simulate_ensemble_dense
 from repro.kernels.bittide_step import SUBLANE, TILE
 from repro.scenarios import (LatencyStep, Reframe, Scenario, edges_between,
                              run_scenario)
@@ -246,15 +248,19 @@ def test_compiled_scenario_skips_the_compile_span():
 # ------------------------------------------------------ 4. byte counters
 
 @pytest.mark.parametrize("engine", ["fused", "sparse"])
-def test_transfer_counters_match_padded_shapes(engine):
+def test_transfer_counters_match_unpadded_shapes(engine):
     """No guard: the counts follow from the shapes alone.  Two segments
     with different latency tables (two stacks or slot tables), three
     chunks of 4 records, one re-establish and one explicit Reframe, each
-    reading the live state once."""
+    reading the live state once.  Uploads are padded; read-backs carry
+    the unpadded (b, n) slices only, one read a chunk and one a state
+    read, and the lanes read no gains back."""
     b = 2
     res, tr = _traced_run(engine, guard=False, b=b)
+    n = res.topo.num_nodes
     n_pad, b_pad = TILE, SUBLANE
     state = b_pad * n_pad * 4                    # one (B_pad, N_pad) f32
+    real = b * n * 4                             # one (b, n) f32
     disp = tr.by_kind("engine_dispatch")
     segs = len(disp)
     assert segs == 3 and res.num_launches == 3
@@ -265,17 +271,17 @@ def test_transfer_counters_match_padded_shapes(engine):
         h2d = (2 * 4 * e * 4 + c * 4                   # edge lists, λ dummy
                + segs * (2 * state + b_pad * c * 4 + n_pad * 4
                          + 2 * b_pad * 4))              # prep
-        reads = segs * 2 * b_pad * 4                    # kp, β_off back
     else:
         k = disp[0].data["k"]
         h2d = (k * n_pad * 4 + 2 * 2 * k * n_pad * 4    # nbr; latf, w ×2
                + segs * (2 * state + n_pad * 4 + 2 * b_pad * 4))
-        reads = 0
-    d2h = (sum(2 * r * state for r in records)          # freq, β
-           + res.num_launches * 4 * state               # watermarks
-           + 2 * 2 * state                              # splice, Reframe
-           + 2 * state + reads)                         # final ψ, ν
-    assert tr.counters == {"h2d_bytes": h2d, "d2h_bytes": d2h}
+    d2h = (sum(2 * r * real for r in records)           # freq, β
+           + res.num_launches * 4 * real                # watermarks
+           + 2 * 2 * real                               # splice, Reframe
+           + 2 * real)                                  # final ψ, ν
+    reads = res.num_launches + 2 + 1
+    assert tr.counters == {"h2d_bytes": h2d, "d2h_bytes": d2h,
+                           "d2h_reads": reads}
 
 
 def test_untraced_run_matches_traced_run():
@@ -287,6 +293,66 @@ def test_untraced_run_matches_traced_run():
     assert b.trace is None
     np.testing.assert_array_equal(a.freq_ppm, b.freq_ppm)
     np.testing.assert_array_equal(a.beta, b.beta)
+
+
+def _padded_fc8(b=5):
+    """FC8 at B = 5: padded on both axes (B to 8 rows, N to 128 lanes)."""
+    topo = fully_connected(8)
+    links = make_links(topo, cable_m=2.0)
+    ctrl = ControllerConfig(kp=2e-7)
+    ppm = np.stack([zero_mean_ppm(8, 0.5, seed=10 + s) for s in range(b)])
+    cfg = SimConfig(dt=1e-3, steps=96, record_every=12)
+    return topo, links, ctrl, ppm.astype(np.float32), cfg
+
+
+def test_unpadded_read_back_matches_the_ensemble_lane_bit_for_bit():
+    """The device slice moves where the padding is cut, not what is
+    read: freq, β, the four watermark fields, ψ and ν equal the padded
+    reads of ``simulate_ensemble_dense`` bit for bit."""
+    topo, links, ctrl, ppm, cfg = _padded_fc8()
+    tr = RunTrace()
+    res = run_scenario(topo, links, ctrl, ppm, Scenario(events=()), cfg,
+                       options=EngineOptions(engine="fused"),
+                       telemetry=Telemetry(beta=True, watermarks=True,
+                                           trace=tr))
+    ref = simulate_ensemble_dense(
+        topo, links, ppm, steps=cfg.steps, kp=ctrl.kp, dt=cfg.dt,
+        record_every=cfg.record_every,
+        options=EngineOptions(engine="fused"),
+        telemetry=Telemetry(beta=True, watermarks=True))
+    assert res.engine == ref.engine == "fused"
+    assert tr.counters["d2h_reads"] == res.num_launches + 1
+    np.testing.assert_array_equal(res.freq_ppm, ref[0])
+    np.testing.assert_array_equal(res.beta, ref.beta)
+    np.testing.assert_array_equal(res.psi, ref[1])
+    np.testing.assert_array_equal(res.nu, ref.nu)
+    for f in ("beta_abs_max", "peak_record", "nu_min_ppm", "nu_max_ppm"):
+        np.testing.assert_array_equal(getattr(res.watermarks, f),
+                                      getattr(ref.watermarks, f))
+
+
+@pytest.mark.parametrize("engine", ["fused", "sparse"])
+def test_unpadded_splice_reads_once_a_chunk(engine):
+    """A re-established LatencyStep at B = 5: one read a chunk, one at
+    the splice, one at the end; traced and untraced runs agree."""
+    topo, links, ctrl, ppm, cfg = _padded_fc8()
+    scen = Scenario(events=(LatencyStep(
+        t=0.048, edges=edges_between(topo, 0, 2), cable_m=1000.0,
+        reestablish=True),))
+    kw = dict(options=EngineOptions(engine=engine))
+    tr = RunTrace()
+    a = run_scenario(topo, links, ctrl, ppm, scen, cfg,
+                     telemetry=Telemetry(beta=True, watermarks=True,
+                                         trace=tr), **kw)
+    b = run_scenario(topo, links, ctrl, ppm, scen, cfg,
+                     telemetry=Telemetry(beta=True, watermarks=True), **kw)
+    assert a.engine == engine and a.freq_ppm.shape == (5, 8, 8)
+    assert len(tr.by_kind("chunk")) == a.num_launches == 2
+    assert tr.counters["d2h_reads"] == a.num_launches + 2
+    for f in ("freq_ppm", "beta", "psi", "nu"):
+        np.testing.assert_array_equal(getattr(a, f), getattr(b, f))
+    np.testing.assert_array_equal(a.watermarks.beta_abs_max,
+                                  b.watermarks.beta_abs_max)
 
 
 # ------------------------------------------- 5. the profiler's own clock

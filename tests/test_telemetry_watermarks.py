@@ -314,7 +314,7 @@ def test_trace_event_is_frozen():
 def test_compile_stats_is_the_harness_guard():
     keys = set(compile_stats())
     assert keys == {"fused/tiled", "per-step", "sparse", "segment-sum",
-                    "segment-sum-ensemble", "dense-stacks"}
+                    "segment-sum-ensemble", "dense-stacks", "read-back"}
     assert engine_cache_sizes is compile_stats
     with pytest.raises(KeyError):
         no_new_compiles(nonsense=1)
