@@ -9,6 +9,9 @@ bittide_step    pl.pallas_call kernels: per-step baseline + fused multi-period
                 folds and the per-node controller-enable mask are all traced
                 inputs — scenario segments and Monte-Carlo link draws reuse
                 one compiled kernel.
+period          the period body the Pallas lanes share: controller law,
+                β measurement, watermarks, guard, early exit, and the
+                wrappers' guard inputs and output layout.
 bittide_sparse  edge-major ELL engine: per-node state resident, (K, N) slot
                 tables (neighbor / per-edge latency / weight) streamed in
                 i-panels — O(N·deg) per period for bounded-degree graphs

@@ -14,8 +14,10 @@ control period as K slot gathers over a **slot-major ELL table**:
     err_i = Σ_k w[k,i]·(ψ[nbr[k,i]] − ν[nbr[k,i]]·latf[k,i])
             − (ψ_i + β_off)·deg_i + lamsum_i,      deg_i = Σ_k w[k,i]
 
-followed by the same cancellation-free controller update as the dense
-kernels.  Per-period cost is O(N·K) — for torus3d(34) (39,304 nodes,
+followed by the cancellation-free controller update the dense kernels
+use: the period body, the measurement, the guard and the output layout
+are ``repro.kernels.period``'s, and this module keeps only the mirror,
+the gather and the column-sliced writes.  Per-period cost is O(N·K) — for torus3d(34) (39,304 nodes,
 K=6) that is ~6,500× less arithmetic than the dense formulation.  On a
 TPU the VMEM-resident state then bounds the node count instead (about
 5·10⁴ nodes at B = 8, see ``sparse_vmem_bytes``).
@@ -81,10 +83,10 @@ from jax.experimental.pallas import tpu as pltpu
 
 from repro.core.topology import Topology
 
-from .bittide_step import (COMPILER_PARAMS, SUBLANE, TILE, VMEM_BUDGET_BYTES,
-                           _check_shapes, _gain_col, _guard_cols,
-                           _lamsum_rows, _mask_row, _split_outputs,
-                           sparse_vmem_bytes)
+from . import period
+from .bittide_step import (SUBLANE, TILE, VMEM_BUDGET_BYTES, _check_shapes,
+                           _lamsum_rows, _mask_row, sparse_vmem_bytes)
+from .period import _gain_col
 
 __all__ = ["bittide_sparse_pallas", "ellify", "max_in_degree"]
 
@@ -190,38 +192,14 @@ def _sparse_kernel(nbr_ref, latf_ref, w_ref, psi0_ref, nu0_ref, nu_u_ref,
     t = pl.program_id(0)
     p = pl.program_id(1)
     i = pl.program_id(2)
-    # With β recording (watermarks, or the in-kernel guard) the period
-    # axis carries one extra trailing pass per record: p < periods
-    # advances the state, p == periods re-streams the table panels to
-    # aggregate the POST-update state's occupancy.
     measure = record_beta or record_watermarks or record_guard
-    periods = pl.num_programs(1) - (1 if measure else 0)
-
-    refs = list(rest)
-    if record_guard:
-        glo_ref, ghi_ref, stop_ref = refs[:3]
-        refs = refs[3:]
-    psi_out_ref, nu_out_ref, rec_ref = refs[:3]
-    refs = refs[3:]
-    brec_ref = refs.pop(0) if record_beta else None
-    if record_watermarks:
-        wm_beta_ref, wm_idx_ref, wm_lo_ref, wm_hi_ref = refs[:4]
-        refs = refs[4:]
-    trip_ref = refs.pop(0) if record_guard else None
-    psi_s, nu_s, mir_s, row_s = refs
+    periods = period.measure_periods(measure)
+    r = period.unpack(rest, record_beta, record_watermarks, record_guard)
+    psi_s, nu_s, mir_s, row_s = r.scratch
     b, n = psi_s.shape
     tile_i = row_s.shape[0]
-
-    first = jnp.logical_and(t == 0, jnp.logical_and(p == 0, i == 0))
-
-    @pl.when(first)
-    def _seed():
-        psi_s[...] = psi0_ref[...]
-        nu_s[...] = nu0_ref[...]
-        if record_guard:
-            # "Never tripped" sentinel: num_records, one past any record.
-            trip_ref[...] = jnp.full(trip_ref.shape, pl.num_programs(0),
-                                     jnp.int32)
+    period.seed(r, psi0_ref, nu0_ref,
+                jnp.logical_and(t == 0, jnp.logical_and(p == 0, i == 0)))
 
     def _snapshot():
         """Node-major mirror of the pass's input state (see module doc)."""
@@ -242,8 +220,8 @@ def _sparse_kernel(nbr_ref, latf_ref, w_ref, psi0_ref, nu0_ref, nu_u_ref,
         def rows(r8, carry):
             # Mosaic unrolls only fully, so unroll one sublane by hand.
             for u in range(SUBLANE):
-                r = r8 * SUBLANE + u
-                row_s[pl.ds(r, 1), :] = mir_s[pl.ds(nbr_ref[k, r], 1), :]
+                row = r8 * SUBLANE + u
+                row_s[pl.ds(row, 1), :] = mir_s[pl.ds(nbr_ref[k, row], 1), :]
             return carry
 
         jax.lax.fori_loop(0, tile_i // SUBLANE, rows, 0)
@@ -257,12 +235,7 @@ def _sparse_kernel(nbr_ref, latf_ref, w_ref, psi0_ref, nu0_ref, nu_u_ref,
             _snapshot()
 
         if measure:
-            # β pass: center ψ by its full-row mean (β is exactly
-            # shift-invariant; centering keeps float32 partial sums O(ψ
-            # spread)).  The mean is over the whole scratch row, so every
-            # panel of the pass — and every engine — subtracts the same
-            # constant.
-            m = jnp.mean(psi_s[...], axis=1, keepdims=True)  # (B, 1)
+            m = period.row_mean(psi_s)                     # (B, 1)
 
         # K slot gathers over the streamed (·, K, tile_i) table panel:
         # each slot row pulls its source nodes' state from the mirror
@@ -275,26 +248,19 @@ def _sparse_kernel(nbr_ref, latf_ref, w_ref, psi0_ref, nu0_ref, nu_u_ref,
             g = _gather(k)
             g_psi, g_nu = g[:b], g[b:2 * b]                # (B, TI)
             if measure:
-                g_psi = jnp.where(p == periods, g_psi - m, g_psi)
+                g_psi = period.centre(g_psi, m, p == periods)
             acc = acc + w[:, k, :] * (g_psi - g_nu * lat[:, k, :])
 
         psi_i = psi_s[:, cols]                             # (B, TI)
         nu_i = nu_s[:, cols]
         if measure:
-            psi_i = jnp.where(p == periods, psi_i - m, psi_i)
+            psi_i = period.centre(psi_i, m, p == periods)
 
         @pl.when(p < periods)
         def _update():
-            err = acc - (psi_i + boff_ref[...]) * deg + lamsum_ref[...]
-            # ν' = (1+ν_u)(1+c) − 1 computed as ν_u + c + ν_u·c: never
-            # forms 1 + O(1e-6) (float32 eps(1.0) = 1.19e-7 would
-            # quantize it).
-            c_rel = kp_ref[...] * err
-            nu_u = nu_u_ref[...]
-            nu_next = nu_u + c_rel + nu_u * c_rel
-            # Holdover: masked-out nodes freeze ν at its previous value.
-            nu_next = jnp.where(mask_ref[...] > 0.5, nu_next, nu_i)
-            psi_next = psi_i + nu_next * dt_frames
+            psi_next, nu_next = period.control(
+                acc, psi_i, nu_i, nu_u_ref, kp_ref, boff_ref, deg,
+                lamsum_ref, mask_ref, dt_frames)
             # In place: later panels gather from the mirror's snapshot of
             # the pre-period state, never from these columns.
             psi_s[:, cols] = psi_next
@@ -302,66 +268,18 @@ def _sparse_kernel(nbr_ref, latf_ref, w_ref, psi0_ref, nu0_ref, nu_u_ref,
             # Telemetry flushes to HBM when the record index advances, so
             # overwriting every period within a record is decimation for
             # free.
-            rec_ref[0, :, cols] = nu_next
-            psi_out_ref[:, cols] = psi_next
-            nu_out_ref[:, cols] = nu_next
+            r.rec[0, :, cols] = nu_next
+            r.psi_out[:, cols] = psi_next
+            r.nu_out[:, cols] = nu_next
 
         if measure:
             @pl.when(p == periods)
             def _record_beta():
                 # acc aggregated the centered post-update state this pass.
                 bnode = acc - psi_i * deg + lamsum_ref[...]
-                if record_beta:
-                    brec_ref[0, :, cols] = bnode
-                if record_watermarks:
-                    # Watermark accumulators are whole (B, N) output
-                    # blocks with CONSTANT index maps (VMEM-resident for
-                    # the whole grid, read-modify-write safe); each panel
-                    # updates only its own node columns.  Strict > keeps
-                    # the FIRST record attaining the max.
-                    babs = jnp.abs(bnode)
+                period.measure(r, bnode, nu_i, t, deg, cols)
 
-                    @pl.when(t == 0)
-                    def _wm_seed():
-                        wm_beta_ref[:, cols] = babs
-                        wm_idx_ref[:, cols] = jnp.zeros_like(babs,
-                                                             jnp.int32)
-                        wm_lo_ref[:, cols] = nu_i
-                        wm_hi_ref[:, cols] = nu_i
-
-                    @pl.when(t > 0)
-                    def _wm_update():
-                        prev = wm_beta_ref[:, cols]
-                        wm_idx_ref[:, cols] = jnp.where(babs > prev, t,
-                                                        wm_idx_ref[:, cols])
-                        wm_beta_ref[:, cols] = jnp.maximum(prev, babs)
-                        wm_lo_ref[:, cols] = jnp.minimum(
-                            wm_lo_ref[:, cols], nu_i)
-                        wm_hi_ref[:, cols] = jnp.maximum(
-                            wm_hi_ref[:, cols], nu_i)
-                if record_guard:
-                    # Degree-scaled band check for THIS panel's node
-                    # columns; the (B, 1) trip block is shared across
-                    # panels (constant index map), so a violation in any
-                    # panel of record t lands t in the draw's slot.
-                    viol = jnp.logical_or(bnode > ghi_ref[...] * deg,
-                                          bnode < glo_ref[...] * deg)
-                    row_viol = jnp.any(viol, axis=1, keepdims=True)
-                    trip_ref[...] = jnp.where(row_viol, t, trip_ref[...])
-
-    if record_guard:
-        # Chunk early-exit: freeze every grid step of records after the
-        # earliest trip (or past the host's stop_after cap).  min(trip)
-        # ≥ t keeps the remaining panels of the trip record live, so the
-        # trip record itself is fully recorded before the freeze.
-        live = jnp.logical_and(jnp.min(trip_ref[...]) >= t,
-                               t <= stop_ref[0, 0])
-
-        @pl.when(live)
-        def _run():
-            _step()
-    else:
-        _step()
+    period.run(r, t, _step)
 
 
 def bittide_sparse_pallas(psi, nu, nu_u, nbr, latf, w, lamsum, kp, beta_off,
@@ -444,52 +362,10 @@ def bittide_sparse_pallas(psi, nu, nu_u, nbr, latf, w, lamsum, kp, beta_off,
             "— shard the node axis or use the segment-sum simulator")
 
     kern = functools.partial(
-        _sparse_kernel, dt_frames=float(dt_frames), max_deg=int(k),
-        record_beta=bool(record_beta),
-        record_watermarks=bool(record_watermarks),
-        record_guard=bool(record_guard))
-
+        _sparse_kernel, dt_frames=float(dt_frames), max_deg=int(k))
     mask = _mask_row(ctrl_mask, n, b)
-    full3 = lambda t, p, i: (0, 0)
+    whole = period.whole
     panel2 = lambda t, p, i: (0, i)
-    record = lambda t, p, i: (t, 0, 0)
-    # Every output block is whole-row: the pipeline writes an output
-    # block back each time its index changes and never reads it in, so a
-    # panel-wide block revisited on the next pass (or skipped by a guard
-    # freeze) would flush a stale buffer over the panel's results.  Each
-    # panel writes its own columns of the resident block instead.
-    out_specs = [
-        pl.BlockSpec((b, n), full3),                          # psi final
-        pl.BlockSpec((b, n), full3),                          # nu final
-        pl.BlockSpec((1, b, n), record),                      # ν record
-    ]
-    out_shape = [
-        jax.ShapeDtypeStruct((b, n), jnp.float32),
-        jax.ShapeDtypeStruct((b, n), jnp.float32),
-        jax.ShapeDtypeStruct((num_records, b, n), jnp.float32),
-    ]
-    if record_beta:
-        out_specs.append(pl.BlockSpec((1, b, n), record))
-        out_shape.append(
-            jax.ShapeDtypeStruct((num_records, b, n), jnp.float32))
-    if record_watermarks:
-        # Whole-row (B, N) accumulators with constant index maps: they
-        # stay VMEM-resident across the grid and each panel
-        # read-modify-writes its own columns.
-        for dt_ in (jnp.float32, jnp.int32, jnp.float32, jnp.float32):
-            out_specs.append(pl.BlockSpec((b, n), full3))
-            out_shape.append(jax.ShapeDtypeStruct((b, n), dt_))
-    if record_guard:
-        # (B, 1) first-trip record index, constant index map shared by
-        # every panel (VMEM-resident; flushed once at the end).
-        out_specs.append(pl.BlockSpec((b, 1), full3))
-        out_shape.append(jax.ShapeDtypeStruct((b, 1), jnp.int32))
-    scratch = [
-        pltpu.VMEM((b, n), jnp.float32),                      # ψ carry
-        pltpu.VMEM((b, n), jnp.float32),                      # ν carry
-        pltpu.VMEM((n, _mirror_width(b)), jnp.float32),       # mirror
-        pltpu.VMEM((tile_i, _mirror_width(b)), jnp.float32),  # gathered
-    ]
     in_specs = [
         # Table panels: the index map advances with i, so the Pallas
         # pipeline double-buffers the HBM fetch of panel i+1 behind
@@ -501,11 +377,11 @@ def bittide_sparse_pallas(psi, nu, nu_u, nbr, latf, w, lamsum, kp, beta_off,
                      lambda t, p, i: (0, 0, i)),          # latf
         pl.BlockSpec((w.shape[0], k, tile_i),
                      lambda t, p, i: (0, 0, i)),          # w
-        pl.BlockSpec((b, n), full3),                      # psi0
-        pl.BlockSpec((b, n), full3),                      # nu0
+        pl.BlockSpec((b, n), whole),                      # psi0
+        pl.BlockSpec((b, n), whole),                      # nu0
         pl.BlockSpec((b, tile_i), panel2),                # nu_u
-        pl.BlockSpec((b, 1), full3),                      # kp per draw
-        pl.BlockSpec((b, 1), full3),                      # beta_off
+        pl.BlockSpec((b, 1), whole),                      # kp per draw
+        pl.BlockSpec((b, 1), whole),                      # beta_off
         pl.BlockSpec((mask.shape[0], tile_i), panel2),    # ctrl mask
         pl.BlockSpec((b, tile_i), panel2),                # lamsum
     ]
@@ -514,22 +390,18 @@ def bittide_sparse_pallas(psi, nu, nu_u, nbr, latf, w, lamsum, kp, beta_off,
             nu.astype(jnp.float32), nu_u.astype(jnp.float32),
             _gain_col(kp, b, "kp"), _gain_col(beta_off, b, "beta_off"),
             mask, _lamsum_rows(lamsum, b, n)]
-    if record_guard:
-        in_specs += [pl.BlockSpec((b, 1), full3),         # guard band lo
-                     pl.BlockSpec((b, 1), full3),         # guard band hi
-                     pl.BlockSpec((b, 1), full3)]         # stop-after
-        args += _guard_cols(guard_lo, guard_hi, guard_stop, b)
     measure = record_beta or record_watermarks or record_guard
-    out = pl.pallas_call(
-        kern,
-        name="bittide_sparse",
-        grid=(num_records, record_every + (1 if measure else 0),
-              i_panels),
-        in_specs=in_specs,
-        out_specs=out_specs,
-        out_shape=out_shape,
-        scratch_shapes=scratch,
-        compiler_params=COMPILER_PARAMS,
-        interpret=interpret,
-    )(*args)
-    return _split_outputs(out, record_beta, record_watermarks, record_guard)
+    return period.launch(
+        kern, name="bittide_sparse",
+        grid=(num_records, record_every + (1 if measure else 0), i_panels),
+        in_specs=in_specs, args=args,
+        scratch=[
+            pltpu.VMEM((b, n), jnp.float32),                      # ψ carry
+            pltpu.VMEM((b, n), jnp.float32),                      # ν carry
+            pltpu.VMEM((n, _mirror_width(b)), jnp.float32),       # mirror
+            pltpu.VMEM((tile_i, _mirror_width(b)), jnp.float32),  # gathered
+        ],
+        b=b, n=n, num_records=num_records, record_beta=record_beta,
+        record_watermarks=record_watermarks, record_guard=record_guard,
+        guard_lo=guard_lo, guard_hi=guard_hi, guard_stop=guard_stop,
+        interpret=interpret)

@@ -17,7 +17,10 @@ step-invariant and precomputed once (they fold the per-edge λeff and β_off
 terms into per-node constants — this algebraic refactor is what removes the
 need to ever materialize the (C, N, N) occupancy tensor β).
 
-Two kernels are provided:
+Three lanes are provided.  They share one period body —
+``repro.kernels.period`` holds the controller law, the β measurement,
+the watermarks, the guard, the early exit and the wrapper-side output
+layout — and each keeps only how it aggregates the adjacency:
 
 ``bittide_step_pallas``
     One control period, grid (N/TILE, N/TILE), err accumulated in the ν'
@@ -50,6 +53,9 @@ Two kernels are provided:
     scratch and an accumulator are VMEM-resident.  With a single j tile
     (TILE_J == N) it degenerates to the resident engine's schedule minus
     the in-kernel period loop.
+
+The sparse ELL lane (``repro.kernels.bittide_sparse``) is the third user
+of the shared body.
 
 Controller gains (``kp``, ``beta_off``) are *traced per-draw inputs* of
 shape (B, 1) in both engines — never compile-time constants — so Fig-15
@@ -106,7 +112,8 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
-from .api import EngineOutputs
+from . import period
+from .period import COMPILER_PARAMS, VMEM_LIMIT_BYTES, _gain_col
 
 __all__ = ["bittide_step_pallas", "bittide_fused_pallas",
            "bittide_tiled_fused_pallas", "select_engine", "fused_vmem_bytes",
@@ -117,16 +124,9 @@ __all__ = ["bittide_step_pallas", "bittide_fused_pallas",
 TILE = 128     # MXU/VPU-aligned tile edge (lane axis)
 SUBLANE = 8    # float32 sublane quantum (batch axis of the fused kernel)
 
-# Scoped-VMEM limit every pallas_call compiles against.  Mosaic's default
-# scoped limit (16 MiB on v5e) is a compiler setting, not the size of the
-# core's VMEM (128 MiB on v5e/v6e): the telemetry variants of the tiled
-# lane at Fig-18 scale need more than the default, so every kernel passes
-# this limit explicitly.
-VMEM_LIMIT_BYTES = 64 * 1024 * 1024
 # What the estimators below may fill: the remaining quarter is headroom
 # for Mosaic's internal scratch (spilled (B, N) temporaries).
 VMEM_BUDGET_BYTES = VMEM_LIMIT_BYTES * 3 // 4
-COMPILER_PARAMS = pltpu.CompilerParams(vmem_limit_bytes=VMEM_LIMIT_BYTES)
 # Full-f32 MXU passes for every aggregation: at Mosaic's default f32
 # contraction precision the fused lane ran 4e-3 ppm (a bf16-scale error)
 # from segment-sum on a v5e — ψ is not mean-centred in the period update.
@@ -182,18 +182,9 @@ def _kernel(lat_ref, a_ref, psi_j_ref, nu_j_ref, psi_i_ref, nu_i_ref,
             opt_refs[0][...] = (nu_out_ref[...]
                                 - psi_i_ref[...] * deg_ref[...]
                                 + lamsum_ref[...])
-        err = (nu_out_ref[...]
-               - (psi_i_ref[...] + beta_off) * deg_ref[...]
-               + lamsum_ref[...])
-        # ν' = (1+ν_u)(1+c) − 1 computed as ν_u + c + ν_u·c: never forms
-        # 1 + O(1e-6), which would quantize to float32 eps(1.0) = 1.19e-7.
-        c_rel = kp * err
-        nu_next = nu_u_ref[...] + c_rel + nu_u_ref[...] * c_rel
-        # Holdover: a masked-out node's ν is frozen at its previous value
-        # (the oscillator keeps its last correction), not recomputed.
-        nu_next = jnp.where(mask_ref[...] > 0.5, nu_next, nu_i_ref[...])
-        psi_out_ref[...] = psi_i_ref[...] + nu_next * dt_frames
-        nu_out_ref[...] = nu_next
+        psi_out_ref[...], nu_out_ref[...] = period.control(
+            nu_out_ref, psi_i_ref, nu_i_ref, nu_u_ref, kp, beta_off,
+            deg_ref, lamsum_ref, mask_ref, dt_frames)
 
 
 def bittide_step_pallas(psi, nu, nu_u, a, lam_eff, lat_frames,
@@ -289,34 +280,9 @@ def _fused_kernel(lat_ref, a_ref, psi0_ref, nu0_ref, nu_u_ref, kp_ref,
                   record_beta: bool, record_watermarks: bool,
                   record_guard: bool):
     t = pl.program_id(0)
-
-    # Optional guard-band inputs trail the fixed inputs; optional outputs
-    # are spliced between the fixed outputs and the scratch refs
-    # (pallas_call passes inputs, then outputs, then scratch): β record
-    # first, then the four (B, N) watermark accumulators, then the (B, 1)
-    # trip-record index.
-    refs = list(rest)
-    if record_guard:
-        glo_ref, ghi_ref, stop_ref = refs[:3]
-        refs = refs[3:]
-    psi_out_ref, nu_out_ref, rec_ref = refs[:3]
-    refs = refs[3:]
-    brec_ref = refs.pop(0) if record_beta else None
-    if record_watermarks:
-        wm_beta_ref, wm_idx_ref, wm_lo_ref, wm_hi_ref = refs[:4]
-        refs = refs[4:]
-    trip_ref = refs.pop(0) if record_guard else None
-    psi_s, nu_s = refs
-
-    # First grid step: load initial state into the persistent VMEM scratch.
-    @pl.when(t == 0)
-    def _seed():
-        psi_s[...] = psi0_ref[...]
-        nu_s[...] = nu0_ref[...]
-        if record_guard:
-            # "Never tripped" sentinel: num_records, one past any record.
-            trip_ref[...] = jnp.full(trip_ref.shape, pl.num_programs(0),
-                                     jnp.int32)
+    r = period.unpack(rest, record_beta, record_watermarks, record_guard)
+    psi_s, nu_s = r.scratch
+    period.seed(r, psi0_ref, nu0_ref, t == 0)
 
     nu_u = nu_u_ref[...]        # (B, N), resident across the whole run
     deg = deg_ref[...]          # (1, N), broadcasts over B
@@ -327,107 +293,46 @@ def _fused_kernel(lat_ref, a_ref, psi0_ref, nu0_ref, nu_u_ref, kp_ref,
     enabled = mask_ref[...] > 0.5   # (1, N)|(B, N) controller-enable mask
     measure = record_beta or record_watermarks or record_guard
 
-    def period(_, carry):
-        psi, nu = carry
+    def aggregate(psi, nu):
         acc = jnp.zeros_like(psi)
         for c in range(num_classes):
             x = psi - nu * lat[:, c:c + 1]                        # (B, N)
-            # err[b, i] += Σ_j A[c, i, j] · x[b, j]  — an MXU matmul.
+            # acc[b, i] += Σ_j A[c, i, j] · x[b, j]  — an MXU matmul.
             acc = acc + jax.lax.dot_general(
                 x, a_ref[c],
                 dimension_numbers=(((1,), (1,)), ((), ())),
                 precision=_F32_MXU,
                 preferred_element_type=jnp.float32)
-        err = acc - (psi + beta_off) * deg + lamsum
-        c_rel = kp * err
-        nu_next = nu_u + c_rel + nu_u * c_rel
-        # Holdover: masked-out nodes freeze ν at its previous value.
-        nu_next = jnp.where(enabled, nu_next, nu)
-        psi_next = psi + nu_next * dt_frames
-        return psi_next, nu_next
+        return acc
+
+    def one_period(_, carry):
+        psi, nu = carry
+        return period.control(aggregate(psi, nu), psi, nu, nu_u, kp,
+                              beta_off, deg, lamsum, enabled, dt_frames)
 
     def _advance():
         psi, nu = jax.lax.fori_loop(
-            0, record_every, period, (psi_s[...], nu_s[...]))
+            0, record_every, one_period, (psi_s[...], nu_s[...]))
         psi_s[...] = psi
         nu_s[...] = nu
 
         # Decimated telemetry: ν once per record, not once per period.
-        rec_ref[...] = nu[None]
+        r.rec[...] = nu[None]
         if measure:
             # Per-node net occupancy of the POST-update state (the
-            # segment-sum recording convention).  β is invariant under a
-            # uniform ψ shift, so center ψ by its row mean first: the
-            # matmul partial sums then stay O(ψ spread) instead of O(ψ
-            # magnitude), which is what keeps the float32 record within
-            # 1e-6 frames of the edge-list math.  Cost: one extra C-class
+            # segment-sum recording convention), from one extra
             # aggregation per RECORD on the resident adjacency —
             # ~1/record_every of the period loop's matmul work.  The
-            # watermarks and the in-kernel guard reuse the SAME
-            # aggregation, so the in-kernel peak is bit-identical to a
-            # reduction of the full β record.
-            psi_c = psi - jnp.mean(psi, axis=1, keepdims=True)
-            bacc = jnp.zeros_like(psi)
-            for c in range(num_classes):
-                x = psi_c - nu * lat[:, c:c + 1]
-                bacc = bacc + jax.lax.dot_general(
-                    x, a_ref[c],
-                    dimension_numbers=(((1,), (1,)), ((), ())),
-                    precision=_F32_MXU,
-                    preferred_element_type=jnp.float32)
-            bnode = bacc - psi_c * deg + lamsum
-            if record_beta:
-                brec_ref[...] = bnode[None]
-            if record_watermarks:
-                # O(B·N) running aggregates in the revisited output blocks
-                # (constant index maps: the blocks stay in VMEM across the
-                # whole grid and flush once at the end).  Strict > keeps
-                # the FIRST record attaining the max — np.argmax semantics.
-                babs = jnp.abs(bnode)
+            # watermarks and the guard read the SAME aggregation, so the
+            # in-kernel peak is bit-identical to a reduction of the full
+            # β record.
+            psi_c = psi - period.row_mean(psi)
+            bnode = aggregate(psi_c, nu) - psi_c * deg + lamsum
+            period.measure(r, bnode, nu, t, deg)
+        r.psi_out[...] = psi
+        r.nu_out[...] = nu
 
-                @pl.when(t == 0)
-                def _wm_seed():
-                    wm_beta_ref[...] = babs
-                    wm_idx_ref[...] = jnp.zeros_like(babs, jnp.int32)
-                    wm_lo_ref[...] = nu
-                    wm_hi_ref[...] = nu
-
-                @pl.when(t > 0)
-                def _wm_update():
-                    wm_idx_ref[...] = jnp.where(babs > wm_beta_ref[...], t,
-                                                wm_idx_ref[...])
-                    wm_beta_ref[...] = jnp.maximum(wm_beta_ref[...], babs)
-                    wm_lo_ref[...] = jnp.minimum(wm_lo_ref[...], nu)
-                    wm_hi_ref[...] = jnp.maximum(wm_hi_ref[...], nu)
-            if record_guard:
-                # In-kernel reframing guard: a draw trips when any live
-                # node's net occupancy leaves the degree-scaled band
-                # [lo·deg_i, hi·deg_i] (lo/hi = target ∓ guard, frames per
-                # unit weighted degree — the host lowers them from
-                # envelopes.reframe_guard_margin).  Strict inequalities
-                # keep degree-0 padding nodes (β ≡ 0) inert.
-                viol = jnp.logical_or(bnode > ghi_ref[...] * deg,
-                                      bnode < glo_ref[...] * deg)
-                row_viol = jnp.any(viol, axis=1, keepdims=True)   # (B, 1)
-                trip_ref[...] = jnp.where(row_viol, t, trip_ref[...])
-        psi_out_ref[...] = psi
-        nu_out_ref[...] = nu
-
-    if record_guard:
-        # Chunk early-exit: once ANY draw tripped at a record t' < t (or
-        # the host capped the launch at stop_after), the remaining grid
-        # steps are no-ops — state, record stream and watermarks freeze at
-        # the trip record, and the host resumes from there at zero
-        # recompiles.  min(trip) ≥ t (sentinel = num_records) keeps the
-        # trip record itself fully recorded.
-        live = jnp.logical_and(jnp.min(trip_ref[...]) >= t,
-                               t <= stop_ref[0, 0])
-
-        @pl.when(live)
-        def _run():
-            _advance()
-    else:
-        _advance()
+    period.run(r, t, _advance)
 
 
 def _block_bytes(*shape: int) -> int:
@@ -571,17 +476,6 @@ def sparse_panel(b: int, n: int, k: int, table_rows: int = 1,
     return None
 
 
-def _gain_col(v, b: int, name: str):
-    """Normalize a traced gain (scalar or per-draw vector) to (B, 1)."""
-    col = jnp.asarray(v, jnp.float32).reshape(-1)
-    if col.shape[0] == 1:
-        col = jnp.broadcast_to(col, (b,))
-    if col.shape[0] != b:
-        raise ValueError(f"{name} must be scalar or length-{b} per-draw, "
-                         f"got shape {jnp.shape(v)}")
-    return col.reshape(b, 1)
-
-
 def _lat_rows(lat_frames, b: int, c: int):
     """Normalize per-class latencies — (C,) shared or (B, C) per-draw —
     to the (B, C) traced input the fused kernels consume."""
@@ -709,24 +603,39 @@ def bittide_fused_pallas(psi, nu, nu_u, a, deg, lamsum, lat_frames,
 
     kern = functools.partial(
         _fused_kernel, dt_frames=float(dt_frames),
-        record_every=int(record_every), num_classes=int(c),
-        record_beta=bool(record_beta),
-        record_watermarks=bool(record_watermarks),
-        record_guard=bool(record_guard))
+        record_every=int(record_every), num_classes=int(c))
+    in_specs, args = _dense_inputs(
+        psi, nu, nu_u, a, deg, lamsum, lat_frames, kp, beta_off, ctrl_mask,
+        (c, n, n), lambda t: (0, 0, 0))
+    return period.launch(
+        kern, name="bittide_fused", grid=(num_records,), in_specs=in_specs,
+        args=args,
+        scratch=[pltpu.VMEM((b, n), jnp.float32),         # ψ carry
+                 pltpu.VMEM((b, n), jnp.float32)],        # ν carry
+        b=b, n=n, num_records=num_records, record_beta=record_beta,
+        record_watermarks=record_watermarks, record_guard=record_guard,
+        guard_lo=guard_lo, guard_hi=guard_hi, guard_stop=guard_stop,
+        interpret=interpret)
 
+
+def _dense_inputs(psi, nu, nu_u, a, deg, lamsum, lat_frames, kp, beta_off,
+                  ctrl_mask, a_block, a_map):
+    """In-specs and args of the dense lanes' fixed inputs: the adjacency
+    in ``a_block`` blocks at ``a_map``, everything else whole."""
+    b, n = psi.shape
+    c = a.shape[0]
     mask = _mask_row(ctrl_mask, n, b)
-    full2 = lambda t: (0, 0)
     in_specs = [
-        pl.BlockSpec((b, c), full2),                 # lat per draw
-        pl.BlockSpec((c, n, n), lambda t: (0, 0, 0)),  # A, resident
-        pl.BlockSpec((b, n), full2),                 # psi0
-        pl.BlockSpec((b, n), full2),                 # nu0
-        pl.BlockSpec((b, n), full2),                 # nu_u
-        pl.BlockSpec((b, 1), full2),                 # kp per draw
-        pl.BlockSpec((b, 1), full2),                 # beta_off per draw
-        pl.BlockSpec((mask.shape[0], n), full2),     # ctrl mask
-        pl.BlockSpec((1, n), full2),                 # deg
-        pl.BlockSpec((b, n), full2),                 # lamsum per draw
+        pl.BlockSpec((b, c), period.whole),               # lat per draw
+        pl.BlockSpec(a_block, a_map),                     # A
+        pl.BlockSpec((b, n), period.whole),               # psi0
+        pl.BlockSpec((b, n), period.whole),               # nu0
+        pl.BlockSpec((b, n), period.whole),               # nu_u
+        pl.BlockSpec((b, 1), period.whole),               # kp per draw
+        pl.BlockSpec((b, 1), period.whole),               # beta_off
+        pl.BlockSpec((mask.shape[0], n), period.whole),   # ctrl mask
+        pl.BlockSpec((1, n), period.whole),               # deg
+        pl.BlockSpec((b, n), period.whole),               # lamsum per draw
     ]
     args = [_lat_rows(lat_frames, b, c), a.astype(jnp.float32),
             psi.astype(jnp.float32), nu.astype(jnp.float32),
@@ -734,86 +643,7 @@ def bittide_fused_pallas(psi, nu, nu_u, a, deg, lamsum, lat_frames,
             _gain_col(beta_off, b, "beta_off"), mask,
             deg.reshape(1, n).astype(jnp.float32),
             _lamsum_rows(lamsum, b, n)]
-    if record_guard:
-        in_specs += [pl.BlockSpec((b, 1), full2),    # guard band lo
-                     pl.BlockSpec((b, 1), full2),    # guard band hi
-                     pl.BlockSpec((b, 1), full2)]    # stop-after record
-        args += _guard_cols(guard_lo, guard_hi, guard_stop, b)
-    out_specs = [
-        pl.BlockSpec((b, n), full2),                     # psi final
-        pl.BlockSpec((b, n), full2),                     # nu final
-        pl.BlockSpec((1, b, n), lambda t: (t, 0, 0)),    # ν record t
-    ]
-    out_shape = [
-        jax.ShapeDtypeStruct((b, n), jnp.float32),
-        jax.ShapeDtypeStruct((b, n), jnp.float32),
-        jax.ShapeDtypeStruct((num_records, b, n), jnp.float32),
-    ]
-    if record_beta:
-        out_specs.append(pl.BlockSpec((1, b, n), lambda t: (t, 0, 0)))
-        out_shape.append(
-            jax.ShapeDtypeStruct((num_records, b, n), jnp.float32))
-    if record_watermarks:
-        # Four (B, N) watermark accumulators with constant index maps:
-        # |β| max, its record index, ν min, ν max.
-        for dt_ in (jnp.float32, jnp.int32, jnp.float32, jnp.float32):
-            out_specs.append(pl.BlockSpec((b, n), full2))
-            out_shape.append(jax.ShapeDtypeStruct((b, n), dt_))
-    if record_guard:
-        # (B, 1) first-trip record index, constant index map (stays in
-        # VMEM across the grid; flushed once at the end).
-        out_specs.append(pl.BlockSpec((b, 1), full2))
-        out_shape.append(jax.ShapeDtypeStruct((b, 1), jnp.int32))
-    out = pl.pallas_call(
-        kern,
-        name="bittide_fused",
-        grid=(num_records,),
-        in_specs=in_specs,
-        out_specs=out_specs,
-        out_shape=out_shape,
-        scratch_shapes=[
-            pltpu.VMEM((b, n), jnp.float32),             # ψ carry
-            pltpu.VMEM((b, n), jnp.float32),             # ν carry
-        ],
-        compiler_params=COMPILER_PARAMS,
-        interpret=interpret,
-    )(*args)
-    return _split_outputs(out, record_beta, record_watermarks, record_guard)
-
-
-def _guard_cols(guard_lo, guard_hi, guard_stop, b: int):
-    """Normalize the traced guard inputs to the (B, 1) columns the
-    kernels consume: f32 band edges + i32 stop-after record index."""
-    if guard_lo is None or guard_hi is None or guard_stop is None:
-        raise ValueError(
-            "record_guard=True requires guard_lo, guard_hi and guard_stop")
-    stop = jnp.asarray(guard_stop, jnp.int32).reshape(-1)
-    if stop.shape[0] == 1:
-        stop = jnp.broadcast_to(stop, (b,))
-    if stop.shape[0] != b:
-        raise ValueError(f"guard_stop must be scalar or length-{b}, "
-                         f"got shape {jnp.shape(guard_stop)}")
-    return [_gain_col(guard_lo, b, "guard_lo"),
-            _gain_col(guard_hi, b, "guard_hi"), stop.reshape(b, 1)]
-
-
-def _split_outputs(out, record_beta: bool, record_watermarks: bool,
-                   record_guard: bool = False):
-    """:class:`EngineOutputs` from the flat pallas_call output list —
-    shared by every fused-engine wrapper."""
-    i = 3
-    brec = wm = trip = None
-    if record_beta:
-        brec = out[i]
-        i += 1
-    if record_watermarks:
-        wm = tuple(out[i:i + 4])
-        i += 4
-    if record_guard:
-        trip = out[i]
-        i += 1
-    return EngineOutputs(psi=out[0], nu=out[1], freq=out[2], beta=brec,
-                         watermarks=wm, guard_state=trip)
+    return in_specs, args
 
 
 def _tiled_kernel(lat_ref, a_ref, psi0_ref, nu0_ref, nu_u_ref, kp_ref,
@@ -825,36 +655,12 @@ def _tiled_kernel(lat_ref, a_ref, psi0_ref, nu0_ref, nu_u_ref, kp_ref,
     p = pl.program_id(1)
     j = pl.program_id(2)
     j_tiles = pl.num_programs(2)
-    # With β recording (watermarks, or the in-kernel guard) the period
-    # axis carries one extra trailing pass per record: p < periods
-    # advances the state, p == periods re-streams the panels once more to
-    # aggregate the POST-update state's occupancy.
     measure = record_beta or record_watermarks or record_guard
-    periods = pl.num_programs(1) - (1 if measure else 0)
-
-    refs = list(rest)
-    if record_guard:
-        glo_ref, ghi_ref, stop_ref = refs[:3]
-        refs = refs[3:]
-    psi_out_ref, nu_out_ref, rec_ref = refs[:3]
-    refs = refs[3:]
-    brec_ref = refs.pop(0) if record_beta else None
-    if record_watermarks:
-        wm_beta_ref, wm_idx_ref, wm_lo_ref, wm_hi_ref = refs[:4]
-        refs = refs[4:]
-    trip_ref = refs.pop(0) if record_guard else None
-    psi_s, nu_s, acc_s = refs
-
-    first = jnp.logical_and(t == 0, jnp.logical_and(p == 0, j == 0))
-
-    @pl.when(first)
-    def _seed():
-        psi_s[...] = psi0_ref[...]
-        nu_s[...] = nu0_ref[...]
-        if record_guard:
-            # "Never tripped" sentinel: num_records, one past any record.
-            trip_ref[...] = jnp.full(trip_ref.shape, pl.num_programs(0),
-                                     jnp.int32)
+    periods = period.measure_periods(measure)
+    r = period.unpack(rest, record_beta, record_watermarks, record_guard)
+    psi_s, nu_s, acc_s = r.scratch
+    period.seed(r, psi0_ref, nu0_ref,
+                jnp.logical_and(t == 0, jnp.logical_and(p == 0, j == 0)))
 
     def _step():
         # Partial aggregation over this j panel: columns [j·TJ, (j+1)·TJ).
@@ -866,12 +672,8 @@ def _tiled_kernel(lat_ref, a_ref, psi0_ref, nu0_ref, nu_u_ref, kp_ref,
         nu_j = nu_s[:, cols]
         lat = lat_ref[...]                                    # (B, C)
         if measure:
-            # β pass: center ψ by its mean (β is exactly shift-invariant;
-            # the centering keeps float32 partial sums O(ψ spread)).  The
-            # mean is over the full scratch row, so every panel of the
-            # pass — and every engine — subtracts the same constant.
-            m = jnp.mean(psi_s[...], axis=1, keepdims=True)   # (B, 1)
-            psi_j = jnp.where(p == periods, psi_j - m, psi_j)
+            m = period.row_mean(psi_s)                        # (B, 1)
+            psi_j = period.centre(psi_j, m, p == periods)
         partial = jnp.zeros(psi_s.shape, jnp.float32)
         for c in range(num_classes):
             x = psi_j - nu_j * lat[:, c:c + 1]
@@ -894,77 +696,29 @@ def _tiled_kernel(lat_ref, a_ref, psi0_ref, nu0_ref, nu_u_ref, kp_ref,
         # step.
         @pl.when(jnp.logical_and(j == j_tiles - 1, p < periods))
         def _finalize():
-            psi = psi_s[...]
-            nu = nu_s[...]
-            nu_u = nu_u_ref[...]
-            err = (acc_s[...] - (psi + boff_ref[...]) * deg_ref[...]
-                   + lamsum_ref[...])
-            c_rel = kp_ref[...] * err
-            nu_next = nu_u + c_rel + nu_u * c_rel
-            # Holdover: masked-out nodes freeze ν at its previous value.
-            nu_next = jnp.where(mask_ref[...] > 0.5, nu_next, nu)
-            psi_next = psi + nu_next * dt_frames
+            psi_next, nu_next = period.control(
+                acc_s, psi_s[...], nu_s[...], nu_u_ref[...], kp_ref,
+                boff_ref, deg_ref, lamsum_ref, mask_ref, dt_frames)
             psi_s[...] = psi_next
             nu_s[...] = nu_next
             # Telemetry flushes to HBM when the record index t advances,
             # so overwriting every period within a record is decimation
             # for free.
-            rec_ref[...] = nu_next[None]
-            psi_out_ref[...] = psi_next
-            nu_out_ref[...] = nu_next
+            r.rec[...] = nu_next[None]
+            r.psi_out[...] = psi_next
+            r.nu_out[...] = nu_next
 
         if measure:
-            # Last panel of the β pass: the accumulator now holds the
-            # full aggregation of the record's post-update state.
-            last_beta_panel = jnp.logical_and(j == j_tiles - 1,
-                                              p == periods)
-
-            @pl.when(last_beta_panel)
+            # Last panel of the measure pass: the accumulator now holds
+            # the full aggregation of the record's post-update state.
+            @pl.when(jnp.logical_and(j == j_tiles - 1, p == periods))
             def _record_beta():
                 bnode = (acc_s[...]
                          - (psi_s[...] - m) * deg_ref[...]
                          + lamsum_ref[...])
-                if record_beta:
-                    brec_ref[...] = bnode[None]
-                if record_watermarks:
-                    babs = jnp.abs(bnode)
-                    nu = nu_s[...]
+                period.measure(r, bnode, nu_s, t, deg_ref)
 
-                    @pl.when(t == 0)
-                    def _wm_seed():
-                        wm_beta_ref[...] = babs
-                        wm_idx_ref[...] = jnp.zeros_like(babs, jnp.int32)
-                        wm_lo_ref[...] = nu
-                        wm_hi_ref[...] = nu
-
-                    @pl.when(t > 0)
-                    def _wm_update():
-                        wm_idx_ref[...] = jnp.where(babs > wm_beta_ref[...],
-                                                    t, wm_idx_ref[...])
-                        wm_beta_ref[...] = jnp.maximum(wm_beta_ref[...],
-                                                       babs)
-                        wm_lo_ref[...] = jnp.minimum(wm_lo_ref[...], nu)
-                        wm_hi_ref[...] = jnp.maximum(wm_hi_ref[...], nu)
-                if record_guard:
-                    # Degree-scaled band check — see _fused_kernel.
-                    viol = jnp.logical_or(
-                        bnode > ghi_ref[...] * deg_ref[...],
-                        bnode < glo_ref[...] * deg_ref[...])
-                    row_viol = jnp.any(viol, axis=1, keepdims=True)
-                    trip_ref[...] = jnp.where(row_viol, t, trip_ref[...])
-
-    if record_guard:
-        # Chunk early-exit: freeze every grid step of records after the
-        # earliest trip (or past the host's stop_after cap).  min(trip)
-        # ≥ t keeps the trip record itself fully processed.
-        live = jnp.logical_and(jnp.min(trip_ref[...]) >= t,
-                               t <= stop_ref[0, 0])
-
-        @pl.when(live)
-        def _run():
-            _step()
-    else:
-        _step()
+    period.run(r, t, _step)
 
 
 def bittide_tiled_fused_pallas(psi, nu, nu_u, a, deg, lamsum, lat_frames,
@@ -1018,74 +772,22 @@ def bittide_tiled_fused_pallas(psi, nu, nu_u, a, deg, lamsum, lat_frames,
 
     kern = functools.partial(
         _tiled_kernel, dt_frames=float(dt_frames), tile_j=int(tile_j),
-        num_classes=int(c), record_beta=bool(record_beta),
-        record_watermarks=bool(record_watermarks),
-        record_guard=bool(record_guard))
-
-    mask = _mask_row(ctrl_mask, n, b)
-    full3 = lambda t, p, j: (0, 0)
-    in_specs = [
-        pl.BlockSpec((b, c), full3),                   # lat per draw
-        # A column panel: the index map advances with j, so the Pallas
-        # pipeline double-buffers the HBM fetch of panel j+1 behind the
-        # matmul on panel j.
-        pl.BlockSpec((c, n, tile_j), lambda t, p, j: (0, 0, j)),
-        pl.BlockSpec((b, n), full3),                   # psi0
-        pl.BlockSpec((b, n), full3),                   # nu0
-        pl.BlockSpec((b, n), full3),                   # nu_u
-        pl.BlockSpec((b, 1), full3),                   # kp per draw
-        pl.BlockSpec((b, 1), full3),                   # beta_off
-        pl.BlockSpec((mask.shape[0], n), full3),       # ctrl mask
-        pl.BlockSpec((1, n), full3),                   # deg
-        pl.BlockSpec((b, n), full3),                   # lamsum per draw
-    ]
-    args = [_lat_rows(lat_frames, b, c), a.astype(jnp.float32),
-            psi.astype(jnp.float32), nu.astype(jnp.float32),
-            nu_u.astype(jnp.float32), _gain_col(kp, b, "kp"),
-            _gain_col(beta_off, b, "beta_off"), mask,
-            deg.reshape(1, n).astype(jnp.float32),
-            _lamsum_rows(lamsum, b, n)]
-    if record_guard:
-        in_specs += [pl.BlockSpec((b, 1), full3),      # guard band lo
-                     pl.BlockSpec((b, 1), full3),      # guard band hi
-                     pl.BlockSpec((b, 1), full3)]      # stop-after record
-        args += _guard_cols(guard_lo, guard_hi, guard_stop, b)
-    out_specs = [
-        pl.BlockSpec((b, n), full3),                     # psi final
-        pl.BlockSpec((b, n), full3),                     # nu final
-        pl.BlockSpec((1, b, n), lambda t, p, j: (t, 0, 0)),  # ν record
-    ]
-    out_shape = [
-        jax.ShapeDtypeStruct((b, n), jnp.float32),
-        jax.ShapeDtypeStruct((b, n), jnp.float32),
-        jax.ShapeDtypeStruct((num_records, b, n), jnp.float32),
-    ]
-    if record_beta:
-        out_specs.append(pl.BlockSpec((1, b, n), lambda t, p, j: (t, 0, 0)))
-        out_shape.append(
-            jax.ShapeDtypeStruct((num_records, b, n), jnp.float32))
-    if record_watermarks:
-        for dt_ in (jnp.float32, jnp.int32, jnp.float32, jnp.float32):
-            out_specs.append(pl.BlockSpec((b, n), full3))
-            out_shape.append(jax.ShapeDtypeStruct((b, n), dt_))
-    if record_guard:
-        out_specs.append(pl.BlockSpec((b, 1), full3))
-        out_shape.append(jax.ShapeDtypeStruct((b, 1), jnp.int32))
+        num_classes=int(c))
+    # A column panel: the index map advances with j, so the Pallas
+    # pipeline double-buffers the HBM fetch of panel j+1 behind the
+    # matmul on panel j.
+    in_specs, args = _dense_inputs(
+        psi, nu, nu_u, a, deg, lamsum, lat_frames, kp, beta_off, ctrl_mask,
+        (c, n, tile_j), lambda t, p, j: (0, 0, j))
     measure = record_beta or record_watermarks or record_guard
-    out = pl.pallas_call(
-        kern,
-        name="bittide_tiled",
-        grid=(num_records, record_every + (1 if measure else 0),
-              j_tiles),
-        in_specs=in_specs,
-        out_specs=out_specs,
-        out_shape=out_shape,
-        scratch_shapes=[
-            pltpu.VMEM((b, n), jnp.float32),               # ψ carry
-            pltpu.VMEM((b, n), jnp.float32),               # ν carry
-            pltpu.VMEM((b, n), jnp.float32),               # err accumulator
-        ],
-        compiler_params=COMPILER_PARAMS,
-        interpret=interpret,
-    )(*args)
-    return _split_outputs(out, record_beta, record_watermarks, record_guard)
+    return period.launch(
+        kern, name="bittide_tiled",
+        grid=(num_records, record_every + (1 if measure else 0), j_tiles),
+        in_specs=in_specs, args=args,
+        scratch=[pltpu.VMEM((b, n), jnp.float32),         # ψ carry
+                 pltpu.VMEM((b, n), jnp.float32),         # ν carry
+                 pltpu.VMEM((b, n), jnp.float32)],        # err accumulator
+        b=b, n=n, num_records=num_records, record_beta=record_beta,
+        record_watermarks=record_watermarks, record_guard=record_guard,
+        guard_lo=guard_lo, guard_hi=guard_hi, guard_stop=guard_stop,
+        interpret=interpret)

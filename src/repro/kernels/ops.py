@@ -74,6 +74,7 @@ from .bittide_sparse import bittide_sparse_pallas, ellify, max_in_degree
 from .bittide_step import (SUBLANE, TILE, bittide_fused_pallas,
                            bittide_step_pallas, bittide_tiled_fused_pallas,
                            select_engine, sparse_panel)
+from .period import fold_watermarks, live, never_tripped, out_of_band
 from .ref import (bittide_dense_multistep_ref, bittide_dense_step_ref,
                   node_occupancy_ref)
 
@@ -471,27 +472,18 @@ def _perstep_engine(psi, nu, nu_u, ctrl_mask, a, lam_eff, lat, kp, beta_off,
         psi_t, nu_t = state
         bnode = measure(psi_t, nu_t) if measure_pass else None
         if record_watermarks:
-            # Running aggregates in the scan carry, from the SAME
-            # in-kernel β measurement the record lane emits.  Strict >
-            # (seeded at -inf) keeps the FIRST record attaining the max.
-            babs = jnp.abs(bnode)
-            bmax, idx, lo, hi = wm
-            wm = (jnp.maximum(bmax, babs),
-                  jnp.where(babs > bmax, t_idx, idx),
-                  jnp.minimum(lo, nu_t), jnp.maximum(hi, nu_t))
+            # Running aggregates in the scan carry (seeded at ∓inf), from
+            # the SAME in-kernel β measurement the record lane emits.
+            wm = fold_watermarks(wm, jnp.abs(bnode), nu_t, t_idx)
         if record_guard:
-            # Degree-scaled band check, same criterion as the Pallas
-            # lanes (strict inequalities keep degree-0 padding inert).
-            viol = jnp.any(jnp.logical_or(bnode > guard_hi * deg,
-                                          bnode < guard_lo * deg))
+            viol = jnp.any(out_of_band(bnode, guard_lo, guard_hi, deg))
             trip = jnp.where(viol, t_idx, trip)
         return (state, wm, trip) + ((bnode,) if record_beta else ())
 
     def record(carry, t_idx):
         state, wm, trip = carry
         if record_guard:
-            live = jnp.logical_and(trip >= num_records,
-                                   t_idx <= guard_stop)
+            live_t = live(trip, t_idx, guard_stop)
 
             def frozen():
                 # Early-exit no-op: carry the frozen state through (the
@@ -503,7 +495,7 @@ def _perstep_engine(psi, nu, nu_u, ctrl_mask, a, lam_eff, lat, kp, beta_off,
                 return out
 
             res = jax.lax.cond(
-                live, lambda: step_record(state, wm, trip, t_idx), frozen)
+                live_t, lambda: step_record(state, wm, trip, t_idx), frozen)
         else:
             res = step_record(state, wm, trip, t_idx)
         if record_beta:
@@ -520,7 +512,7 @@ def _perstep_engine(psi, nu, nu_u, ctrl_mask, a, lam_eff, lat, kp, beta_off,
             jnp.full((n_p,), jnp.inf, jnp.float32),
             jnp.full((n_p,), -jnp.inf, jnp.float32))
            if record_watermarks else ())
-    trip0 = (jnp.asarray(num_records, jnp.int32) if record_guard
+    trip0 = (never_tripped((), num_records) if record_guard
              else jnp.int32(0))
     ((psi, nu), wm, trip), rec = jax.lax.scan(
         record, ((psi, nu), wm0, trip0),

@@ -49,6 +49,15 @@ Engines:
                   (:func:`_build_sparse_tables`), the sparse analogue of
                   the dense stack builder.
 
+Every kernel lane — fused, tiled, per-step, sparse — runs through ONE
+chunk loop.  Each segment is prepped into a lane value (``_Lane``: its
+label and tile, its ``engine_dispatch`` fields, and its chunk launch);
+the loop owns the rest once: the stop cap, the ``chunk`` spans, the
+read-back, the watermark merge, the guard evaluation and the rotation
+with its re-prep.  The batched lanes launch every draw at once and read
+the chunk back in one transfer; the per-step lane launches each draw on
+its own and resyncs them on the host after a guard trip.
+
 β splicing: occupancy is a pure function of the threaded (ψ, ν, λeff)
 state in relative coordinates, so dense β telemetry splices across
 segment boundaries exactly like ψ/ν — bit-identically for a no-event
@@ -112,7 +121,7 @@ from __future__ import annotations
 
 import dataclasses
 import functools
-from typing import List, Optional
+from typing import Callable, List, NamedTuple, Optional
 
 import numpy as np
 
@@ -779,6 +788,16 @@ def _prep_dense_segment(topo: Topology, links_seg: LinkParams, seg, comp,
             b_pad, n_pad)
 
 
+class _Lane(NamedTuple):
+    """One segment prepped on a kernel lane, as the chunk loop runs it."""
+    engine: str        # the lane that runs: the result's and spans' label
+    tile: int          # its panel width: tile_j, or tile_i on sparse
+    dispatch: dict     # the engine_dispatch event's fields
+    b_pad: int
+    nu_u: object       # (B_pad, N_pad) ν_u on the device: the first ν
+    run: Callable      # (ψ, ν, stop) → (ψ, ν, trips, t*, valid, freq, β, wm)
+
+
 def run_scenario(topo: Topology, links: LinkParams, ctrl: ControllerConfig,
                  ppm_u: np.ndarray, scenario: Scenario,
                  cfg: SimConfig = SimConfig(),
@@ -1023,7 +1042,7 @@ def _run_scenario(topo: Topology, links: LinkParams, ctrl: ControllerConfig,
     rec_done, total = 0, comp.total_records
     eng_label, tile_j = engine, 0
     # All segments' dense adjacency stacks / sparse slot tables, built
-    # once (the chunk loops never re-densify A or re-scatter slots).
+    # once (the chunk loop never re-densifies A or re-scatter slots).
     stacks = tables = None
     if dense or sparse:
         with tr.span("segment.stacks"):
@@ -1046,6 +1065,132 @@ def _run_scenario(topo: Topology, links: LinkParams, ctrl: ControllerConfig,
             psi_now, nu_now = _fetch_unpadded(tr, (psi_pad, nu_pad), b, n)
             return (psi_now[0], nu_now[0]) if single else (psi_now, nu_now)
         return state.psi, state.nu
+
+    dt_frames = float(cfg.omega_nom * cfg.dt)
+
+    def guard_kw(stop):
+        """The in-kernel guard's traced inputs for a chunk capped at
+        record ``stop``."""
+        return dict(record_guard=guard_on,
+                    guard_lo=gband[0] if guard_on else None,
+                    guard_hi=gband[1] if guard_on else None,
+                    guard_stop=stop if guard_on else None)
+
+    def batched(launch):
+        """The chunk of a lane that launches every draw at once: launch,
+        wait, and read the chunk back in one transfer."""
+        def run(psi, nu, stop):
+            with tr.span("chunk.dispatch"):
+                out = launch(psi, nu, stop)
+            with tr.span("chunk.wait"):
+                jax.block_until_ready(out)
+            with tr.span("chunk.fetch"):
+                return (out.psi, out.nu) + _read_chunk(tr, out, b, n, chunk,
+                                                       stop)
+        return run
+
+    def prep_lane(si, seg, links_seg, ppm_seg) -> _Lane:
+        """Segment ``si`` prepped on the kernel lane."""
+        ppm2d = np.atleast_2d(ppm_seg)
+        if sparse:
+            (latf_j, w_j, lamsum_j, mask_j, nu_u_j, kp_j, boff_j, ti, b_pad,
+             n_pad) = _prep_sparse_segment(topo, links_seg, seg, ctrl, ppm2d,
+                                           cfg, tables, si, variant, tr)
+
+            def launch_sparse(psi, nu, stop):
+                return _sparse_engine(
+                    psi, nu, nu_u_j, kp_j, boff_j, mask_j, tables.nbr, latf_j,
+                    w_j, lamsum_j, dt_frames, int(chunk),
+                    int(cfg.record_every), int(ti), interp, rb_dense, rw,
+                    **guard_kw(stop))
+
+            fields = dict(engine="sparse", tile_i=int(ti), b_pad=int(b_pad),
+                          n_pad=int(n_pad), k=int(tables.k),
+                          vmem_est_bytes=sparse_vmem_bytes(
+                              b_pad, n_pad, tables.k, ti,
+                              max(latf_j.shape[0], w_j.shape[0]), **variant))
+            return _Lane("sparse", ti, fields, b_pad, nu_u_j,
+                         batched(launch_sparse))
+        (a, lam_list, lamsum_j, lat_j, mask_j, nu_u_j, kp_j, boff_j, chosen,
+         tj, b_pad, n_pad) = _prep_dense_segment(
+            topo, links_seg, seg, comp, ctrl, ppm2d, cfg, engine, stacks, si,
+            variant, tr)
+        c_stack = int(a.shape[0])
+        if chosen == "fused":
+            vmem_est = fused_vmem_bytes(b_pad, n_pad, c_stack, **variant)
+        elif chosen == "tiled":
+            vmem_est = tiled_vmem_bytes(b_pad, n_pad, c_stack, tj, **variant)
+        else:   # per-step: one double-buffered (C, TILE, TILE) tile
+            vmem_est = 2 * 4 * c_stack * TILE * TILE
+        fields = dict(engine=chosen, tile_j=int(tj), b_pad=int(b_pad),
+                      n_pad=int(n_pad), c=c_stack,
+                      vmem_est_bytes=int(vmem_est))
+        if chosen != "per-step":
+            def launch_dense(psi, nu, stop):
+                return _fused_engine(
+                    psi, nu, nu_u_j, kp_j, boff_j, mask_j, a, lam_list[0],
+                    lamsum_j, lat_j, dt_frames, int(chunk),
+                    int(cfg.record_every), chosen, int(tj), interp, False,
+                    rb_dense, rw, **guard_kw(stop))
+
+            return _Lane(chosen, tj, fields, b_pad, nu_u_j,
+                         batched(launch_dense))
+        # The per-step lane launches each draw on its own, with its gains
+        # as compile keys read back once a segment.
+        kp_np = _fetch(tr, kp_j)
+        boff_np = _fetch(tr, boff_j)
+
+        def run_perstep(psi, nu, stop):
+            def launch(bi, stop_i):
+                return _perstep_engine(
+                    psi[bi], nu[bi], nu_u_j[bi],
+                    mask_j[bi] if mask_j.ndim == 2 else mask_j, a,
+                    lam_list[bi], lat_j[bi], float(kp_np[bi]),
+                    float(boff_np[bi]), dt_frames, int(chunk),
+                    int(cfg.record_every), interp, False, rb_dense, rw,
+                    record_guard=guard_on,
+                    guard_lo=(float(policy.target - guard_rows[bi])
+                              if guard_on else None),
+                    guard_hi=(float(policy.target + guard_rows[bi])
+                              if guard_on else None),
+                    guard_stop=stop_i if guard_on else None)
+
+            with tr.span("chunk.dispatch"):
+                rows = [launch(bi, stop) for bi in range(b)]
+            with tr.span("chunk.wait"):
+                jax.block_until_ready(rows)
+            trips, tstar = None, chunk
+            if guard_on:
+                with tr.span("chunk.fetch"):
+                    trips = np.array([int(_fetch(tr, r.guard_state))
+                                      for r in rows])
+                tstar = int(trips.min())
+            if guard_on and tstar <= stop and bool((trips > tstar).any()):
+                # This lane launches draws separately, so the Pallas
+                # lanes' global batch freeze needs a host resync: re-run
+                # the draws that ran past the earliest trip with the stop
+                # cap AT that record — the deterministic prefix lands
+                # their state exactly there, through the same executable
+                # (the cap is traced).
+                with tr.span("chunk.dispatch"):
+                    for bi in np.flatnonzero(trips > tstar):
+                        rows[int(bi)] = launch(int(bi), int(tstar))
+                with tr.span("chunk.wait"):
+                    jax.block_until_ready(rows)
+            valid = min(tstar, stop) + 1
+            with tr.span("chunk.fetch"):
+                psi = psi.at[:b].set(jnp.stack([r.psi for r in rows]))
+                nu = nu.at[:b].set(jnp.stack([r.nu for r in rows]))
+                freq = np.stack([_fetch(tr, r.freq)[:valid, :n]
+                                 for r in rows]) * 1e6
+                beta = (np.stack([_fetch(tr, r.beta)[:valid, :n]
+                                  for r in rows]) if rb_dense else None)
+                wm = (Watermarks.stack([_fetch_watermarks(
+                    tr, r.watermarks, valid, None, n) for r in rows])
+                    if rw else None)
+            return psi, nu, trips, tstar, valid, freq, beta, wm
+
+        return _Lane(chosen, tj, fields, b_pad, nu_u_j, run_perstep)
 
     for si, seg in enumerate(comp.segments):
         lat_frames = np.asarray(seg.latency_s, np.float64) * cfg.omega_nom
@@ -1116,218 +1261,34 @@ def _run_scenario(topo: Topology, links: LinkParams, ctrl: ControllerConfig,
                 est = np.abs(pot[..., src_np] - pot[..., dst_np])
                 return np.atleast_2d(est.max(axis=-1))
 
-        if sparse:
-            # Sparse ELL lane: same once-per-segment prep / chunk-replay
-            # split as the dense lanes, but the traced tables are the
-            # precomputed slot tables — per-draw weights and fully
-            # heterogeneous per-draw latencies included.
+        if dense or sparse:
+            # Segment prep — λeff folds, padding, stack or table lookup —
+            # happens ONCE per segment; the chunk loop below replays the
+            # jitted engine on device-resident padded state with zero host
+            # rebuilds (A and the slot tables were built before the
+            # segment loop).
             with tr.span("segment.prep", segment=si):
-                (latf_j, w_j, lamsum_j, mask_j, nu_u_j, kp_j, boff_j, ti,
-                 b_pad, n_pad) = _prep_sparse_segment(
-                    topo, links_seg, seg, ctrl, np.atleast_2d(ppm_seg), cfg,
-                    tables, si, variant, tr)
+                lane = prep_lane(si, seg, links_seg, ppm_seg)
                 if psi_pad is None:
-                    psi_pad, nu_pad = jnp.zeros_like(nu_u_j), nu_u_j
-            eng_label, tile_j = "sparse", ti
-            tr.event("engine_dispatch", segment=si, engine="sparse",
-                     tile_i=int(ti), b_pad=int(b_pad), n_pad=int(n_pad),
-                     k=int(tables.k),
-                     vmem_est_bytes=sparse_vmem_bytes(
-                         b_pad, n_pad, tables.k, ti,
-                         max(latf_j.shape[0], w_j.shape[0]), **variant))
-            dt_frames = float(cfg.omega_nom * cfg.dt)
+                    psi_pad, nu_pad = jnp.zeros_like(lane.nu_u), lane.nu_u
+            eng_label, tile_j = lane.engine, lane.tile
+            tr.event("engine_dispatch", segment=si, **lane.dispatch)
             if guard_on and gband is None:
                 with tr.span("guard", name="band"):
-                    gband = _guard_band_cols(b_pad, b, policy.target,
+                    gband = _guard_band_cols(lane.b_pad, b, policy.target,
                                              guard_rows, tr)
             seg_done = 0
             while seg_done < seg.records:
                 # Traced stop cap: a post-splice partial chunk keeps the
                 # static num_records and no-ops its tail — zero recompiles.
                 stop = min(chunk, seg.records - seg_done) - 1
-                with tr.span("chunk", engine="sparse", segment=si,
+                with tr.span("chunk", engine=lane.engine, segment=si,
                              launch=launches, records=int(stop + 1)):
-                    with tr.span("chunk.dispatch"):
-                        out = _sparse_engine(
-                            psi_pad, nu_pad, nu_u_j, kp_j, boff_j, mask_j,
-                            tables.nbr, latf_j, w_j, lamsum_j, dt_frames,
-                            int(chunk), int(cfg.record_every), int(ti),
-                            interp, rb_dense, rw, record_guard=guard_on,
-                            guard_lo=gband[0] if guard_on else None,
-                            guard_hi=gband[1] if guard_on else None,
-                            guard_stop=stop if guard_on else None)
-                    with tr.span("chunk.wait"):
-                        jax.block_until_ready(out)
-                    with tr.span("chunk.fetch"):
-                        psi_pad, nu_pad = out.psi, out.nu
-                        (trips, tstar, valid, freq_c, beta_c,
-                         wm_c) = _read_chunk(tr, out, b, n, chunk, stop)
-                        freq_chunks.append(freq_c)
-                        if rb_dense:
-                            beta_chunks.append(beta_c)
-                if rw:
-                    wm_acc = wm_c if wm_acc is None else wm_acc.merge(wm_c)
-                launches += 1
-                seg_done += valid
-                rec_done += valid
-                tripped_now = guard_on and tstar <= stop
-                if guard_on:
-                    tr.event("guard_eval", record=int(rec_done),
-                             guard=float(guard_rows.min()),
-                             tripped=(int(np.count_nonzero(trips == tstar))
-                                      if tripped_now else 0))
-                if tripped_now and rec_done < total:
-                    # Same per-draw trip + rotation as the dense lanes
-                    # (the in-kernel measurement is the identical
-                    # per-node net occupancy quantity).
-                    with tr.span("reframe", record=int(rec_done), auto=True,
-                                 segment=si):
-                        psi_now, nu_now = live_state()
-                        lam_eff, shift = _rotation_shifts(
-                            topo, lam_eff, psi_now, nu_now, lat_frames,
-                            seg.edge_w, "graph", policy.target,
-                            lap_pinv=lap_pinv, rows_mask=(trips == tstar))
-                        reframes.append(AppliedReframe(
-                            record=rec_done, time=rec_done * rec_period,
-                            shift=shift, auto=True, guard_latency=1))
-                        tr.note(max_shift=int(np.abs(shift).max()))
-                        if seg_done < seg.records:
-                            links_seg = LinkParams(
-                                latency_s=seg.latency_s,
-                                beta0=np.array(lam_eff, copy=True))
-                            (latf_j, w_j, lamsum_j, mask_j, nu_u_j, kp_j,
-                             boff_j, ti, b_pad, n_pad) = \
-                                _prep_sparse_segment(
-                                    topo, links_seg, seg, ctrl,
-                                    np.atleast_2d(ppm_seg), cfg, tables,
-                                    si, variant, tr)
-            continue
-
-        if dense:
-            # Segment prep — λeff folds, padding, stack lookup — happens
-            # ONCE per segment; the chunk loop below replays the jitted
-            # engine on device-resident padded state with zero host
-            # rebuilds (A was densified before the segment loop).
-            with tr.span("segment.prep", segment=si):
-                (a, lam_list, lamsum_j, lat_j, mask_j, nu_u_j, kp_j,
-                 boff_j, chosen, tj, b_pad, n_pad) = _prep_dense_segment(
-                    topo, links_seg, seg, comp, ctrl,
-                    np.atleast_2d(ppm_seg), cfg, engine, stacks, si,
-                    variant, tr)
-                if psi_pad is None:
-                    psi_pad, nu_pad = jnp.zeros_like(nu_u_j), nu_u_j
-                if chosen == "per-step":
-                    kp_np = _fetch(tr, kp_j)
-                    boff_np = _fetch(tr, boff_j)
-            eng_label, tile_j = chosen, tj
-            c_stack = int(a.shape[0])
-            if chosen == "fused":
-                vmem_est = fused_vmem_bytes(b_pad, n_pad, c_stack, **variant)
-            elif chosen == "tiled":
-                vmem_est = tiled_vmem_bytes(b_pad, n_pad, c_stack, tj,
-                                            **variant)
-            else:   # per-step: one double-buffered (C, TILE, TILE) tile
-                vmem_est = 2 * 4 * c_stack * TILE * TILE
-            tr.event("engine_dispatch", segment=si, engine=chosen,
-                     tile_j=int(tj), b_pad=int(b_pad), n_pad=int(n_pad),
-                     c=c_stack, vmem_est_bytes=int(vmem_est))
-            dt_frames = float(cfg.omega_nom * cfg.dt)
-            if guard_on and gband is None:
-                with tr.span("guard", name="band"):
-                    gband = _guard_band_cols(b_pad, b, policy.target,
-                                             guard_rows, tr)
-            seg_done = 0
-            while seg_done < seg.records:
-                # Traced stop cap: a post-splice partial chunk keeps the
-                # static num_records and no-ops its tail — zero recompiles.
-                stop = min(chunk, seg.records - seg_done) - 1
-                with tr.span("chunk", engine=chosen, segment=si,
-                             launch=launches, records=int(stop + 1)):
-                    if chosen == "per-step":
-                        psi_prev, nu_prev = psi_pad, nu_pad
-
-                        def launch_ps(bi, stop_i):
-                            return _perstep_engine(
-                                psi_prev[bi], nu_prev[bi], nu_u_j[bi],
-                                mask_j[bi] if mask_j.ndim == 2 else mask_j,
-                                a, lam_list[bi], lat_j[bi],
-                                float(kp_np[bi]), float(boff_np[bi]),
-                                dt_frames, int(chunk),
-                                int(cfg.record_every), interp, False,
-                                rb_dense, rw, record_guard=guard_on,
-                                guard_lo=(float(policy.target
-                                                - guard_rows[bi])
-                                          if guard_on else None),
-                                guard_hi=(float(policy.target
-                                                + guard_rows[bi])
-                                          if guard_on else None),
-                                guard_stop=stop_i if guard_on else None)
-
-                        with tr.span("chunk.dispatch"):
-                            rows = [launch_ps(bi, stop) for bi in range(b)]
-                        with tr.span("chunk.wait"):
-                            jax.block_until_ready(rows)
-                        trips, tstar = None, chunk
-                        if guard_on:
-                            with tr.span("chunk.fetch"):
-                                trips = np.array(
-                                    [int(_fetch(tr, r.guard_state))
-                                     for r in rows])
-                            tstar = int(trips.min())
-                        if guard_on and tstar <= stop \
-                                and bool((trips > tstar).any()):
-                            # This lane launches draws separately, so the
-                            # Pallas lanes' global batch freeze needs a
-                            # host resync: re-run the draws that ran past
-                            # the earliest trip with the stop cap AT that
-                            # record — the deterministic prefix lands
-                            # their state exactly there, through the same
-                            # executable (the cap is traced).
-                            with tr.span("chunk.dispatch"):
-                                for bi in np.flatnonzero(trips > tstar):
-                                    rows[int(bi)] = launch_ps(int(bi),
-                                                              int(tstar))
-                            with tr.span("chunk.wait"):
-                                jax.block_until_ready(rows)
-                        valid = min(tstar, stop) + 1
-                        with tr.span("chunk.fetch"):
-                            psi_pad = psi_pad.at[:b].set(
-                                jnp.stack([r.psi for r in rows]))
-                            nu_pad = nu_pad.at[:b].set(
-                                jnp.stack([r.nu for r in rows]))
-                            freq_chunks.append(np.stack(
-                                [_fetch(tr, r.freq)[:valid, :n]
-                                 for r in rows]) * 1e6)
-                            if rb_dense:
-                                beta_chunks.append(np.stack(
-                                    [_fetch(tr, r.beta)[:valid, :n]
-                                     for r in rows]))
-                            if rw:
-                                wm_c = Watermarks.stack(
-                                    [_fetch_watermarks(tr, r.watermarks,
-                                                       valid, None, n)
-                                     for r in rows])
-                    else:
-                        with tr.span("chunk.dispatch"):
-                            out = _fused_engine(
-                                psi_pad, nu_pad, nu_u_j, kp_j, boff_j,
-                                mask_j, a, lam_list[0], lamsum_j, lat_j,
-                                dt_frames, int(chunk),
-                                int(cfg.record_every), chosen, int(tj),
-                                interp, False, rb_dense, rw,
-                                record_guard=guard_on,
-                                guard_lo=gband[0] if guard_on else None,
-                                guard_hi=gband[1] if guard_on else None,
-                                guard_stop=stop if guard_on else None)
-                        with tr.span("chunk.wait"):
-                            jax.block_until_ready(out)
-                        with tr.span("chunk.fetch"):
-                            psi_pad, nu_pad = out.psi, out.nu
-                            (trips, tstar, valid, freq_c, beta_c,
-                             wm_c) = _read_chunk(tr, out, b, n, chunk, stop)
-                            freq_chunks.append(freq_c)
-                            if rb_dense:
-                                beta_chunks.append(beta_c)
+                    (psi_pad, nu_pad, trips, tstar, valid, freq_c, beta_c,
+                     wm_c) = lane.run(psi_pad, nu_pad, stop)
+                    freq_chunks.append(freq_c)
+                    if rb_dense:
+                        beta_chunks.append(beta_c)
                 if rw:
                     wm_acc = wm_c if wm_acc is None else wm_acc.merge(wm_c)
                 launches += 1
@@ -1366,15 +1327,7 @@ def _run_scenario(topo: Topology, links: LinkParams, ctrl: ControllerConfig,
                             links_seg = LinkParams(
                                 latency_s=seg.latency_s,
                                 beta0=np.array(lam_eff, copy=True))
-                            (a, lam_list, lamsum_j, lat_j, mask_j, nu_u_j,
-                             kp_j, boff_j, chosen, tj, b_pad, n_pad) = \
-                                _prep_dense_segment(
-                                    topo, links_seg, seg, comp, ctrl,
-                                    np.atleast_2d(ppm_seg), cfg, engine,
-                                    stacks, si, variant, tr)
-                            if chosen == "per-step":
-                                kp_np = _fetch(tr, kp_j)
-                                boff_np = _fetch(tr, boff_j)
+                            lane = prep_lane(si, seg, links_seg, ppm_seg)
             continue
 
         tr.event("engine_dispatch", segment=si, engine="segment-sum",
