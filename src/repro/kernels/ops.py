@@ -639,19 +639,29 @@ def _per_draw_class_values(lat_frames: np.ndarray, classes: np.ndarray,
     return latv.astype(np.float32)
 
 
+def _fold_index(dst, b_rows: int, n_pad: int) -> np.ndarray:
+    """Flat (b_rows·E,) slots ``row·n_pad + dst`` of a (b_rows, E) per-edge
+    array in (b_rows, n_pad) per-node rows, row-major."""
+    return (np.arange(b_rows, dtype=np.int64)[:, None] * n_pad
+            + np.asarray(dst, np.int64)[None, :]).ravel()
+
+
 def _lamsum_host(topo: Topology, beta0: np.ndarray, edge_w, b_rows: int,
-                 n_pad: int) -> np.ndarray:
-    """Per-node λeff fold Σ_{e→i} w_e·β0_e as (b_rows, n_pad) rows."""
+                 n_pad: int, index: Optional[np.ndarray] = None) -> np.ndarray:
+    """Per-node λeff fold Σ_{e→i} w_e·β0_e as (b_rows, n_pad) rows.
+
+    One ``np.bincount`` over :func:`_fold_index` (``index``, when the
+    caller keeps it): each node adds its edges' terms in edge order in
+    float64, the order and precision of ``np.add.at``, so the fold is
+    the same to the bit."""
     w = (np.ones(topo.num_edges, np.float64) if edge_w is None
          else np.asarray(edge_w, np.float64))
     contrib = np.broadcast_to(beta0 * w, (b_rows, topo.num_edges))
-    out = np.zeros((b_rows, n_pad), np.float64)
-    rows = np.broadcast_to(np.arange(b_rows)[:, None],
-                           (b_rows, topo.num_edges))
-    dst = np.broadcast_to(np.asarray(topo.dst, np.int64)[None, :],
-                          (b_rows, topo.num_edges))
-    np.add.at(out, (rows, dst), contrib)
-    return out.astype(np.float32)
+    if index is None:
+        index = _fold_index(topo.dst, b_rows, n_pad)
+    out = np.bincount(index, weights=contrib.ravel(),
+                      minlength=b_rows * n_pad)
+    return out.reshape(b_rows, n_pad).astype(np.float32)
 
 
 def _host_watermarks(wm_dev, num_records: int, b: Optional[int],

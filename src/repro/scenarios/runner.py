@@ -49,6 +49,14 @@ Engines:
                   (:func:`_build_sparse_tables`), the sparse analogue of
                   the dense stack builder.
 
+Segment plans (``repro.scenarios.plan``): the kernel lanes keep one
+fabric's dense stacks or sparse slot tables across calls, and with them
+each segment's engine choice and padded device inputs for the last
+controller, draw count, lane and telemetry variant.  A call whose inputs
+equal the last call's by value (new draws only) does only what the draws
+change: ν_u, the re-establish splice, a per-draw λeff fold (one bincount
+over the plan's flat index) and the first state.
+
 Every kernel lane — fused, tiled, per-step, sparse — runs through ONE
 chunk loop.  Each segment is prepped into a lane value (``_Lane``: its
 label and tile, its ``engine_dispatch`` fields, and its chunk launch);
@@ -142,7 +150,7 @@ from repro.kernels.bittide_sparse import ell_tables
 from repro.kernels.bittide_step import (SUBLANE, TILE, fused_vmem_bytes,
                                        select_engine, sparse_panel,
                                        sparse_vmem_bytes, tiled_vmem_bytes)
-from repro.kernels.ops import (_auto_interpret, _fused_engine,
+from repro.kernels.ops import (_auto_interpret, _fold_index, _fused_engine,
                                _host_watermarks, _lamsum_host, _pad_batch,
                                _pad_gain, _pad_table_rows, _perstep_engine,
                                _sparse_engine, latency_classes)
@@ -150,8 +158,10 @@ from repro.telemetry import (NULL_TRACE, Watermarks, coerce_trace,
                              compile_stats)
 from repro.telemetry.api import resolve_telemetry
 
+from . import plan as plans
 from .compiler import CompiledScenario, compile_scenario
 from .events import Scenario
+from .plan import SegmentPlan, same_bits
 
 __all__ = ["AppliedReframe", "ScenarioResult", "run_scenario"]
 
@@ -484,18 +494,19 @@ def _rotation_shifts(topo: Topology, lam_eff, psi, nu, lat_frames, edge_w,
 
 
 class _DenseStacks:
-    """Per-segment dense adjacency stacks, built once per scenario run.
+    """Per-segment dense adjacency stacks, built once per segment plan.
 
     ``a[si]`` is the device-resident (C, N_pad, N_pad) float32 adjacency
     of segment ``si`` over the scenario's global latency-class axis.
     Identical parameter sets are deduped (a swap-back event reuses the
     original device buffer), so each unique stack is built on the device
-    exactly once per run however many chunks replay it.  ``lam_dummy``
-    is a shared zero (C, 1, 1) placeholder for the fused/tiled engines'
-    unused λeff argument (dead in the Pallas jaxpr — those kernels fold
-    λeff via the traced ``lamsum`` rows instead — so it only needs to
-    exist, not to be full-size; a real (C, N_pad, N_pad) zeros stack
-    would double the device footprint at Fig-18 scale for nothing).
+    exactly once per plan however many chunks and calls replay it.
+    ``lam_dummy`` is a shared zero (C, 1, 1) placeholder for the
+    fused/tiled engines' unused λeff argument (dead in the Pallas jaxpr
+    — those kernels fold λeff via the traced ``lamsum`` rows instead —
+    so it only needs to exist, not to be full-size; a real (C, N_pad,
+    N_pad) zeros stack would double the device footprint at Fig-18
+    scale for nothing).
     """
 
     def __init__(self, a: List, lam_dummy, classes, n_pad: int,
@@ -592,7 +603,7 @@ def _build_dense_stacks(topo: Topology, comp, cfg: SimConfig,
 
 
 class _SparseTables:
-    """Per-segment ELL slot tables, built once per scenario run.
+    """Per-segment ELL slot tables, built once per segment plan.
 
     The (K, N_pad) neighbor table is topology-determined and shared by
     every segment; ``latf[si]`` / ``w[si]`` are segment ``si``'s per-edge
@@ -637,49 +648,6 @@ def _build_sparse_tables(topo: Topology, comp, cfg: SimConfig,
     return _SparseTables(nbr, latf_list, w_list, n_pad)
 
 
-def _prep_sparse_segment(topo: Topology, links_seg: LinkParams, seg,
-                         ctrl: ControllerConfig, ppm2d: np.ndarray,
-                         cfg: SimConfig, tables: _SparseTables,
-                         seg_index: int, variant: dict, tr=NULL_TRACE):
-    """Host-side prep for one sparse-lane segment (once per segment).
-
-    Mirrors :func:`_prep_dense_segment`: picks up the precomputed slot
-    tables, folds λeff into traced (B_pad, N_pad) lamsum rows (per-draw
-    when re-establishment or per-draw edge weights made the fold
-    per-draw), pads gains/mask/ν_u, and fixes the node-panel width for
-    the kernel ``variant`` (its ``record_*`` flags).
-    Every returned shape is scenario-constant, so the chunk loop replays
-    one compiled engine.
-    """
-    b, n = ppm2d.shape
-    n_pad = tables.n_pad
-    beta0 = np.asarray(links_seg.beta0, np.float64)
-    w_np = np.asarray(seg.edge_w, np.float64)
-    rows_l = b if (beta0.ndim == 2 or w_np.ndim == 2) else 1
-    lamsum_rows = _lamsum_host(topo, beta0 if beta0.ndim == 2
-                               else beta0[None], w_np, rows_l, n_pad)
-    nu_u, b_pad = _pad_batch(ppm2d, n, n_pad)
-    lamsum_pad = np.zeros((b_pad, n_pad), np.float32)
-    lamsum_pad[:b] = np.broadcast_to(lamsum_rows, (b, n_pad))
-    latf_j = _pad_table_rows(tables.latf[seg_index], b_pad)
-    w_j = _pad_table_rows(tables.w[seg_index], b_pad)
-    rows_t = max(latf_j.shape[0], w_j.shape[0])
-    # Falls back to TILE and lets the kernel's own VMEM check raise.
-    ti = sparse_panel(b_pad, n_pad, tables.k, rows_t, **variant) or TILE
-    mask_np = np.asarray(seg.ctrl_mask, np.float32)
-    if mask_np.ndim == 2:
-        mask_pad = np.ones((b_pad, n_pad), np.float32)
-        mask_pad[:b, :n] = mask_np
-    else:
-        mask_pad = np.ones((n_pad,), np.float32)
-        mask_pad[:n] = mask_np
-    kp_j = _pad_gain(broadcast_gain(ctrl.kp, b), b_pad)
-    boff_j = _pad_gain(broadcast_gain(ctrl.beta_off, b, "beta_off"), b_pad)
-    tr.count("h2d_bytes", nu_u.nbytes + kp_j.nbytes + boff_j.nbytes)
-    return (latf_j, w_j, _put(tr, lamsum_pad), _put(tr, mask_pad),
-            nu_u, kp_j, boff_j, ti, b_pad, n_pad)
-
-
 def _lam_stack(topo: Topology, inv: np.ndarray, lam_eff_row, edge_w,
                c: int, n_pad: int, tr=NULL_TRACE):
     """(C, N_pad, N_pad) λeff tensor for one draw on the per-step lane.
@@ -700,40 +668,65 @@ def _lam_stack(topo: Topology, inv: np.ndarray, lam_eff_row, edge_w,
     return _put(tr, lam)
 
 
-def _prep_dense_segment(topo: Topology, links_seg: LinkParams, seg, comp,
-                        ctrl: ControllerConfig, ppm2d: np.ndarray,
-                        cfg: SimConfig, engine: str, stacks: _DenseStacks,
-                        seg_index: int, variant: dict, tr=NULL_TRACE):
-    """Host-side prep for one dense-engine segment (done once per segment).
+class _Inputs(NamedTuple):
+    """One segment's draw-independent kernel-lane inputs, kept in the
+    fabric's :class:`SegmentPlan`."""
+    engine: str        # the lane that runs: the result's and spans' label
+    tile: int          # its panel width: tile_j, or tile_i on sparse
+    dispatch: dict     # the engine_dispatch event's fields
+    b_pad: int
+    n_pad: int
+    dev: dict          # device arrays, by the engine argument they feed
+    gains: tuple       # (kp, β_off) as (B,) float32 host rows
 
-    Args:
-      links_seg: the segment's links — ``latency_s`` (E,) seconds,
-        ``beta0`` the live λeff fold, (E,) or per-draw (B, E) frames.
-      ppm2d: (B, N) per-draw unadjusted offsets (ppm) for this segment.
-      stacks / seg_index: the precomputed per-segment adjacency stacks
-        (see :class:`_DenseStacks`) — A is NOT re-densified here.
-      variant: the kernel's ``record_*`` flags — dispatch budgets the
-        telemetry buffers of the variant that runs.
 
-    Picks up the precomputed A stack, folds λeff into the traced
-    (B_pad, N_pad) lamsum rows (per-draw when re-establishment made λeff
-    per-draw), and pads gains/mask/ν_u.  The chunk loop then replays the
-    jitted engine on device-resident state with no further host work.
+def _kept(plan: SegmentPlan, key, make):
+    """``plan.shared[key]``, made by ``make()`` on first use."""
+    if key not in plan.shared:
+        plan.shared[key] = make()
+    return plan.shared[key]
 
-    Returns (a, lam_list, lamsum, lat, mask, nu_u, kp, beta_off, chosen,
-    tile_j, b_pad, n_pad); ``lam_list`` holds per-draw (C, N, N) λeff
-    tensors for the per-step engine (the shared zero placeholder on the
-    fused/tiled lanes, whose kernels fold λeff via ``lamsum`` instead).
-    """
-    b, n = ppm2d.shape
-    beta0 = np.asarray(links_seg.beta0, np.float64)
-    beta0_rows = beta0 if beta0.ndim == 2 else beta0[None]
-    a = stacks.a[seg_index]
-    n_pad = stacks.n_pad
-    classes = stacks.classes
-    c = a.shape[0]
-    nu_u, b_pad = _pad_batch(ppm2d, n, n_pad)
 
+def _common_inputs(plan: SegmentPlan, seg, ctrl: ControllerConfig, b: int,
+                   n: int, b_pad: int, n_pad: int, tr):
+    """The controller's padded mask and its kp / β_off columns on the
+    device, made once per batch key (the mask once per distinct mask), and
+    the gains as (B,) float32 host rows."""
+    mask_np = np.asarray(seg.ctrl_mask, np.float32)
+
+    def mask():
+        if mask_np.ndim == 2:
+            # Per-draw holdover victims: (B, N) → padded rows (padding
+            # rows keep the controller enabled; their state is inert).
+            pad = np.ones((b_pad, n_pad), np.float32)
+            pad[:b, :n] = mask_np
+        else:
+            pad = np.ones((n_pad,), np.float32)
+            pad[:n] = mask_np
+        return _put(tr, pad)
+
+    def gains():
+        host = (broadcast_gain(ctrl.kp, b),
+                broadcast_gain(ctrl.beta_off, b, "beta_off"))
+        tr.count("h2d_bytes", 2 * b_pad * 4)
+        return host, tuple(_pad_gain(g, b_pad) for g in host)
+
+    host, (kp_j, boff_j) = _kept(plan, "gains", gains)
+    mask_j = _kept(plan, ("mask", mask_np.shape, mask_np.tobytes()), mask)
+    return dict(mask=mask_j, kp=kp_j, boff=boff_j), host
+
+
+def _dense_inputs(plan: SegmentPlan, seg, si: int, ctrl: ControllerConfig,
+                  b: int, n: int, engine: str, variant: dict,
+                  tr=NULL_TRACE) -> _Inputs:
+    """Segment ``si``'s draw-independent dense-lane inputs: its A stack
+    (see :class:`_DenseStacks`), the engine and panel width ``variant``
+    (the kernel's ``record_*`` flags) selects, the padded latency-class
+    rows, mask and gains."""
+    stacks = plan.stacks
+    a = stacks.a[si]
+    n_pad, c = stacks.n_pad, int(a.shape[0])
+    b_pad = ((b + SUBLANE - 1) // SUBLANE) * SUBLANE
     if engine == "auto":
         chosen, tj = select_engine(b_pad, n_pad, c, **variant)
     elif engine == "per-step":
@@ -742,50 +735,82 @@ def _prep_dense_segment(topo: Topology, links_seg: LinkParams, seg, comp,
         chosen, tj = "tiled", select_engine(b_pad, n_pad, c, **variant)[1]
     else:
         chosen, tj = "fused", n_pad
+    if chosen == "fused":
+        vmem_est = fused_vmem_bytes(b_pad, n_pad, c, **variant)
+    elif chosen == "tiled":
+        vmem_est = tiled_vmem_bytes(b_pad, n_pad, c, tj, **variant)
+    else:   # per-step: one double-buffered (C, TILE, TILE) tile
+        vmem_est = 2 * 4 * c * TILE * TILE
 
-    if chosen == "per-step":
-        # The capability lane consumes the dense λeff tensor directly; its
-        # per-period kernel folds lamsum internally from it.  (Rebuilt per
-        # segment: λeff is live state under re-establishment events.)
-        inv_seg = stacks.inv[seg_index]
-        if beta0.ndim == 2:
-            lam_list = [_lam_stack(topo, inv_seg, beta0[bi], seg.edge_w,
-                                   c, n_pad, tr) for bi in range(b)]
+    def lat():
+        if stacks.class_rows is not None:
+            # Per-draw class values (chaos campaigns): draw bi's row.
+            pad = np.empty((b_pad, c), np.float32)
+            pad[:b] = stacks.class_rows
+            pad[b:] = stacks.class_rows[0]
         else:
-            lam0 = _lam_stack(topo, inv_seg, beta0_rows[0], seg.edge_w,
-                              c, n_pad, tr)
-            lam_list = [lam0] * max(b, 1)
-    else:
-        lam_list = [stacks.lam_dummy] * max(b, 1)
+            pad = np.broadcast_to(
+                np.asarray(stacks.classes, np.float32)[None, :], (b_pad, c))
+        return _put(tr, np.ascontiguousarray(pad))
 
-    lamsum_rows = _lamsum_host(topo, beta0_rows, seg.edge_w,
-                               beta0_rows.shape[0], n_pad)
-    lamsum_pad = np.zeros((b_pad, n_pad), np.float32)
-    lamsum_pad[:b] = np.broadcast_to(lamsum_rows, (b, n_pad))
-    if stacks.class_rows is not None:
-        # Per-draw class values (chaos campaigns): draw bi's latency row.
-        lat_pad = np.empty((b_pad, c), np.float32)
-        lat_pad[:b] = stacks.class_rows
-        lat_pad[b:] = stacks.class_rows[0]
-    else:
-        lat_pad = np.broadcast_to(
-            np.asarray(classes, np.float32)[None, :], (b_pad, c))
-    mask_np = np.asarray(seg.ctrl_mask, np.float32)
-    if mask_np.ndim == 2:
-        # Per-draw holdover victims: (B, N) → padded rows (padding rows
-        # keep the controller enabled; their state is inert anyway).
-        mask_pad = np.ones((b_pad, n_pad), np.float32)
-        mask_pad[:b, :n] = mask_np
-    else:
-        mask_pad = np.ones((n_pad,), np.float32)
-        mask_pad[:n] = mask_np
-    kp_j = _pad_gain(broadcast_gain(ctrl.kp, b), b_pad)
-    boff_j = _pad_gain(broadcast_gain(ctrl.beta_off, b, "beta_off"), b_pad)
-    tr.count("h2d_bytes", nu_u.nbytes + kp_j.nbytes + boff_j.nbytes)
-    return (a, lam_list, _put(tr, lamsum_pad),
-            _put(tr, np.ascontiguousarray(lat_pad)),
-            _put(tr, mask_pad), nu_u, kp_j, boff_j, chosen, tj,
-            b_pad, n_pad)
+    dev, gains = _common_inputs(plan, seg, ctrl, b, n, b_pad, n_pad, tr)
+    dev.update(a=a, lat=_kept(plan, "lat", lat))
+    fields = dict(engine=chosen, tile_j=int(tj), b_pad=int(b_pad),
+                  n_pad=int(n_pad), c=c, vmem_est_bytes=int(vmem_est))
+    return _Inputs(chosen, tj, fields, b_pad, n_pad, dev, gains)
+
+
+def _sparse_inputs(plan: SegmentPlan, seg, si: int, ctrl: ControllerConfig,
+                   b: int, n: int, variant: dict,
+                   tr=NULL_TRACE) -> _Inputs:
+    """Segment ``si``'s draw-independent sparse-lane inputs: its slot
+    tables padded to B_pad rows, the node-panel width for ``variant``,
+    the padded mask and gains."""
+    tables = plan.tables
+    n_pad = tables.n_pad
+    b_pad = ((b + SUBLANE - 1) // SUBLANE) * SUBLANE
+    latf = _kept(plan, ("latf", id(tables.latf[si])),
+                 lambda: _pad_table_rows(tables.latf[si], b_pad))
+    w = _kept(plan, ("w", id(tables.w[si])),
+              lambda: _pad_table_rows(tables.w[si], b_pad))
+    rows_t = max(latf.shape[0], w.shape[0])
+    # Falls back to TILE and lets the kernel's own VMEM check raise.
+    ti = sparse_panel(b_pad, n_pad, tables.k, rows_t, **variant) or TILE
+    dev, gains = _common_inputs(plan, seg, ctrl, b, n, b_pad, n_pad, tr)
+    dev.update(latf=latf, w=w)
+    fields = dict(engine="sparse", tile_i=int(ti), b_pad=int(b_pad),
+                  n_pad=int(n_pad), k=int(tables.k),
+                  vmem_est_bytes=sparse_vmem_bytes(b_pad, n_pad, tables.k,
+                                                   ti, rows_t, **variant))
+    return _Inputs("sparse", ti, fields, b_pad, n_pad, dev, gains)
+
+
+def _lamsum_input(plan: SegmentPlan, topo: Topology, si: int, lam_eff,
+                  edge_w, b: int, b_pad: int, n_pad: int, tr=NULL_TRACE):
+    """Segment ``si``'s (B_pad, N_pad) λeff fold Σ_{e→i} w_e·λeff_e on
+    the device, per draw when λeff or the edge weights are.
+
+    The fold of the fabric's own β0 (``plan.lam0``) is kept in the plan,
+    made once per edge-weight vector.  Any other λeff — per draw after a
+    re-establish or a rotation — folds per call, in one bincount over
+    the plan's flat index."""
+    lam = np.asarray(lam_eff, np.float64)
+    rows = b if (lam.ndim == 2 or np.ndim(edge_w) == 2) else 1
+
+    def fold():
+        if rows not in plan.index:
+            plan.index[rows] = _fold_index(topo.dst, rows, n_pad)
+        folded = _lamsum_host(topo, lam if lam.ndim == 2 else lam[None],
+                              edge_w, rows, n_pad, plan.index[rows])
+        pad = np.zeros((b_pad, n_pad), np.float32)
+        pad[:b] = folded
+        return _put(tr, pad)
+
+    if not same_bits(plan.lam0, lam):
+        return fold()
+    w_np = np.asarray(edge_w)
+    return _kept(plan, ("fold", si), lambda: _kept(
+        plan, ("fold", w_np.shape, w_np.tobytes()), fold))
 
 
 class _Lane(NamedTuple):
@@ -1041,18 +1066,28 @@ def _run_scenario(topo: Topology, links: LinkParams, ctrl: ControllerConfig,
     gband = None               # padded (B_pad, 1) kernel-lane guard band
     rec_done, total = 0, comp.total_records
     eng_label, tile_j = engine, 0
-    # All segments' dense adjacency stacks / sparse slot tables, built
-    # once (the chunk loop never re-densifies A or re-scatter slots).
-    stacks = tables = None
-    if dense or sparse:
-        with tr.span("segment.stacks"):
-            if dense:
-                stacks = _build_dense_stacks(topo, comp, cfg, tr=tr)
-            else:
-                tables = _build_sparse_tables(topo, comp, cfg, tr=tr)
     interp = _auto_interpret()
     variant = dict(record_beta=bool(rb_dense), record_watermarks=bool(rw),
                    record_guard=guard_on)
+    # The fabric's segment plan (``repro.scenarios.plan``): all segments'
+    # dense adjacency stacks / sparse slot tables, built once per fabric
+    # (the chunk loop never re-densifies A or re-scatters slots, and a
+    # later call on an equal fabric builds neither), and the segments'
+    # padded inputs, made again when the controller, the draw count, the
+    # lane or the telemetry variant changes.
+    plan = None
+    if dense or sparse:
+        with tr.span("segment.stacks"):
+            plan = plans.lookup(
+                (topo, links, cfg, scenario if compiled is None else compiled),
+                (engine, ctrl, ppm_u.shape, tuple(variant.values())), tr)
+            if plan.lam0 is None:
+                plan.lam0 = np.array(links.beta0, np.float64)
+            if dense and plan.stacks is None:
+                plan.stacks = _build_dense_stacks(topo, comp, cfg, tr=tr)
+            if sparse and plan.tables is None:
+                plan.tables = _build_sparse_tables(topo, comp, cfg, tr=tr)
+    nu_last = None           # (dppm, ν_u on the device) of the last prep
 
     def live_state():
         """Exact threaded (ψ, ν) — (N,)/(B, N) float host views.  Every
@@ -1089,56 +1124,60 @@ def _run_scenario(topo: Topology, links: LinkParams, ctrl: ControllerConfig,
                                                        stop)
         return run
 
-    def prep_lane(si, seg, links_seg, ppm_seg) -> _Lane:
-        """Segment ``si`` prepped on the kernel lane."""
-        ppm2d = np.atleast_2d(ppm_seg)
+    def prep_lane(si, seg, lam_seg, ppm_seg) -> _Lane:
+        """Segment ``si`` prepped on the kernel lane: the plan's inputs
+        for it, with this call's ν_u and λeff fold."""
+        nonlocal nu_last
+        if si not in plan.inputs:
+            plan.inputs[si] = (
+                _sparse_inputs(plan, seg, si, ctrl, b, n, variant, tr)
+                if sparse else _dense_inputs(plan, seg, si, ctrl, b, n,
+                                             engine, variant, tr))
+        ins = plan.inputs[si]
+        dev = ins.dev
+        dppm = np.asarray(seg.dppm, np.float32)
+        if nu_last is None or not same_bits(nu_last[0], dppm):
+            nu_u_j = _pad_batch(np.atleast_2d(ppm_seg), n, ins.n_pad)[0]
+            tr.count("h2d_bytes", nu_u_j.nbytes)
+            nu_last = (dppm, nu_u_j)
+        nu_u_j = nu_last[1]
+        lamsum_j = _lamsum_input(plan, topo, si, lam_seg, seg.edge_w, b,
+                                 ins.b_pad, ins.n_pad, tr)
         if sparse:
-            (latf_j, w_j, lamsum_j, mask_j, nu_u_j, kp_j, boff_j, ti, b_pad,
-             n_pad) = _prep_sparse_segment(topo, links_seg, seg, ctrl, ppm2d,
-                                           cfg, tables, si, variant, tr)
-
             def launch_sparse(psi, nu, stop):
                 return _sparse_engine(
-                    psi, nu, nu_u_j, kp_j, boff_j, mask_j, tables.nbr, latf_j,
-                    w_j, lamsum_j, dt_frames, int(chunk),
-                    int(cfg.record_every), int(ti), interp, rb_dense, rw,
-                    **guard_kw(stop))
+                    psi, nu, nu_u_j, dev["kp"], dev["boff"], dev["mask"],
+                    plan.tables.nbr, dev["latf"], dev["w"], lamsum_j,
+                    dt_frames, int(chunk), int(cfg.record_every),
+                    int(ins.tile), interp, rb_dense, rw, **guard_kw(stop))
 
-            fields = dict(engine="sparse", tile_i=int(ti), b_pad=int(b_pad),
-                          n_pad=int(n_pad), k=int(tables.k),
-                          vmem_est_bytes=sparse_vmem_bytes(
-                              b_pad, n_pad, tables.k, ti,
-                              max(latf_j.shape[0], w_j.shape[0]), **variant))
-            return _Lane("sparse", ti, fields, b_pad, nu_u_j,
-                         batched(launch_sparse))
-        (a, lam_list, lamsum_j, lat_j, mask_j, nu_u_j, kp_j, boff_j, chosen,
-         tj, b_pad, n_pad) = _prep_dense_segment(
-            topo, links_seg, seg, comp, ctrl, ppm2d, cfg, engine, stacks, si,
-            variant, tr)
-        c_stack = int(a.shape[0])
-        if chosen == "fused":
-            vmem_est = fused_vmem_bytes(b_pad, n_pad, c_stack, **variant)
-        elif chosen == "tiled":
-            vmem_est = tiled_vmem_bytes(b_pad, n_pad, c_stack, tj, **variant)
-        else:   # per-step: one double-buffered (C, TILE, TILE) tile
-            vmem_est = 2 * 4 * c_stack * TILE * TILE
-        fields = dict(engine=chosen, tile_j=int(tj), b_pad=int(b_pad),
-                      n_pad=int(n_pad), c=c_stack,
-                      vmem_est_bytes=int(vmem_est))
+            return _Lane("sparse", ins.tile, ins.dispatch, ins.b_pad,
+                         nu_u_j, batched(launch_sparse))
+        chosen, tj = ins.engine, ins.tile
         if chosen != "per-step":
             def launch_dense(psi, nu, stop):
                 return _fused_engine(
-                    psi, nu, nu_u_j, kp_j, boff_j, mask_j, a, lam_list[0],
-                    lamsum_j, lat_j, dt_frames, int(chunk),
-                    int(cfg.record_every), chosen, int(tj), interp, False,
-                    rb_dense, rw, **guard_kw(stop))
+                    psi, nu, nu_u_j, dev["kp"], dev["boff"], dev["mask"],
+                    dev["a"], plan.stacks.lam_dummy, lamsum_j, dev["lat"],
+                    dt_frames, int(chunk), int(cfg.record_every), chosen,
+                    int(tj), interp, False, rb_dense, rw, **guard_kw(stop))
 
-            return _Lane(chosen, tj, fields, b_pad, nu_u_j,
+            return _Lane(chosen, tj, ins.dispatch, ins.b_pad, nu_u_j,
                          batched(launch_dense))
-        # The per-step lane launches each draw on its own, with its gains
-        # as compile keys read back once a segment.
-        kp_np = _fetch(tr, kp_j)
-        boff_np = _fetch(tr, boff_j)
+        # The capability lane consumes the dense λeff tensor directly; its
+        # per-period kernel folds lamsum internally from it.  (Rebuilt per
+        # segment: λeff is live state under re-establishment events.)  It
+        # launches each draw on its own, with its gains as compile keys.
+        inv_seg, c = plan.stacks.inv[si], int(dev["a"].shape[0])
+        lam = np.asarray(lam_seg, np.float64)
+        if lam.ndim == 2:
+            lam_list = [_lam_stack(topo, inv_seg, lam[bi], seg.edge_w, c,
+                                   ins.n_pad, tr) for bi in range(b)]
+        else:
+            lam_list = [_lam_stack(topo, inv_seg, lam, seg.edge_w, c,
+                                   ins.n_pad, tr)] * b
+        kp_np, boff_np = ins.gains
+        mask_j, a, lat_j = dev["mask"], dev["a"], dev["lat"]
 
         def run_perstep(psi, nu, stop):
             def launch(bi, stop_i):
@@ -1190,7 +1229,8 @@ def _run_scenario(topo: Topology, links: LinkParams, ctrl: ControllerConfig,
                     if rw else None)
             return psi, nu, trips, tstar, valid, freq, beta, wm
 
-        return _Lane(chosen, tj, fields, b_pad, nu_u_j, run_perstep)
+        return _Lane(chosen, tj, ins.dispatch, ins.b_pad, nu_u_j,
+                     run_perstep)
 
     for si, seg in enumerate(comp.segments):
         lat_frames = np.asarray(seg.latency_s, np.float64) * cfg.omega_nom
@@ -1219,8 +1259,9 @@ def _run_scenario(topo: Topology, links: LinkParams, ctrl: ControllerConfig,
             dppm32 = np.asarray(seg.dppm, np.float32)
             ppm_seg = (ppm_u + dppm32 if (single or dppm32.ndim == 2)
                        else ppm_u + dppm32[None])
-            links_seg = LinkParams(latency_s=seg.latency_s,
-                                   beta0=np.array(lam_eff, copy=True))
+            if not (dense or sparse):
+                links_seg = LinkParams(latency_s=seg.latency_s,
+                                       beta0=np.array(lam_eff, copy=True))
             lam_rows.append(_lam_table(lam_eff, seg.latency_s,
                                        cfg.omega_nom))
         if policy is not None:
@@ -1262,13 +1303,13 @@ def _run_scenario(topo: Topology, links: LinkParams, ctrl: ControllerConfig,
                 return np.atleast_2d(est.max(axis=-1))
 
         if dense or sparse:
-            # Segment prep — λeff folds, padding, stack or table lookup —
+            # Segment prep — ν_u, the λeff fold, the plan's padded inputs —
             # happens ONCE per segment; the chunk loop below replays the
             # jitted engine on device-resident padded state with zero host
             # rebuilds (A and the slot tables were built before the
             # segment loop).
             with tr.span("segment.prep", segment=si):
-                lane = prep_lane(si, seg, links_seg, ppm_seg)
+                lane = prep_lane(si, seg, lam_eff, ppm_seg)
                 if psi_pad is None:
                     psi_pad, nu_pad = jnp.zeros_like(lane.nu_u), lane.nu_u
             eng_label, tile_j = lane.engine, lane.tile
@@ -1324,10 +1365,7 @@ def _run_scenario(topo: Topology, links: LinkParams, ctrl: ControllerConfig,
                         # prep picks the shifted lam_eff up, so skip the
                         # re-prep there (its outputs would be discarded).
                         if seg_done < seg.records:
-                            links_seg = LinkParams(
-                                latency_s=seg.latency_s,
-                                beta0=np.array(lam_eff, copy=True))
-                            lane = prep_lane(si, seg, links_seg, ppm_seg)
+                            lane = prep_lane(si, seg, lam_eff, ppm_seg)
             continue
 
         tr.event("engine_dispatch", segment=si, engine="segment-sum",

@@ -17,8 +17,9 @@ last three):
 
     scenario          span: one run_scenario call, parent of the rest
     segment.compile   span: compile_scenario (when not passed compiled=)
-    segment.stacks    span: the dense adjacency stacks (scattered on the
-                      device) / sparse slot tables built up front
+    segment.stacks    span: the segment plan's lookup, and the dense
+                      adjacency stacks (scattered on the device) / sparse
+                      slot tables when it builds them
     segment.upload    span: their device_put — the dense stacks' edge
                       lists (child of segment.stacks)
     segment.prep      span: a segment's prep at its start (ppm and λeff
@@ -45,7 +46,10 @@ Counters (:meth:`RunTrace.count`): ``h2d_bytes``, the padded bytes of
 every host array the run places on the device; ``d2h_bytes``, the bytes
 of every device array it reads back (the kernel lanes' records and
 state cut to their unpadded draws and nodes on the device first);
-``d2h_reads``, the number of those blocking reads.
+``d2h_reads``, the number of those blocking reads; ``prep_plan_builds``
+or ``prep_plan_hits``, one of the two a kernel-lane call, as the call
+built its fabric's segment plan or reused its stacks or slot tables
+(``repro.scenarios.plan``).
 
 Export is JSON-lines (a header line with the counters first, then one
 event per line) and round-trips through :meth:`RunTrace.from_jsonl`.

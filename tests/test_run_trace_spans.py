@@ -9,9 +9,11 @@ and the spans ``run_scenario`` opens on every kernel lane.
 3. A traced FC8 scenario (re-established LatencyStep, explicit graph
    Reframe, guard on) emits every span kind on each kernel lane; each
    ``chunk``'s dispatch / wait / fetch children lie inside it.
-4. ``h2d_bytes`` equals the bytes of the padded shapes, ``d2h_bytes``
-   those of the unpadded ones, and ``d2h_reads`` one read a chunk and
-   one a state read; the unpadded reads equal the padded lane's.
+4. ``h2d_bytes`` equals the bytes of the padded shapes, on the call
+   that builds the fabric's segment plan and on a call that reuses it,
+   ``d2h_bytes`` those of the unpadded ones, and ``d2h_reads`` one read
+   a chunk and one a state read; the unpadded reads equal the padded
+   lane's.
 5. With ``annotate=True`` a ``jax.profiler`` capture holds one host
    event per span, with its name, at the span's start mapped onto the
    profiler clock.
@@ -30,6 +32,7 @@ from repro.kernels import EngineOptions, simulate_ensemble_dense
 from repro.kernels.bittide_step import SUBLANE, TILE
 from repro.scenarios import (LatencyStep, Reframe, Scenario, edges_between,
                              run_scenario)
+from repro.scenarios.plan import _clear_plans
 from repro.telemetry import NULL_TRACE, RunTrace, Telemetry, TraceEvent
 from repro.telemetry import trace as trace_mod
 from repro.telemetry.trace import annotation_name
@@ -39,6 +42,13 @@ RUN_KINDS = {"scenario", "segment.compile", "segment.stacks",
              "segment.upload", "segment.prep", "segment.splice", "guard",
              "reframe", "chunk", "chunk.dispatch", "chunk.wait",
              "chunk.fetch"}
+
+
+@pytest.fixture(autouse=True)
+def _cold_plans():
+    """Every test starts with no segment plan, so what a run builds and
+    uploads does not depend on which tests this worker ran before."""
+    _clear_plans()
 
 
 # ------------------------------------------------------------ 1. recorder
@@ -247,15 +257,25 @@ def test_compiled_scenario_skips_the_compile_span():
 
 # ------------------------------------------------------ 4. byte counters
 
+@pytest.mark.parametrize("path", ["build", "hit"])
 @pytest.mark.parametrize("engine", ["fused", "sparse"])
-def test_transfer_counters_match_unpadded_shapes(engine):
+def test_transfer_counters_match_unpadded_shapes(engine, path):
     """No guard: the counts follow from the shapes alone.  Two segments
     with different latency tables (two stacks or slot tables), three
     chunks of 4 records, one re-establish and one explicit Reframe, each
     reading the live state once.  Uploads are padded; read-backs carry
     the unpadded (b, n) slices only, one read a chunk and one a state
-    read, and the lanes read no gains back."""
+    read, and the lanes read no gains back.
+
+    Every call uploads ν_u once (no segment changes it) and the per-draw
+    λeff folds after the re-establish and the Reframe.  The call that
+    builds the plan also uploads what the plan keeps: the stacks or slot
+    tables, one mask, the gains and the latency-class rows for the whole
+    scenario, and segment 0's fold of β0.  The same call again hits the
+    plan and uploads nothing else."""
     b = 2
+    if path == "hit":
+        _traced_run(engine, guard=False, b=b)
     res, tr = _traced_run(engine, guard=False, b=b)
     n = res.topo.num_nodes
     n_pad, b_pad = TILE, SUBLANE
@@ -265,23 +285,28 @@ def test_transfer_counters_match_unpadded_shapes(engine):
     segs = len(disp)
     assert segs == 3 and res.num_launches == 3
     records = [c.data["records"] for c in tr.by_kind("chunk")]
-    if engine == "fused":
+    h2d = 3 * state                                     # ν_u, two folds
+    if path == "hit":
+        plan = {"prep_plan_hits": 1}
+    elif engine == "fused":
+        plan = {"prep_plan_builds": 1}
         c = disp[0].data["c"]
         e = res.topo.num_edges
-        h2d = (2 * 4 * e * 4 + c * 4                   # edge lists, λ dummy
-               + segs * (2 * state + b_pad * c * 4 + n_pad * 4
-                         + 2 * b_pad * 4))              # prep
+        h2d += (2 * 4 * e * 4 + c * 4                   # edge lists, λ dummy
+                + n_pad * 4 + 2 * b_pad * 4             # mask, gains
+                + b_pad * c * 4 + state)                # classes, fold of β0
     else:
+        plan = {"prep_plan_builds": 1}
         k = disp[0].data["k"]
-        h2d = (k * n_pad * 4 + 2 * 2 * k * n_pad * 4    # nbr; latf, w ×2
-               + segs * (2 * state + n_pad * 4 + 2 * b_pad * 4))
+        h2d += (k * n_pad * 4 + 2 * 2 * k * n_pad * 4   # nbr; latf, w ×2
+                + n_pad * 4 + 2 * b_pad * 4 + state)    # mask, gains, fold
     d2h = (sum(2 * r * real for r in records)           # freq, β
            + res.num_launches * 4 * real                # watermarks
            + 2 * 2 * real                               # splice, Reframe
            + 2 * real)                                  # final ψ, ν
     reads = res.num_launches + 2 + 1
     assert tr.counters == {"h2d_bytes": h2d, "d2h_bytes": d2h,
-                           "d2h_reads": reads}
+                           "d2h_reads": reads, **plan}
 
 
 def test_untraced_run_matches_traced_run():
