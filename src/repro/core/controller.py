@@ -22,6 +22,13 @@ Controller kinds
   removes the steady-state buffer offset that pure-P control leaves, cf. the
   consensus literature the paper cites [33]).
 
+Which engine runs which: the segment-sum simulator
+(:mod:`repro.core.frame_model`) runs all three kinds, with continuous or
+integer (``SimConfig.quantize_beta``) buffer readout.  Every Pallas lane
+runs ``proportional`` with continuous readout; the fused lane also runs
+``discrete`` and integer readout (``repro.kernels.period``); ``pi`` runs
+on segment-sum alone (``repro.scenarios.run_scenario`` refuses the rest).
+
 Gain sweeps
 -----------
 ``kp`` and ``beta_off`` are *traced* through both simulation engines: they
@@ -44,6 +51,8 @@ __all__ = ["ControllerConfig", "hardware_gain", "controller_init",
 
 @dataclasses.dataclass(frozen=True)
 class ControllerConfig:
+    """A controller kind and its constants (module docstring: which
+    engine runs which kind and readout)."""
     kind: str = "proportional"  # proportional | discrete | pi
     kp: float = 2e-10           # relative-frequency per frame of occupancy error
     ki: float = 0.0             # integral gain (pi only), per frame per control period

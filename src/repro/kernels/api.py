@@ -44,7 +44,9 @@ class EngineOutputs(NamedTuple):
     final carried state; ``beta`` / ``watermarks`` are ``None`` unless
     requested; ``guard_state`` is the (B, 1) int32 first-trip record
     index (sentinel ``num_records`` = never tripped), ``None`` when the
-    in-kernel guard is off.
+    in-kernel guard is off; ``c_est`` is the FINC/FDEC controller's
+    (B, N) accumulated correction after the last period, ``None`` under
+    the proportional controller.
     """
 
     psi: Any
@@ -53,6 +55,7 @@ class EngineOutputs(NamedTuple):
     beta: Optional[Any] = None
     watermarks: Optional[tuple] = None
     guard_state: Optional[Any] = None
+    c_est: Optional[Any] = None
 
 
 def resolve_options(options: Optional[EngineOptions], caller: str, *,

@@ -106,16 +106,19 @@ flag is off (it is a compile-time switch, not a traced branch).
 from __future__ import annotations
 
 import functools
+from typing import NamedTuple, Optional
 
 import jax
 import jax.numpy as jnp
+import numpy as np
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
 from . import period
 from .period import COMPILER_PARAMS, VMEM_LIMIT_BYTES, _gain_col
 
-__all__ = ["bittide_step_pallas", "bittide_fused_pallas",
+__all__ = ["bittide_step_pallas", "bittide_fused_pallas", "Incidence",
+           "incidence",
            "bittide_tiled_fused_pallas", "select_engine", "fused_vmem_bytes",
            "tiled_vmem_bytes", "sparse_vmem_bytes", "TILE", "SUBLANE",
            "sparse_panel", "VMEM_LIMIT_BYTES", "VMEM_BUDGET_BYTES",
@@ -278,11 +281,14 @@ def _fused_kernel(lat_ref, a_ref, psi0_ref, nu0_ref, nu_u_ref, kp_ref,
                   boff_ref, mask_ref, deg_ref, lamsum_ref, *rest,
                   dt_frames: float, record_every: int, num_classes: int,
                   record_beta: bool, record_watermarks: bool,
-                  record_guard: bool):
+                  record_guard: bool, pulses=None, integer: bool = False):
     t = pl.program_id(0)
-    r = period.unpack(rest, record_beta, record_watermarks, record_guard)
-    psi_s, nu_s = r.scratch
-    period.seed(r, psi0_ref, nu0_ref, t == 0)
+    edge_refs, rest = (rest[:4], rest[4:]) if integer else ((), rest)
+    c0_ref, rest = (rest[0], rest[1:]) if pulses else (None, rest)
+    r = period.unpack(rest, record_beta, record_watermarks, record_guard,
+                      pulses is not None)
+    psi_s, nu_s = r.scratch[:2]
+    period.seed(r, psi0_ref, nu0_ref, t == 0, c0_ref)
 
     nu_u = nu_u_ref[...]        # (B, N), resident across the whole run
     deg = deg_ref[...]          # (1, N), broadcasts over B
@@ -305,16 +311,56 @@ def _fused_kernel(lat_ref, a_ref, psi0_ref, nu0_ref, nu_u_ref, kp_ref,
                 preferred_element_type=jnp.float32)
         return acc
 
+    if integer:
+        src_ref, dst_ref, scat_ref, lame_ref = edge_refs
+        lam_e = lame_ref[...]   # (1, E)|(B, E) per-edge λeff
+
+        def contract(x, m, **precision):
+            # out[b, k] = Σ_j m[k, j] · x[b, j]  — an MXU matmul.
+            return jax.lax.dot_general(
+                x, m, dimension_numbers=(((1,), (1,)), ((), ())),
+                preferred_element_type=jnp.float32, **precision)
+
+        def gather(x, m):
+            # A one-hot bf16 row selects x[b, j] exactly: x is split into
+            # three bf16 parts, x = hi + mid + lo exactly, each selected by
+            # one single-pass contraction and summed back exactly in f32
+            # (half the passes of a Precision.HIGHEST contraction).
+            hi = x.astype(jnp.bfloat16)
+            r = x - hi.astype(jnp.float32)
+            mid = r.astype(jnp.bfloat16)
+            lo = (r - mid.astype(jnp.float32)).astype(jnp.bfloat16)
+            return (contract(hi, m) + contract(mid, m)) + contract(lo, m)
+
+        def readout(psi, nu):
+            # Σ_{e→i} w_e·rint(β_e): each edge formed and rounded alone.
+            src_term = gather(psi - nu * lat[:, 0:1], src_ref[0])
+            for c in range(1, num_classes):
+                src_term = src_term + gather(psi - nu * lat[:, c:c + 1],
+                                             src_ref[c])
+            beta_e = period.read_edges(src_term, lam_e,
+                                       gather(psi, dst_ref[...]))
+            return contract(beta_e, scat_ref[...], precision=_F32_MXU)
+    else:
+        readout = aggregate
+
     def one_period(_, carry):
-        psi, nu = carry
-        return period.control(aggregate(psi, nu), psi, nu, nu_u, kp,
-                              beta_off, deg, lamsum, enabled, dt_frames)
+        psi, nu = carry[:2]
+        return period.control(readout(psi, nu), psi, nu, nu_u, kp,
+                              beta_off, deg, lamsum, enabled, dt_frames,
+                              pulses, carry[2] if pulses else None, integer)
 
     def _advance():
-        psi, nu = jax.lax.fori_loop(
-            0, record_every, one_period, (psi_s[...], nu_s[...]))
+        carry = (psi_s[...], nu_s[...])
+        if pulses:
+            carry += (r.scratch[2][...],)
+        carry = jax.lax.fori_loop(0, record_every, one_period, carry)
+        psi, nu = carry[:2]
         psi_s[...] = psi
         nu_s[...] = nu
+        if pulses:
+            r.scratch[2][...] = carry[2]
+            r.c_out[...] = carry[2]
 
         # Decimated telemetry: ν once per record, not once per period.
         r.rec[...] = nu[None]
@@ -372,12 +418,24 @@ def _state_operands(b: int, n: int, panel: int, record_beta: bool,
 
 def fused_vmem_bytes(b: int, n: int, c: int, *, record_beta: bool = False,
                      record_watermarks: bool = False,
-                     record_guard: bool = False) -> int:
+                     record_guard: bool = False, edges: int = 0,
+                     pulses: bool = False) -> int:
     """Working set of the fused kernel variant: the resident (C, N, N)
-    stack, state, telemetry outputs and the ψ/ν scratch carries."""
+    stack, state, telemetry outputs and the ψ/ν scratch carries.  Integer
+    readout over ``edges`` (padded E, 0 = continuous) adds the (C, E, N)
+    source and (E, N) destination incidence, the (N, E) weighted scatter
+    and the (B, E) per-edge λeff (each counted at 32 bits, so the bf16
+    one-hot matrices are over-counted); the FINC/FDEC variant
+    (``pulses``) the ``c_est`` input, output and carry."""
     ops = [(b, c), (c, n, n), (1, n)] + _state_operands(
         b, n, n, record_beta, record_watermarks, record_guard)
-    return _working_set(ops, [(b, n)] * 2)
+    scratch = [(b, n)] * 2
+    if edges:
+        ops += [(c, edges, n), (edges, n), (n, edges), (b, edges)]
+    if pulses:
+        ops += [(b, n)] * 2
+        scratch.append((b, n))
+    return _working_set(ops, scratch)
 
 
 def tiled_vmem_bytes(b: int, n: int, c: int, tile_j: int, *,
@@ -415,7 +473,8 @@ def select_engine(b: int, n: int, c: int,
                   vmem_budget: int = VMEM_BUDGET_BYTES,
                   max_deg=None, *, record_beta: bool = False,
                   record_watermarks: bool = False,
-                  record_guard: bool = False):
+                  record_guard: bool = False, edges: int = 0,
+                  pulses: bool = False):
     """Tile-size dispatch heuristic: (engine, tile_j) for padded (B, N, C).
 
     Replaces the old VMEM cliff (fused-or-per-step-fallback) with four
@@ -439,11 +498,15 @@ def select_engine(b: int, n: int, c: int,
     the historical three-regime behavior unchanged.  The telemetry flags
     name the kernel variant that will run: its β record, watermark and
     guard buffers count against the budget, so the lane and tile chosen
-    are the ones that compile for that variant.
+    are the ones that compile for that variant.  So do integer readout
+    over ``edges`` and the FINC/FDEC ``pulses``, which only the fused
+    lane runs: their operands count against its budget, and where it
+    does not fit the lane returned is one that refuses them.
     """
     tel = dict(record_beta=record_beta, record_watermarks=record_watermarks,
                record_guard=record_guard)
-    if n <= RESIDENT_N_MAX and fused_vmem_bytes(b, n, c, **tel) <= vmem_budget:
+    if n <= RESIDENT_N_MAX and fused_vmem_bytes(
+            b, n, c, **tel, edges=edges, pulses=pulses) <= vmem_budget:
         return "fused", n
     tj = min(n, TILE_J_MAX)
     while tj >= TILE:
@@ -523,6 +586,44 @@ def _check_shapes(b, n, num_records, record_every):
         raise ValueError("num_records and record_every must be >= 1")
 
 
+class Incidence(NamedTuple):
+    """A fabric's edges as one-hot matrices, for integer readout on the
+    fused lane (E padded to a multiple of TILE; padding edges are zero
+    rows and columns):
+
+    src:  (C, E, N) bf16 — src[c, e, j] = 1 where edge e leaves node j
+          and has latency class c;
+    dst:  (E, N) bf16    — dst[e, i] = 1 where edge e enters node i;
+    scat: (N, E) f32     — scat[i, e] = w_e where edge e enters node i.
+
+    0 and 1 are exact in bfloat16, so the gathers take single-pass
+    contractions; the weights may be any float32.
+    """
+    src: object
+    dst: object
+    scat: object
+
+
+def incidence(src, dst, cls, w, c: int, n: int) -> Incidence:
+    """Host :class:`Incidence` of E edges (``src``, ``dst``, class
+    ``cls``, weight ``w``, each (E,)) over C classes and N padded nodes."""
+    e = len(src)
+    e_pad = max(TILE, -(-e // TILE) * TILE)
+    ix = np.arange(e)
+    s = np.zeros((c, e_pad, n), jnp.bfloat16)
+    s[np.asarray(cls), ix, np.asarray(src)] = 1
+    d = np.zeros((e_pad, n), jnp.bfloat16)
+    d[ix, np.asarray(dst)] = 1
+    sc = np.zeros((n, e_pad), np.float32)
+    sc[np.asarray(dst), ix] = np.asarray(w, np.float32)
+    return Incidence(s, d, sc)
+
+
+def _whole_map(ndim: int):
+    """Index map of a block that is the whole ``ndim``-axis array."""
+    return lambda *_: (0,) * ndim
+
+
 def bittide_fused_pallas(psi, nu, nu_u, a, deg, lamsum, lat_frames,
                          kp, beta_off, dt_frames: float,
                          *, num_records: int, record_every: int,
@@ -530,7 +631,10 @@ def bittide_fused_pallas(psi, nu, nu_u, a, deg, lamsum, lat_frames,
                          record_watermarks: bool = False,
                          record_guard: bool = False, guard_lo=None,
                          guard_hi=None, guard_stop=None,
-                         interpret: bool = False):
+                         interpret: bool = False,
+                         pulses: Optional[period.Pulses] = None,
+                         c_est=None, edges: Optional[Incidence] = None,
+                         lam_edges=None):
     """Advance ``num_records * record_every`` control periods in ONE kernel.
 
     Args:
@@ -580,20 +684,38 @@ def bittide_fused_pallas(psi, nu, nu_u, a, deg, lamsum, lat_frames,
         PARTIAL chunk on the same compiled kernel (zero-recompile splice
         resumes).  Required with ``record_guard``.
       interpret: run in interpret mode (CPU validation).
+      pulses: the FINC/FDEC controller (:class:`repro.kernels.period.
+        Pulses`, static fs and pulse budget) in place of the proportional
+        one; ``c_est`` is then its (B, N) accumulated correction at the
+        start, carried in VMEM across the whole run and returned.
+        Compile-time switch.
+      edges: integer readout (:class:`Incidence`): every period each
+        edge's occupancy is formed from one-hot gathers of the state,
+        rounded to a whole frame and summed by destination on the MXU;
+        ``lam_edges`` is its (E,)/(1, E) shared or (B, E) per-draw λeff.
+        The β record, the watermarks and the guard read the unrounded
+        occupancy as before.  Compile-time switch.
 
     Returns:
       :class:`repro.kernels.EngineOutputs` — (psi_final (B, N), nu_final
       (B, N), freq = nu_rec (num_records, B, N), beta = beta_rec
       (num_records, B, N) or None, watermarks or None, guard_state (B, 1)
-      int32 or None) where watermarks = (beta_abs_max (B, N) f32,
-      peak_record (B, N) i32, nu_min (B, N) f32, nu_max (B, N) f32).
+      int32 or None, c_est (B, N) or None) where watermarks =
+      (beta_abs_max (B, N) f32, peak_record (B, N) i32, nu_min (B, N)
+      f32, nu_max (B, N) f32).
     """
     b, n = psi.shape
     c = a.shape[0]
     _check_shapes(b, n, num_records, record_every)
+    if (pulses is None) != (c_est is None):
+        raise ValueError("pulses and c_est go together")
+    if (edges is None) != (lam_edges is None):
+        raise ValueError("edges and lam_edges go together")
+    e_pad = 0 if edges is None else int(edges.dst.shape[0])
     vmem = fused_vmem_bytes(b, n, c, record_beta=record_beta,
                             record_watermarks=record_watermarks,
-                            record_guard=record_guard)
+                            record_guard=record_guard, edges=e_pad,
+                            pulses=pulses is not None)
     if vmem > VMEM_BUDGET_BYTES and not interpret:
         raise ValueError(
             f"fused kernel resident set {vmem/2**20:.1f} MiB exceeds the "
@@ -601,21 +723,41 @@ def bittide_fused_pallas(psi, nu, nu_u, a, deg, lamsum, lat_frames,
             f"C={c}); use bittide_tiled_fused_pallas (adjacency streamed in "
             "column panels) for networks this large")
 
+    law = {}
+    if pulses is not None:
+        law["pulses"] = period.Pulses(float(pulses.fs), int(pulses.budget))
+    if edges is not None:
+        law["integer"] = True
     kern = functools.partial(
         _fused_kernel, dt_frames=float(dt_frames),
-        record_every=int(record_every), num_classes=int(c))
+        record_every=int(record_every), num_classes=int(c), **law)
     in_specs, args = _dense_inputs(
         psi, nu, nu_u, a, deg, lamsum, lat_frames, kp, beta_off, ctrl_mask,
         (c, n, n), lambda t: (0, 0, 0))
+    scratch = [pltpu.VMEM((b, n), jnp.float32),           # ψ carry
+               pltpu.VMEM((b, n), jnp.float32)]           # ν carry
+    if edges is not None:
+        lam_e = jnp.asarray(lam_edges, jnp.float32)
+        lam_e = lam_e.reshape(-1, lam_e.shape[-1])
+        if lam_e.shape not in ((1, e_pad), (b, e_pad)):
+            raise ValueError(f"lam_edges must be ({e_pad},), (1, {e_pad}) "
+                             f"or ({b}, {e_pad}), got {lam_e.shape}")
+        ops = [jnp.asarray(edges.src, jnp.bfloat16),
+               jnp.asarray(edges.dst, jnp.bfloat16),
+               jnp.asarray(edges.scat, jnp.float32), lam_e]
+        in_specs += [pl.BlockSpec(x.shape, _whole_map(x.ndim)) for x in ops]
+        args += ops
+    if pulses is not None:
+        in_specs.append(pl.BlockSpec((b, n), period.whole))
+        args.append(jnp.asarray(c_est, jnp.float32))
+        scratch.append(pltpu.VMEM((b, n), jnp.float32))   # c_est carry
     return period.launch(
         kern, name="bittide_fused", grid=(num_records,), in_specs=in_specs,
-        args=args,
-        scratch=[pltpu.VMEM((b, n), jnp.float32),         # ψ carry
-                 pltpu.VMEM((b, n), jnp.float32)],        # ν carry
+        args=args, scratch=scratch,
         b=b, n=n, num_records=num_records, record_beta=record_beta,
         record_watermarks=record_watermarks, record_guard=record_guard,
         guard_lo=guard_lo, guard_hi=guard_hi, guard_stop=guard_stop,
-        interpret=interpret)
+        interpret=interpret, pulses=pulses is not None)
 
 
 def _dense_inputs(psi, nu, nu_u, a, deg, lamsum, lat_frames, kp, beta_off,

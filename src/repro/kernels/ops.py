@@ -281,13 +281,14 @@ def bittide_step(psi, nu, nu_u, a, lam_eff, lat, kp, beta_off, dt_frames,
                                              "tile_j", "interpret",
                                              "use_ref", "record_beta",
                                              "record_watermarks",
-                                             "record_guard"))
+                                             "record_guard", "pulses"))
 def _fused_engine(psi, nu, nu_u, kp, beta_off, ctrl_mask, a, lam_eff,
                   lamsum, lat, dt_frames, num_records, record_every, engine,
                   tile_j, interpret, use_ref, record_beta: bool = False,
                   record_watermarks: bool = False,
                   record_guard: bool = False, guard_lo=None, guard_hi=None,
-                  guard_stop=None):
+                  guard_stop=None, pulses=None, c_est=None, edges=None,
+                  lam_edges=None):
     """jit entry for the fused engines; one compile per (B, N, C, statics).
 
     Traced arguments (data, never compile keys — the scenario runner swaps
@@ -316,9 +317,22 @@ def _fused_engine(psi, nu, nu_u, kp, beta_off, ctrl_mask, a, lam_eff,
     ``EngineOutputs.guard_state`` (sentinel ``num_records``); since the
     stop cap is traced too, a partial chunk reuses this exact executable.
 
+    Which lane runs which controller and readout: both lanes run the
+    proportional controller with continuous readout.  The FINC/FDEC
+    controller (static ``pulses``, a ``repro.kernels.period.Pulses``,
+    with its traced (B_pad, N_pad) ``c_est``) and integer readout (traced
+    ``edges``, a ``repro.kernels.bittide_step.Incidence``, with the
+    per-edge λeff ``lam_edges``, (1 | B_pad, E_pad)) run on the fused
+    lane alone; the tiled lane and the oracle refuse them.
+
     Returns :class:`repro.kernels.EngineOutputs` with watermarks =
     (beta_abs_max, peak_record, nu_min, nu_max).
     """
+    law = dict(pulses=pulses, c_est=c_est, edges=edges, lam_edges=lam_edges)
+    if (use_ref or engine == "tiled") and (pulses is not None
+                                           or edges is not None):
+        raise ValueError("FINC/FDEC and integer readout run on the fused "
+                         "lane alone")
     if use_ref:
         if record_guard:
             raise ValueError("record_guard is not supported on the "
@@ -356,7 +370,7 @@ def _fused_engine(psi, nu, nu_u, kp, beta_off, ctrl_mask, a, lam_eff,
         num_records=num_records, record_every=record_every,
         ctrl_mask=ctrl_mask, record_beta=record_beta,
         record_watermarks=record_watermarks, interpret=interpret,
-        **guard_kw)
+        **guard_kw, **law)
 
 
 @functools.partial(jax.jit, static_argnames=("dt_frames", "num_records",

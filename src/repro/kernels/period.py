@@ -14,7 +14,10 @@ held here once:
   :func:`split_outputs`);
 - :func:`seed` loads the initial state and the guard's trip sentinel;
 - :func:`control` is the controller law and integrator (the per-step
-  kernel calls it too, with scalar gains);
+  kernel calls it too, with scalar gains), with two compile-time
+  variants: the FINC/FDEC law (:class:`Pulses`, which carries ``c_est``)
+  and integer readout (:func:`read_edges`, each edge's occupancy
+  rounded to a whole frame before the node sum);
 - :func:`measure` turns one record's per-node net occupancy into the β
   record, the watermarks and the guard trip; :func:`measure_periods`,
   :func:`row_mean` and :func:`centre` are the measure-pass rule of the
@@ -70,13 +73,21 @@ def _st(x, ix, v):
     return x
 
 
+class Pulses(NamedTuple):
+    """The FINC/FDEC controller's static constants (arXiv:2503.05033
+    §4.3): the pulse step ``fs`` (relative frequency) and the most
+    pulses a node may issue in one control period.  A compile key."""
+    fs: float
+    budget: int
+
+
 class Refs(NamedTuple):
     """A kernel's refs after its fixed inputs, by name.
 
     ``pallas_call`` passes inputs, then outputs, then scratch.  The guard
     band and stop cap trail the fixed inputs; the β record, the four
-    watermarks and the trip column trail the fixed outputs, in that
-    order.  Absent ones are None.
+    watermarks, the trip column and the FINC/FDEC ``c_est`` trail the
+    fixed outputs, in that order.  Absent ones are None.
     """
     glo: Optional[object]
     ghi: Optional[object]
@@ -87,11 +98,12 @@ class Refs(NamedTuple):
     brec: Optional[object]
     wm: Optional[tuple]    # (|β| max, its record index, ν min, ν max)
     trip: Optional[object]
+    c_out: Optional[object]
     scratch: tuple         # ψ carry, ν carry, then the lane's own
 
 
 def unpack(rest, record_beta: bool, record_watermarks: bool,
-           record_guard: bool) -> Refs:
+           record_guard: bool, pulses: bool = False) -> Refs:
     """Name the refs that follow a kernel's fixed inputs."""
     rest = list(rest)
 
@@ -107,20 +119,24 @@ def unpack(rest, record_beta: bool, record_watermarks: bool,
     brec, = take(1, record_beta)
     wm = take(4) if record_watermarks else None
     trip, = take(1, record_guard)
-    return Refs(glo, ghi, stop, psi_out, nu_out, rec, brec, wm, trip,
+    c_out, = take(1, pulses)
+    return Refs(glo, ghi, stop, psi_out, nu_out, rec, brec, wm, trip, c_out,
                 tuple(rest))
 
 
-def seed(r: Refs, psi0_ref, nu0_ref, first):
+def seed(r: Refs, psi0_ref, nu0_ref, first, c0_ref=None):
     """On the first grid step, load the initial state into the ψ/ν
-    carries and set the guard's "never tripped" sentinel: num_records,
-    one past any record."""
+    carries (and ``c0_ref`` into the FINC/FDEC ``c_est`` carry, the
+    scratch after them) and set the guard's "never tripped" sentinel:
+    num_records, one past any record."""
     psi_s, nu_s = r.scratch[:2]
 
     @pl.when(first)
     def _seed():
         psi_s[...] = psi0_ref[...]
         nu_s[...] = nu0_ref[...]
+        if c0_ref is not None:
+            r.scratch[2][...] = c0_ref[...]
         if r.trip is not None:
             r.trip[...] = never_tripped(r.trip.shape, pl.num_programs(0))
 
@@ -130,21 +146,62 @@ def never_tripped(shape, num_records):
     return jnp.full(shape, num_records, jnp.int32)
 
 
-def control(acc, psi, nu, nu_u, kp, beta_off, deg, lamsum, enabled,
-            dt_frames):
-    """One period of the proportional controller and the integrator.
+def read_edges(src_term, lam_e, psi_dst):
+    """Integer readout: each edge's occupancy read as a whole frame,
 
-        err = acc − (ψ + β_off)·deg + lamsum
-        ν'  = ν_u + c + ν_u·c,  c = kp·err
+        rint(β_e),  β_e = (ψ_src − ν_src·lat_e) + λeff_e − ψ_dst,
+
+    formed in ``repro.core.frame_model``'s order from the gathered
+    source term and destination phase; ``jnp.round`` rounds half to
+    even, as ``np.rint``."""
+    return jnp.round(src_term + _ld(lam_e) - psi_dst)
+
+
+def finc_fdec(c_rel, c_est, pulses: Pulses):
+    """The FINC/FDEC step toward the proportional correction ``c_rel``:
+    ``c_est`` moved by n = clip(rint((c_rel − c_est)/fs), ±budget) whole
+    pulses of fs."""
+    n = jnp.clip(jnp.round((c_rel - c_est) / pulses.fs),
+                 -pulses.budget, pulses.budget)
+    return c_est + n * pulses.fs
+
+
+def control(acc, psi, nu, nu_u, kp, beta_off, deg, lamsum, enabled,
+            dt_frames, pulses: Optional[Pulses] = None, c_est=None,
+            integer: bool = False):
+    """One period of the controller and the integrator.
+
+        err = acc − (ψ + β_off)·deg + lamsum     continuous readout
+        err = acc − β_off·deg                    integer readout
+        ν'  = ν_u + c + ν_u·c
         ψ'  = ψ + ν'·Δt
 
     ``acc`` is the aggregation Σ_c [A_c @ (ψ − ν·lat_c)] (or its ELL
-    gather).  ``enabled`` is a bool array or the float controller-enable
-    mask (on above 0.5): a node switched off holds its previous ν (clock
-    holdover) instead of recomputing it.  Returns (ψ', ν').
+    gather); with ``integer`` readout it is Σ_{e→i} w_e·rint(β_e)
+    (:func:`read_edges`).  The proportional law takes c = kp·err.  With
+    ``pulses`` (FINC/FDEC, :class:`Pulses`) a node moves its clock only
+    in whole pulses of fs and carries their sum ``c_est``
+    (:func:`finc_fdec`):
+
+        n     = clip(rint((kp·err − c_est)/fs), ±budget)
+        c     = c_est + n·fs
+
+    as ``repro.core.controller.controller_step`` takes it.  ``enabled``
+    is a bool array or the float controller-enable mask (on above 0.5):
+    a node switched off holds its previous ν (clock holdover) instead of
+    recomputing it, and its ``c_est``.  Returns (ψ', ν'), and with
+    ``pulses`` (ψ', ν', c_est').  Both variants are compile-time
+    switches: without them the body is the proportional period with
+    continuous readout.
     """
-    err = _ld(acc) - (_ld(psi) + _ld(beta_off)) * _ld(deg) + _ld(lamsum)
+    if integer:
+        err = _ld(acc) - _ld(beta_off) * _ld(deg)
+    else:
+        err = _ld(acc) - (_ld(psi) + _ld(beta_off)) * _ld(deg) + _ld(lamsum)
     c_rel = _ld(kp) * err
+    if pulses is not None:
+        c_est = _ld(c_est)
+        c_rel = finc_fdec(c_rel, c_est, pulses)
     nu_u = _ld(nu_u)
     # (1+ν_u)(1+c) − 1 computed as ν_u + c + ν_u·c: never forms
     # 1 + O(1e-6), which would quantize to float32 eps(1.0) = 1.19e-7.
@@ -152,7 +209,10 @@ def control(acc, psi, nu, nu_u, kp, beta_off, deg, lamsum, enabled,
     if jax.typeof(enabled).dtype != jnp.bool_:
         enabled = _ld(enabled) > 0.5
     nu_next = jnp.where(enabled, nu_next, _ld(nu))
-    return _ld(psi) + nu_next * dt_frames, nu_next
+    psi_next = _ld(psi) + nu_next * dt_frames
+    if pulses is None:
+        return psi_next, nu_next
+    return psi_next, nu_next, jnp.where(enabled, c_rel, c_est)
 
 
 def fold_watermarks(wm, babs, nu, t, ix=...):
@@ -293,7 +353,8 @@ def _record(t, *_):
 
 
 def _outputs(b: int, n: int, num_records: int, record_beta: bool,
-             record_watermarks: bool, record_guard: bool):
+             record_watermarks: bool, record_guard: bool,
+             pulses: bool = False):
     """Out-specs and out-shapes in :func:`split_outputs`' order.
 
     Every block is whole-row: the pipeline writes an output block back
@@ -302,7 +363,8 @@ def _outputs(b: int, n: int, num_records: int, record_beta: bool,
     flush a stale buffer over the panel's results.  ψ, ν, the watermarks
     and the trip column keep constant index maps (VMEM-resident across
     the grid, flushed once at the end); the ν and β records advance with
-    the record index, so each record flushes once.
+    the record index, so each record flushes once.  The FINC/FDEC
+    variant (``pulses``) ends with the whole-row ``c_est``.
     """
     f32 = jnp.float32
     row = (pl.BlockSpec((b, n), whole), jax.ShapeDtypeStruct((b, n), f32))
@@ -319,15 +381,17 @@ def _outputs(b: int, n: int, num_records: int, record_beta: bool,
     if record_guard:
         outs.append((pl.BlockSpec((b, 1), whole),
                      jax.ShapeDtypeStruct((b, 1), jnp.int32)))
+    if pulses:
+        outs.append(row)
     specs, shapes = zip(*outs)
     return list(specs), list(shapes)
 
 
 def split_outputs(out, record_beta: bool, record_watermarks: bool,
-                  record_guard: bool) -> EngineOutputs:
+                  record_guard: bool, pulses: bool = False) -> EngineOutputs:
     """:class:`EngineOutputs` from the flat ``pallas_call`` output list."""
     i = 3
-    brec = wm = trip = None
+    brec = wm = trip = c_est = None
     if record_beta:
         brec = out[i]
         i += 1
@@ -336,25 +400,30 @@ def split_outputs(out, record_beta: bool, record_watermarks: bool,
         i += 4
     if record_guard:
         trip = out[i]
+        i += 1
+    if pulses:
+        c_est = out[i]
     return EngineOutputs(psi=out[0], nu=out[1], freq=out[2], beta=brec,
-                         watermarks=wm, guard_state=trip)
+                         watermarks=wm, guard_state=trip, c_est=c_est)
 
 
 def launch(kernel, *, name: str, grid, in_specs, args, scratch, b: int,
            n: int, num_records: int, record_beta: bool,
            record_watermarks: bool, record_guard: bool, guard_lo, guard_hi,
-           guard_stop, interpret: bool) -> EngineOutputs:
+           guard_stop, interpret: bool, pulses: bool = False
+           ) -> EngineOutputs:
     """A lane's ``pallas_call``: its fixed inputs, then the guard band
     and stop cap (three (B, 1) columns) with ``record_guard``; the
-    outputs of :func:`_outputs`; ``kernel`` given the ``record_*``
-    flags."""
+    outputs of :func:`_outputs` (with ``pulses``, the FINC/FDEC
+    ``c_est`` last); ``kernel`` given the ``record_*`` flags."""
     flags = dict(record_beta=bool(record_beta),
                  record_watermarks=bool(record_watermarks),
                  record_guard=bool(record_guard))
     if record_guard:
         in_specs = in_specs + [pl.BlockSpec((b, 1), whole)] * 3
         args = args + _guard_cols(guard_lo, guard_hi, guard_stop, b)
-    out_specs, out_shape = _outputs(b, n, num_records, **flags)
+    out_specs, out_shape = _outputs(b, n, num_records, **flags,
+                                    pulses=pulses)
     out = pl.pallas_call(
         functools.partial(kernel, **flags),
         name=name,
@@ -366,4 +435,4 @@ def launch(kernel, *, name: str, grid, in_specs, args, scratch, b: int,
         compiler_params=COMPILER_PARAMS,
         interpret=interpret,
     )(*args)
-    return split_outputs(out, **flags)
+    return split_outputs(out, **flags, pulses=pulses)

@@ -23,7 +23,11 @@ Engines:
                   ``record_beta=True``, in-kernel per-node net occupancy
                   (T, N) β telemetry (frames; see
                   ``repro.kernels.bittide_step``); proportional
-                  controller, shared base links (per-draw λeff from
+                  controller with continuous readout on every dense
+                  lane, and on the fused lane also the FINC/FDEC
+                  controller and integer buffer readout
+                  (``quantize_beta``), whose ``c_est`` the runner threads
+                  like ψ and ν; shared base links (per-draw λeff from
                   re-establishment is supported; per-draw base latencies
                   belong on segment-sum).  The per-segment (C, N, N)
                   adjacency stacks are built ONCE up front, on the
@@ -36,11 +40,11 @@ Engines:
                   host rebuilds and zero re-transfers.
 ``sparse``        the edge-major ELL Pallas lane
                   (``repro.kernels.bittide_sparse``) — same telemetry
-                  contract and proportional-controller restriction as the
-                  dense lanes, but O(N·deg) per period: bounded-degree
-                  scenario studies scale past the dense lanes (up to
-                  ~5·10⁴ nodes at B = 8 on one TPU).  No latency
-                  classes exist here (every slot carries its edge's own
+                  contract as the dense lanes, proportional controller
+                  and continuous readout, but O(N·deg) per period:
+                  bounded-degree scenario studies scale past the dense
+                  lanes (up to ~5·10⁴ nodes at B = 8 on one TPU).  No
+                  latency classes exist here (every slot carries its edge's own
                   latency in frames), so fully heterogeneous per-draw
                   (B, E) links AND per-draw (B, E) edge weights — chaos
                   campaigns with per-draw LinkDrop victims — run
@@ -148,12 +152,14 @@ from repro.core.topology import Topology
 from repro.kernels.api import resolve_options
 from repro.kernels.bittide_sparse import ell_tables
 from repro.kernels.bittide_step import (SUBLANE, TILE, fused_vmem_bytes,
-                                       select_engine, sparse_panel,
-                                       sparse_vmem_bytes, tiled_vmem_bytes)
+                                       incidence, select_engine,
+                                       sparse_panel, sparse_vmem_bytes,
+                                       tiled_vmem_bytes)
 from repro.kernels.ops import (_auto_interpret, _fold_index, _fused_engine,
                                _host_watermarks, _lamsum_host, _pad_batch,
                                _pad_gain, _pad_table_rows, _perstep_engine,
                                _sparse_engine, latency_classes)
+from repro.kernels.period import Pulses
 from repro.telemetry import (NULL_TRACE, Watermarks, coerce_trace,
                              compile_stats)
 from repro.telemetry.api import resolve_telemetry
@@ -518,6 +524,10 @@ class _DenseStacks:
         self.inv = inv                  # per-segment (E,) edge→class maps
         self.n_pad = n_pad
         self.num_unique = len({id(x) for x in a})
+        # Integer readout's one-hot edge matrices (``Incidence``) by
+        # (class map, edge weights), made by the first call that reads
+        # whole frames (:func:`_incidence_input`).
+        self.incidence = {}
 
 
 @functools.partial(jax.jit, static_argnames=("c", "n_pad"))
@@ -716,27 +726,41 @@ def _common_inputs(plan: SegmentPlan, seg, ctrl: ControllerConfig, b: int,
     return dict(mask=mask_j, kp=kp_j, boff=boff_j), host
 
 
+def _law_refusal(lane: str, why: str = "") -> ValueError:
+    """The refusal of a lane that lacks FINC/FDEC or integer readout."""
+    return ValueError(
+        f"FINC/FDEC control and integer buffer readout (quantize_beta) run "
+        f"on the fused lane and on the segment-sum engine; the {lane} lane "
+        f"implements the proportional controller with continuous readout"
+        + why)
+
+
 def _dense_inputs(plan: SegmentPlan, seg, si: int, ctrl: ControllerConfig,
-                  b: int, n: int, engine: str, variant: dict,
+                  b: int, n: int, engine: str, variant: dict, law: dict,
                   tr=NULL_TRACE) -> _Inputs:
     """Segment ``si``'s draw-independent dense-lane inputs: its A stack
     (see :class:`_DenseStacks`), the engine and panel width ``variant``
-    (the kernel's ``record_*`` flags) selects, the padded latency-class
-    rows, mask and gains."""
+    (the kernel's ``record_*`` flags) and ``law`` (integer readout's
+    padded edge count and the FINC/FDEC flag, which only the fused lane
+    runs) select, the padded latency-class rows, mask and gains."""
     stacks = plan.stacks
     a = stacks.a[si]
     n_pad, c = stacks.n_pad, int(a.shape[0])
     b_pad = ((b + SUBLANE - 1) // SUBLANE) * SUBLANE
     if engine == "auto":
-        chosen, tj = select_engine(b_pad, n_pad, c, **variant)
+        chosen, tj = select_engine(b_pad, n_pad, c, **variant, **law)
     elif engine == "per-step":
         chosen, tj = "per-step", 0
     elif engine == "tiled":
         chosen, tj = "tiled", select_engine(b_pad, n_pad, c, **variant)[1]
     else:
         chosen, tj = "fused", n_pad
+    if chosen != "fused" and (law["edges"] or law["pulses"]):
+        raise _law_refusal(chosen, (
+            f" (dispatch chose it: the fused working set does not fit at "
+            f"B_pad={b_pad}, N_pad={n_pad}, C={c})"))
     if chosen == "fused":
-        vmem_est = fused_vmem_bytes(b_pad, n_pad, c, **variant)
+        vmem_est = fused_vmem_bytes(b_pad, n_pad, c, **variant, **law)
     elif chosen == "tiled":
         vmem_est = tiled_vmem_bytes(b_pad, n_pad, c, tj, **variant)
     else:   # per-step: one double-buffered (C, TILE, TILE) tile
@@ -757,7 +781,44 @@ def _dense_inputs(plan: SegmentPlan, seg, si: int, ctrl: ControllerConfig,
     dev.update(a=a, lat=_kept(plan, "lat", lat))
     fields = dict(engine=chosen, tile_j=int(tj), b_pad=int(b_pad),
                   n_pad=int(n_pad), c=c, vmem_est_bytes=int(vmem_est))
+    if law["edges"]:
+        fields["e_pad"] = int(law["edges"])
     return _Inputs(chosen, tj, fields, b_pad, n_pad, dev, gains)
+
+
+def _incidence_input(plan: SegmentPlan, topo: Topology, seg, si: int,
+                     tr=NULL_TRACE):
+    """Segment ``si``'s one-hot edge matrices for integer readout on the
+    device (``repro.kernels.bittide_step.Incidence``), kept with the
+    fabric's stacks and made once per (class map, edge weights)."""
+    stacks = plan.stacks
+    inv = stacks.inv[si]
+    w = np.asarray(seg.edge_w, np.float64)
+    key = (inv.tobytes(), w.tobytes())
+    if key not in stacks.incidence:
+        host = incidence(topo.src, topo.dst, inv, w,
+                         int(stacks.a[si].shape[0]), stacks.n_pad)
+        stacks.incidence[key] = type(host)(*(_put(tr, x) for x in host))
+    return stacks.incidence[key]
+
+
+def _lam_edges_input(plan: SegmentPlan, lam_eff, b_pad: int, e_pad: int,
+                     tr=NULL_TRACE):
+    """The per-edge λeff integer readout reads, on the device: (1, E_pad)
+    shared or (B_pad, E_pad) per draw, in float32 as the segment-sum
+    engine takes it.  The fabric's own β0 is kept in the plan."""
+    lam = np.asarray(lam_eff, np.float64)
+
+    def pad():
+        rows = lam.reshape(-1, lam.shape[-1])
+        out = np.zeros((1 if rows.shape[0] == 1 else b_pad, e_pad),
+                       np.float32)
+        out[:rows.shape[0], :rows.shape[1]] = rows
+        return _put(tr, out)
+
+    if not same_bits(plan.lam0, lam):
+        return pad()
+    return _kept(plan, "lam_edges", pad)
 
 
 def _sparse_inputs(plan: SegmentPlan, seg, si: int, ctrl: ControllerConfig,
@@ -820,7 +881,8 @@ class _Lane(NamedTuple):
     dispatch: dict     # the engine_dispatch event's fields
     b_pad: int
     nu_u: object       # (B_pad, N_pad) ν_u on the device: the first ν
-    run: Callable      # (ψ, ν, stop) → (ψ, ν, trips, t*, valid, freq, β, wm)
+    run: Callable      # (ψ, ν, c_est, stop) →
+                       # (ψ, ν, c_est, trips, t*, valid, freq, β, wm)
 
 
 def run_scenario(topo: Topology, links: LinkParams, ctrl: ControllerConfig,
@@ -850,6 +912,16 @@ def run_scenario(topo: Topology, links: LinkParams, ctrl: ControllerConfig,
         ("auto" | "fused" | "tiled" | "per-step"), or "sparse" (the
         edge-major ELL lane — bounded-degree mega-scale topologies,
         per-draw LinkDrop victims, heterogeneous per-draw links).
+        Which lane runs which controller and readout: segment-sum runs
+        every ``ctrl.kind`` with either readout (and telemetry noise);
+        every kernel lane runs the proportional controller with
+        continuous readout; the fused lane also runs FINC/FDEC
+        (``kind="discrete"``, its ``c_est`` carried across chunks and
+        segments and returned in ``c_state``) and integer readout
+        (``cfg.quantize_beta``), which the tiled, sparse and per-step
+        lanes refuse — and so does "auto" where dispatch picks one of
+        them.  PI, and FINC/FDEC under the reframing guard, run on
+        segment-sum alone.
       chunk_records: kernel-launch granularity override; must divide
         every segment's record count.  Default: the compiler's GCD.
       compiled: reuse a previous :func:`compile_scenario` result.
@@ -995,15 +1067,23 @@ def _run_scenario(topo: Topology, links: LinkParams, ctrl: ControllerConfig,
                 "per-draw LinkDrop/LinkRestore victims need the "
                 "segment-sum or sparse engine (the dense (C, N, N) "
                 "adjacency stacks are shared across draws)")
+    pulses = ctrl.kind == "discrete"
     if dense or sparse:
         kind = "dense" if dense else "sparse"
-        if ctrl.kind != "proportional":
+        if ctrl.kind == "pi":
             raise ValueError(
-                f"{kind} engines implement the proportional controller; "
-                f"{ctrl.kind!r} runs on the segment-sum engine")
-        if cfg.quantize_beta or cfg.telemetry_noise_ppm:
+                f"{kind} engines implement the proportional controller and, "
+                f"on the fused lane, FINC/FDEC; 'pi' runs on the "
+                f"segment-sum engine")
+        if (pulses or cfg.quantize_beta) and engine not in ("auto", "fused"):
+            raise _law_refusal(engine)
+        if pulses and tel.guard:
             raise ValueError(
-                "quantize_beta / telemetry noise are segment-sum features")
+                "the in-kernel reframing guard runs under the proportional "
+                "controller; FINC/FDEC with a guard runs on the segment-sum "
+                "engine")
+        if cfg.telemetry_noise_ppm:
+            raise ValueError("telemetry noise is a segment-sum feature")
 
     # β recording: the typed request wins; with neither telemetry= nor
     # record_beta= passed, segment-sum keeps the cfg.record_beta default
@@ -1051,6 +1131,7 @@ def _run_scenario(topo: Topology, links: LinkParams, ctrl: ControllerConfig,
             guard_rows = np.asarray(policy.guard(margins),
                                     np.float64).reshape(-1)
 
+    readout = "integer" if cfg.quantize_beta else "continuous"
     rec_period = cfg.dt * cfg.record_every
     beta0_base = np.asarray(links.beta0, np.float64)
     lam_eff = np.array(beta0_base, copy=True)
@@ -1058,6 +1139,7 @@ def _run_scenario(topo: Topology, links: LinkParams, ctrl: ControllerConfig,
     b = 1 if single else ppm_u.shape[0]
     state = None                 # segment-sum: result object with .psi/.nu
     psi_pad = nu_pad = None      # dense lanes: padded (B_pad, N_pad) state
+    c_pad = None                 # and FINC/FDEC's c_est, (B_pad, N_pad)
     freq_chunks, beta_chunks = [], []
     wm_acc: Optional[Watermarks] = None
     lam_rows, launches = [], 0
@@ -1069,6 +1151,9 @@ def _run_scenario(topo: Topology, links: LinkParams, ctrl: ControllerConfig,
     interp = _auto_interpret()
     variant = dict(record_beta=bool(rb_dense), record_watermarks=bool(rw),
                    record_guard=guard_on)
+    # Integer readout reads E_pad edges a period; FINC/FDEC carries c_est.
+    law = dict(edges=(-(-topo.num_edges // TILE) * TILE
+                      if cfg.quantize_beta else 0), pulses=pulses)
     # The fabric's segment plan (``repro.scenarios.plan``): all segments'
     # dense adjacency stacks / sparse slot tables, built once per fabric
     # (the chunk loop never re-densifies A or re-scatters slots, and a
@@ -1114,14 +1199,14 @@ def _run_scenario(topo: Topology, links: LinkParams, ctrl: ControllerConfig,
     def batched(launch):
         """The chunk of a lane that launches every draw at once: launch,
         wait, and read the chunk back in one transfer."""
-        def run(psi, nu, stop):
+        def run(psi, nu, c_est, stop):
             with tr.span("chunk.dispatch"):
-                out = launch(psi, nu, stop)
+                out = launch(psi, nu, c_est, stop)
             with tr.span("chunk.wait"):
                 jax.block_until_ready(out)
             with tr.span("chunk.fetch"):
-                return (out.psi, out.nu) + _read_chunk(tr, out, b, n, chunk,
-                                                       stop)
+                return (out.psi, out.nu, out.c_est) + _read_chunk(
+                    tr, out, b, n, chunk, stop)
         return run
 
     def prep_lane(si, seg, lam_seg, ppm_seg) -> _Lane:
@@ -1132,7 +1217,7 @@ def _run_scenario(topo: Topology, links: LinkParams, ctrl: ControllerConfig,
             plan.inputs[si] = (
                 _sparse_inputs(plan, seg, si, ctrl, b, n, variant, tr)
                 if sparse else _dense_inputs(plan, seg, si, ctrl, b, n,
-                                             engine, variant, tr))
+                                             engine, variant, law, tr))
         ins = plan.inputs[si]
         dev = ins.dev
         dppm = np.asarray(seg.dppm, np.float32)
@@ -1144,7 +1229,7 @@ def _run_scenario(topo: Topology, links: LinkParams, ctrl: ControllerConfig,
         lamsum_j = _lamsum_input(plan, topo, si, lam_seg, seg.edge_w, b,
                                  ins.b_pad, ins.n_pad, tr)
         if sparse:
-            def launch_sparse(psi, nu, stop):
+            def launch_sparse(psi, nu, c_est, stop):
                 return _sparse_engine(
                     psi, nu, nu_u_j, dev["kp"], dev["boff"], dev["mask"],
                     plan.tables.nbr, dev["latf"], dev["w"], lamsum_j,
@@ -1155,12 +1240,23 @@ def _run_scenario(topo: Topology, links: LinkParams, ctrl: ControllerConfig,
                          nu_u_j, batched(launch_sparse))
         chosen, tj = ins.engine, ins.tile
         if chosen != "per-step":
-            def launch_dense(psi, nu, stop):
+            law_j = {}
+            if pulses:
+                law_j["pulses"] = Pulses(float(ctrl.fs),
+                                         int(ctrl.pulses_per_update))
+            if law["edges"]:
+                law_j.update(
+                    edges=_incidence_input(plan, topo, seg, si, tr),
+                    lam_edges=_lam_edges_input(plan, lam_seg, ins.b_pad,
+                                               law["edges"], tr))
+
+            def launch_dense(psi, nu, c_est, stop):
                 return _fused_engine(
                     psi, nu, nu_u_j, dev["kp"], dev["boff"], dev["mask"],
                     dev["a"], plan.stacks.lam_dummy, lamsum_j, dev["lat"],
                     dt_frames, int(chunk), int(cfg.record_every), chosen,
-                    int(tj), interp, False, rb_dense, rw, **guard_kw(stop))
+                    int(tj), interp, False, rb_dense, rw, **guard_kw(stop),
+                    **law_j, **({"c_est": c_est} if pulses else {}))
 
             return _Lane(chosen, tj, ins.dispatch, ins.b_pad, nu_u_j,
                          batched(launch_dense))
@@ -1179,7 +1275,7 @@ def _run_scenario(topo: Topology, links: LinkParams, ctrl: ControllerConfig,
         kp_np, boff_np = ins.gains
         mask_j, a, lat_j = dev["mask"], dev["a"], dev["lat"]
 
-        def run_perstep(psi, nu, stop):
+        def run_perstep(psi, nu, c_est, stop):
             def launch(bi, stop_i):
                 return _perstep_engine(
                     psi[bi], nu[bi], nu_u_j[bi],
@@ -1227,7 +1323,7 @@ def _run_scenario(topo: Topology, links: LinkParams, ctrl: ControllerConfig,
                 wm = (Watermarks.stack([_fetch_watermarks(
                     tr, r.watermarks, valid, None, n) for r in rows])
                     if rw else None)
-            return psi, nu, trips, tstar, valid, freq, beta, wm
+            return psi, nu, c_est, trips, tstar, valid, freq, beta, wm
 
         return _Lane(chosen, tj, ins.dispatch, ins.b_pad, nu_u_j,
                      run_perstep)
@@ -1312,8 +1408,11 @@ def _run_scenario(topo: Topology, links: LinkParams, ctrl: ControllerConfig,
                 lane = prep_lane(si, seg, lam_eff, ppm_seg)
                 if psi_pad is None:
                     psi_pad, nu_pad = jnp.zeros_like(lane.nu_u), lane.nu_u
+                    # FINC/FDEC starts from c_est = 0, made on the device.
+                    c_pad = jnp.zeros_like(lane.nu_u) if pulses else None
             eng_label, tile_j = lane.engine, lane.tile
-            tr.event("engine_dispatch", segment=si, **lane.dispatch)
+            tr.event("engine_dispatch", segment=si, **lane.dispatch,
+                     controller=ctrl.kind, readout=readout)
             if guard_on and gband is None:
                 with tr.span("guard", name="band"):
                     gband = _guard_band_cols(lane.b_pad, b, policy.target,
@@ -1325,8 +1424,8 @@ def _run_scenario(topo: Topology, links: LinkParams, ctrl: ControllerConfig,
                 stop = min(chunk, seg.records - seg_done) - 1
                 with tr.span("chunk", engine=lane.engine, segment=si,
                              launch=launches, records=int(stop + 1)):
-                    (psi_pad, nu_pad, trips, tstar, valid, freq_c, beta_c,
-                     wm_c) = lane.run(psi_pad, nu_pad, stop)
+                    (psi_pad, nu_pad, c_pad, trips, tstar, valid, freq_c,
+                     beta_c, wm_c) = lane.run(psi_pad, nu_pad, c_pad, stop)
                     freq_chunks.append(freq_c)
                     if rb_dense:
                         beta_chunks.append(beta_c)
@@ -1369,7 +1468,8 @@ def _run_scenario(topo: Topology, links: LinkParams, ctrl: ControllerConfig,
             continue
 
         tr.event("engine_dispatch", segment=si, engine="segment-sum",
-                 records=int(seg.records))
+                 records=int(seg.records), controller=ctrl.kind,
+                 readout=readout)
         for _ in range(seg.records // chunk):
             # Per-launch derived seed: telemetry-noise keys must differ
             # across chunks (exact zeros when noise is off, so splitting
@@ -1439,7 +1539,8 @@ def _run_scenario(topo: Topology, links: LinkParams, ctrl: ControllerConfig,
     if dense or sparse:
         if single:
             freq = freq[0]
-        psi_f, nu_f = _fetch_unpadded(tr, (psi_pad, nu_pad), b, n)
+        psi_f, nu_f, *c_f = _fetch_unpadded(
+            tr, (psi_pad, nu_pad) + ((c_pad,) if pulses else ()), b, n)
         if rb_dense:
             beta = np.concatenate(beta_chunks, axis=1)
             if single:
@@ -1447,8 +1548,8 @@ def _run_scenario(topo: Topology, links: LinkParams, ctrl: ControllerConfig,
         else:
             beta = np.zeros(freq.shape[:-1] + (0,), np.float32)
         if single:
-            psi_f, nu_f = psi_f[0], nu_f[0]
-        c_state = {}
+            psi_f, nu_f, c_f = psi_f[0], nu_f[0], [c[0] for c in c_f]
+        c_state = {"c_est": c_f[0]} if pulses else {}
     else:
         beta = (np.concatenate(beta_chunks, axis=axis) if rb_seg
                 else np.zeros(freq.shape[:-1] + (0,), np.float32))

@@ -156,9 +156,9 @@ def test_engine_outputs_named_and_positional():
     assert EngineOutputs._fields[:5] == ("psi", "nu", "freq", "beta",
                                          "watermarks")
     out = EngineOutputs(psi=1, nu=2, freq=3)
-    psi, nu, freq, beta, wm, guard = out
+    psi, nu, freq, beta, wm, guard, c_est = out
     assert (psi, nu, freq) == (1, 2, 3)
-    assert beta is None and wm is None and guard is None
+    assert beta is None and wm is None and guard is None and c_est is None
 
     # And the public ensemble entry point still unpacks like the
     # historical 2-tuple while exposing the named telemetry fields.

@@ -110,6 +110,31 @@ def test_fused_compiles_at_testbed_size(one_chip, tel):
     _compile_dense(bittide_fused_pallas, one_chip, 128, 1, tel)
 
 
+def test_fused_pulse_compiles_at_testbed_cell_size(one_chip):
+    """FINC/FDEC with integer readout, the testbed.finc_mc kernel: FC8 at
+    B = 1,024, one latency class, 56 edges padded to 128, β and
+    watermarks, 500 records of 20 periods."""
+    from repro.kernels.bittide_step import Incidence
+    from repro.kernels.period import Pulses
+    b, n, e = 1024, 128, 128
+    tel = dict(record_beta=True, record_watermarks=True)
+    assert select_engine(b, n, 1, **tel, edges=e, pulses=True) == (
+        "fused", n)
+
+    def fn(psi, nu, nu_u, kp, boff, mask, lamsum, a, deg, lat, src, dst,
+           scat, lam_e, c0):
+        return bittide_fused_pallas(
+            psi, nu, nu_u, a, deg, lamsum, lat, kp, boff, 6250.0,
+            num_records=500, record_every=20, ctrl_mask=mask,
+            interpret=False, **tel, pulses=Pulses(1e-7, 50), c_est=c0,
+            edges=Incidence(src, dst, scat), lam_edges=lam_e)
+    _compile(fn, one_chip, "bittide_fused",
+             *[((b, n), F32)] * 3, ((b,), F32), ((b,), F32), ((1, n), F32),
+             ((b, n), F32), ((1, n, n), F32), ((1, n), F32), ((b, 1), F32),
+             ((1, e, n), jnp.bfloat16), ((e, n), jnp.bfloat16), ((n, e), F32),
+             ((1, e), F32), ((b, n), F32))
+
+
 def test_fused_compiles_at_resident_limit(one_chip):
     assert select_engine(B, 256, 8, **ALL_TELEMETRY) == ("fused", 256)
     _compile_dense(bittide_fused_pallas, one_chip, 256, 8, ALL_TELEMETRY)
